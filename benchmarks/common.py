@@ -40,30 +40,14 @@ def intermediate_shapes(fn, *args) -> set[tuple[int, ...]]:
     (jit/scan bodies) — a structural HBM-footprint probe.  Shared by the
     kernel and workloads benches (and their tests): tiling contracts are
     asserted against the traced program, not against runtime telemetry."""
-    import jax.core as jcore
+    from repro.obs import walk_eqns
 
-    shapes: set[tuple[int, ...]] = set()
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            for ov in eqn.outvars:
-                aval = getattr(ov, "aval", None)
-                if getattr(aval, "dtype", None) == jnp.float32:
-                    shapes.add(tuple(aval.shape))
-            for val in eqn.params.values():
-                if isinstance(val, jcore.ClosedJaxpr):
-                    walk(val.jaxpr)
-                elif isinstance(val, jcore.Jaxpr):
-                    walk(val)
-                elif isinstance(val, (list, tuple)):
-                    for x in val:
-                        if isinstance(x, jcore.ClosedJaxpr):
-                            walk(x.jaxpr)
-                        elif isinstance(x, jcore.Jaxpr):
-                            walk(x)
-
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return shapes
+    return {
+        tuple(ov.aval.shape)
+        for eqn, _ in walk_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        for ov in eqn.outvars
+        if getattr(getattr(ov, "aval", None), "dtype", None) == jnp.float32
+    }
 
 
 _CORPora: dict = {}

@@ -19,10 +19,10 @@ from benchmarks.common import BenchResult, intermediate_shapes, time_fn
 from repro.kernels import ops, ref
 
 
-def _vmem_bytes_phase1(block_v=512, block_h=128, m=384, b_out=1):
-    # emb tile + t tile + valid + out accumulator + (bv, bh) distance tile
-    return 4 * (block_v * m + block_h * m + block_h
-                + block_v * b_out + block_v * block_h)
+def _vmem_bytes_phase1(block_v=512, block_h=16, m=384, b=64):
+    # emb tile + t tile (bh, B, m) + t2 + out accumulator + (bv, B) min tile
+    return 4 * (block_v * m + block_h * b * m + block_h * b
+                + 2 * block_v * b)
 
 
 def _vmem_bytes_fused(block_v=256, block_n=8, h=32, h1=32, m=384, b=8,
@@ -135,7 +135,7 @@ def run() -> list[BenchResult]:
     # absolute times are NOT TPU times).
     block_n = 8
     steps_naive = n * h
-    steps_blocked = (n // block_n) * h
+    steps_blocked = n // block_n
     ns, hs, vs, bs = 64, 8, 256, 8
     ids_s = jnp.asarray(rng.integers(0, vs, (ns, hs)).astype(np.int32))
     w_s = jnp.asarray(rng.uniform(0, 1, (ns, hs)).astype(np.float32))
@@ -191,7 +191,7 @@ def run() -> list[BenchResult]:
         BenchResult("kernel_phase1_vmem_footprint", 0.0, derived={
             "bytes": vmem, "limit": 16 * 2**20,
             "fits_vmem": bool(vmem < 16 * 2**20),
-            "blockspec": "bv=512,bh=128,m=384"}),
+            "blockspec": "bv=512,bh=16,B=64,m=384"}),
         BenchResult("kernel_fused_vmem_footprint", 0.0, derived={
             "bytes": vmem_fused, "limit": 16 * 2**20,
             "fits_vmem": bool(vmem_fused < 16 * 2**20),

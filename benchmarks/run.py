@@ -41,6 +41,9 @@ def main() -> None:
     out_dir = pathlib.Path(args.out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
 
+    from repro.launch.serve import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         fig10_11_overlap,
         fig12_13_runtime,

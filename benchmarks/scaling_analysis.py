@@ -67,6 +67,9 @@ def run() -> list:
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = str(REPO / "src")
+    # Compile-only on virtual CPU devices: the child never takes the chip,
+    # which the parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                        text=True, env=env, timeout=1500)
     if r.returncode != 0:

@@ -1,10 +1,8 @@
-"""Version shims for the jax API surface this repo uses.
+"""Mesh and shard_map entry points shared by every call site.
 
-The codebase targets the current ``jax.shard_map`` / ``jax.sharding.AxisType``
-API; pinned CI containers may carry an older jax where shard_map still lives
-in ``jax.experimental`` (with ``check_rep`` instead of ``check_vma``) and
-meshes take no ``axis_types``.  Every call site goes through these wrappers
-so the drift is handled in exactly one place.
+Meshes are built with explicitly-Auto axis types, and shard_map runs with
+its replication checks off (the serve steps psum their outputs).  Keeping
+both in one place keeps those two policies consistent across the repo.
 """
 
 from __future__ import annotations
@@ -13,20 +11,13 @@ import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh with explicitly-Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with explicitly-Auto axis types."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     """shard_map with per-output replication checks off (psum'd outputs)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False)

@@ -18,12 +18,14 @@ Array = jax.Array
 _EPS = 0.0  # distances are clamped at 0; sqrt(0) grads are guarded below.
 
 
-def sq_dists(a: Array, b: Array, *, precision=None, bf16_matmul: bool = False) -> Array:
+def sq_dists(a: Array, b: Array, *, bf16_matmul: bool = False) -> Array:
     """Squared Euclidean distances between rows of ``a`` (p,m) and ``b`` (q,m).
 
     Returns (p, q) float32.  ``bf16_matmul=True`` downcasts the GEMM inputs to
     bf16 (fp32 accumulation via ``preferred_element_type``) — the TPU
-    adaptation of the paper's fp32 CUBLAS call.
+    adaptation of the paper's fp32 CUBLAS call.  The f32 GEMM asks for
+    ``HIGHEST`` precision: a TPU's default f32 matmul rounds its inputs to
+    bf16, and ‖a‖² + ‖b‖² − 2ab cancels that error into the distances.
     """
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
@@ -38,7 +40,8 @@ def sq_dists(a: Array, b: Array, *, precision=None, bf16_matmul: bool = False) -
         )
     else:
         ab = jax.lax.dot_general(
-            a, b, (((1,), (1,)), ((), ())), precision=precision,
+            a, b, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
     return jnp.maximum(a2 + b2 - 2.0 * ab, 0.0)

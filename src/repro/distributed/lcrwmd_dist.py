@@ -64,7 +64,7 @@ def _mesh_key(mesh) -> tuple:
     return (
         tuple(mesh.axis_names),
         tuple(int(mesh.shape[a]) for a in mesh.axis_names),
-        tuple(int(d.id) for d in mesh.devices.flat),
+        tuple((d.platform, int(d.id)) for d in mesh.devices.flat),
     )
 
 

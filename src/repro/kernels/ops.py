@@ -52,11 +52,15 @@ def _phase1_padded(
     bf16_matmul: bool,
     interpret: bool,
 ) -> Array:
-    t = _pad_to(t, block_h, axis=1)
-    valid = _pad_to(valid, block_h, axis=1)
+    # Word-slot-major layout: the kernel takes one (B, m) slab per slot, so
+    # the query batch rides whole in every block (no lane-axis reshape).
+    bh = min(block_h, -(-t.shape[1] // 8) * 8)
+    t_s = _pad_to(jnp.swapaxes(t, 0, 1), bh, axis=0)             # (h, B, m)
+    t2 = jnp.where(valid.T > 0, jnp.sum(t * t, axis=-1).T, _INF)  # (h, B)
+    t2 = _pad_to(t2, bh, axis=0, value=_INF)
     z_sq = _p1.lc_rwmd_phase1_pallas(
-        emb_f, t, valid,
-        block_v=block_v, block_h=min(block_h, t.shape[1]),
+        emb_f, t_s, t2,
+        block_v=block_v, block_h=bh,
         bf16_matmul=bf16_matmul, interpret=interpret,
     )
     return jnp.sqrt(jnp.maximum(z_sq[:v_out], 0.0))
@@ -71,7 +75,7 @@ def lc_rwmd_phase1(
     q_w: Array,      # (B, h) float (0 = padding)
     *,
     block_v: int = 512,
-    block_h: int = 128,
+    block_h: int = 16,
     bf16_matmul: bool = False,
     interpret: bool | None = None,
 ) -> Array:
@@ -99,7 +103,7 @@ def lc_rwmd_phase1_pregathered(
     valid: Array,    # (B, h) float 0/1
     *,
     block_v: int = 512,
-    block_h: int = 128,
+    block_h: int = 16,
     bf16_matmul: bool = False,
     interpret: bool | None = None,
 ) -> Array:
@@ -146,8 +150,10 @@ def spmm_ell(
     ids_p = _pad_to(ids, block_n, axis=0)
     w_p = _pad_to(w_f, block_n, axis=0)
     if mode == "blocked":
+        # Row DMAs are not bounds-checked on the device: clamp the ids.
         out = _sp.spmm_ell_pallas(
-            ids_p, w_p, z_p, block_n=block_n, interpret=interpret)
+            jnp.clip(ids_p, 0, z.shape[0] - 1), w_p, z_p, block_n=block_n,
+            interpret=interpret)
     elif mode == "dense":
         z_p = _pad_to(z_p, block_v, axis=0)
         out = _sp.spmm_ell_dense_pallas(
@@ -174,7 +180,7 @@ def lc_rwmd_fused(
     fuse: str = "scan",
     block_n: int = 8,
     block_v: int = 256,
-    block_h: int = 128,
+    block_h: int = 16,
     bf16_matmul: bool = False,
     interpret: bool | None = None,
 ) -> Array:
@@ -323,7 +329,7 @@ def lc_rwmd_fused_topk(
     row_block: int = 128,
     block_n: int = 8,
     block_v: int = 256,
-    block_h: int = 128,
+    block_h: int = 16,
     vocab_chunk: int = 512,
     bf16_matmul: bool = False,
     interpret: bool | None = None,
@@ -452,14 +458,16 @@ def sinkhorn_wmd(
         interpret = _on_cpu()
     p, h1, _ = t1.shape
     h2 = t2.shape[1]
-    # Lane-align the embedding and word axes; padding words carry weight 0
-    # (masked in log domain inside the kernel).  Padding PAIRS (P axis) are
-    # all-zero-weight problems that converge on their first iteration.
+    # Lane-align the embedding axis and the query word axis (the cost
+    # tile's lanes); the candidate word axis is its sublanes.  Padding words
+    # carry weight 0 (masked in log domain inside the kernel).  Padding
+    # PAIRS (P axis) are all-zero-weight problems that converge on their
+    # first iteration.
     t1 = _pad_to(t1.astype(jnp.float32), 128, axis=2)
     t2 = _pad_to(t2.astype(jnp.float32), 128, axis=2)
-    t1 = _pad_to(t1, 128, axis=1)
+    t1 = _pad_to(t1, 8, axis=1)
     t2 = _pad_to(t2, 128, axis=1)
-    w1 = _pad_to(w1.astype(jnp.float32), 128, axis=1)
+    w1 = _pad_to(w1.astype(jnp.float32), 8, axis=1)
     w2 = _pad_to(w2.astype(jnp.float32), 128, axis=1)
     t1 = _pad_to(t1, block_p, axis=0)
     t2 = _pad_to(t2, block_p, axis=0)

@@ -1,21 +1,18 @@
-"""Pallas TPU kernels: LC-RWMD Phase 2 — ELL-format SpMM via scalar prefetch.
+"""Pallas TPU kernels: LC-RWMD Phase 2 — ELL-format SpMM as a row gather.
 
 Computes ``D[i, j] = Σ_p w[i, p] · Z[ids[i, p], j]`` (sparse resident matrix
 times the dense Phase-1 output).  The paper uses CUSPARSE CSR SpMM; TPUs
-have no sparse unit, so we use the canonical Pallas *scalar-prefetch*
-embedding-gather pattern: the ELL column-id array rides in SMEM and steers
-the BlockSpec index_maps, so each grid step DMAs exactly the Z rows it needs
-into VMEM — random-access gather expressed as block choreography.
+have no sparse unit, so the ELL column ids ride in SMEM and steer DMAs of
+exactly the Z rows each doc needs into VMEM — random-access gather.
 
 Three formulations (see EXPERIMENTS.md §Perf for the HBM-traffic model):
 
 ``spmm_ell_pallas`` (blocked gather, the default):
-  Grid ``(n // block_n, h)`` — outer over doc *tiles*, inner over ELL slots.
-  Each step gathers ``block_n`` Z rows at once: the Z operand is passed
-  ``block_n`` times, each copy with its own ids-steered index_map, so the
-  pipeline issues ``block_n`` (1, B) row DMAs per step instead of one.
-  This cuts grid steps from the seed's ``n·h`` to ``(n/block_n)·h`` and
-  lets the DMA engine overlap the row fetches of a whole doc tile.
+  Grid ``(n // block_n,)`` over doc *tiles*.  Each step reads the tile's
+  ELL ids from SMEM and issues all ``block_n·h`` (1, B) row DMAs out of Z
+  in HBM before waiting on any, so the DMA engine overlaps the row fetches
+  of a whole doc tile; the weighted sum then runs over the gathered rows
+  in VMEM.
 
 ``spmm_ell_dense_pallas`` (one-hot MXU formulation):
   Grid ``(n // block_n, v // block_v)``.  Per step, the (block_n, h) id tile
@@ -41,58 +38,81 @@ from jax.experimental.pallas import tpu as pltpu
 # ---------------------------------------------------------------------------
 # Blocked gather formulation (default)
 # ---------------------------------------------------------------------------
-def _spmm_blocked_kernel(ids_ref, w_ref, *refs, block_n: int):
-    # ids_ref: SMEM (n, h) int32 (scalar-prefetch operand; consumed by the
-    #          index_maps, not the body)
+def _spmm_blocked_kernel(ids_ref, w_ref, z_hbm, out_ref, rows, sem):
+    # ids_ref: SMEM (block_n, h) int32 — ELL ids of the current doc tile
     # w_ref:   VMEM (block_n, h) f32 — weights of the current doc tile
-    # refs:    block_n gathered Z rows (1, B) f32, then out (block_n, B) f32
-    del ids_ref
-    z_refs, out_ref = refs[:-1], refs[-1]
-    p = pl.program_id(1)
+    # z_hbm:   HBM (v, B) f32 — the whole Z; rows are DMA'd on demand
+    # out_ref: VMEM (block_n, B) f32
+    # rows:    VMEM (h, block_n, B) f32 scratch — the tile's gathered rows
+    # sem:     one DMA semaphore shared by the tile's block_n·h row copies
+    block_n, h = w_ref.shape
 
-    @pl.when(p == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def row_copy(p, j):
+        return pltpu.make_async_copy(
+            z_hbm.at[pl.ds(ids_ref[j, p], 1)], rows.at[p, pl.ds(j, 1)],
+            sem.at[0])
 
-    for j in range(block_n):
-        out_ref[j, :] += w_ref[j, p] * z_refs[j][0, :]
+    def start(p, carry):
+        for j in range(block_n):
+            row_copy(p, j).start()
+        return carry
+
+    def wait(p, carry):
+        for j in range(block_n):
+            row_copy(p, j).wait()
+        return carry
+
+    # Every row DMA of the tile is in flight before the first wait.
+    jax.lax.fori_loop(0, h, start, 0)
+    jax.lax.fori_loop(0, h, wait, 0)
+
+    w = w_ref[...]
+    slot = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+
+    def accumulate(p, acc):
+        w_p = jnp.sum(jnp.where(slot == p, w, 0.0), axis=1, keepdims=True)
+        return acc + w_p * rows[p]
+
+    out_ref[...] = jax.lax.fori_loop(
+        0, h, accumulate, jnp.zeros(out_ref.shape, jnp.float32))
 
 
 def spmm_ell_pallas(
-    ids: jax.Array,   # (n, h) int32 ELL column ids (0 at padding)
+    ids: jax.Array,   # (n, h) int32 ELL column ids in [0, v) (0 at padding)
     w: jax.Array,     # (n, h) f32 weights (0 at padding)
     z: jax.Array,     # (v, B) f32 dense Phase-1 output
     *,
     block_n: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
-    """Blocked ELL SpMM: grid (n // block_n, h), block_n row DMAs per step.
+    """Blocked ELL SpMM: grid (n // block_n,), block_n·h row DMAs per step.
 
     Requires ``n % block_n == 0`` (ops.spmm_ell pads); padding docs carry
-    weight 0 everywhere, so their gathered rows contribute nothing.
+    weight 0 everywhere, so their gathered rows contribute nothing.  Each
+    doc tile's ids ride in SMEM and steer explicit row DMAs out of Z in
+    HBM: a (1, B) row block is not a legal BlockSpec tile on a (v, B)
+    array, and scalar-prefetching all n·h ids would not fit SMEM at
+    corpus scale.
     """
     n, h = ids.shape
     v, b = z.shape
     if n % block_n != 0:
         raise ValueError(f"n={n} not a multiple of block_n={block_n}")
-
-    def _row_map(i, p, ids, j):
-        return (ids[i * block_n + j, p], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n // block_n, h),
-        in_specs=[pl.BlockSpec((block_n, h), lambda i, p, ids: (i, 0))]  # w
-        + [pl.BlockSpec((1, b), functools.partial(_row_map, j=j))        # z rows
-           for j in range(block_n)],
-        out_specs=pl.BlockSpec((block_n, b), lambda i, p, ids: (i, 0)),
-    )
     return pl.pallas_call(
-        functools.partial(_spmm_blocked_kernel, block_n=block_n),
-        grid_spec=grid_spec,
+        _spmm_blocked_kernel,
+        grid=(n // block_n,),
+        in_specs=[
+            pl.BlockSpec((block_n, h), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),                 # ids
+            pl.BlockSpec((block_n, h), lambda i: (i, 0)),           # w
+            pl.BlockSpec(memory_space=pl.ANY),                      # z
+        ],
+        out_specs=pl.BlockSpec((block_n, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((h, block_n, b), jnp.float32),
+                        pltpu.SemaphoreType.DMA((1,))],
         interpret=interpret,
-    )(ids, w, *([z] * block_n))
+    )(ids, w, z)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,12 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 
-# NOTE: the two lines above MUST run before any other import (jax locks the
-# device count at first init). 512 placeholder host devices exist ONLY here,
-# never in tests/benchmarks.
+# NOTE: the lines above MUST run before any other import (jax locks the
+# platform and device count at first init). 512 placeholder host devices
+# exist ONLY here, never in tests/benchmarks.  The dry run compiles on the
+# CPU even where an accelerator is attached: it never takes the chip.
 
 import argparse
 import json

@@ -18,7 +18,8 @@ process-wide jit caches, so it lives as a process-wide singleton in
 Also here: :func:`jaxpr_collective_counts`, a build-time structural
 probe that counts mesh collectives (psum / all_gather / …) in a traced
 function — recorded once per serve-step build as gauges, so collective
-regressions show up in a metrics diff instead of a profiler session.
+regressions show up in a metrics diff instead of a profiler session — on
+top of :func:`walk_eqns`, the repo's one jaxpr walker.
 """
 
 from __future__ import annotations
@@ -96,35 +97,44 @@ def render_prometheus(obs: Observability | MetricsRegistry | None = None) -> str
     return _render_metrics(reg)
 
 
+def walk_eqns(jaxpr, mult: int = 1):
+    """Yield ``(eqn, mult)`` for every equation of ``jaxpr``, recursing into
+    sub-jaxprs (jit, scan, cond, shard_map bodies).
+
+    ``mult`` is the product of the enclosing ``scan`` lengths — how many
+    times the equation runs per call.  The one jaxpr walker of the repo:
+    collective counts here and the benchmarks' intermediate-shape probe
+    both read the traced program through it.
+    """
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn, mult
+        inner = mult
+        if eqn.primitive.name == "scan":
+            length = eqn.params.get("length")
+            if isinstance(length, int):
+                inner = mult * length
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from walk_eqns(getattr(sub, "jaxpr", sub), inner)
+
+
 def jaxpr_collective_counts(fn, *args, **kwargs) -> dict[str, int]:
     """Count collective primitives in ``fn``'s jaxpr for these args.
 
-    Walks nested jaxprs; equations inside ``scan`` bodies are multiplied
-    by the scan ``length`` so the numbers reflect per-call collective
-    *issues*, matching what a profiler would see (this is how PR 7's
-    psum-batching win becomes a visible metric).  Returns only nonzero
-    entries.
+    Equations inside ``scan`` bodies are multiplied by the scan ``length``
+    so the numbers reflect per-call collective *issues*, matching what a
+    profiler would see (this is how the psum-batching win becomes a
+    visible metric).  Returns only nonzero entries.
     """
     import jax
 
     counts: dict[str, int] = {}
-
-    def walk(jaxpr, mult: int) -> None:
-        for eqn in jaxpr.eqns:
-            name = eqn.primitive.name
-            if name in COLLECTIVE_PRIMS:
-                name = _PRIM_ALIASES.get(name, name)
-                counts[name] = counts.get(name, 0) + mult
-            inner_mult = mult
-            if name == "scan":
-                length = eqn.params.get("length")
-                if isinstance(length, int):
-                    inner_mult = mult * length
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(getattr(sub, "jaxpr", sub), inner_mult)
-
-    closed = jax.make_jaxpr(fn)(*args, **kwargs)
-    walk(closed.jaxpr, 1)
+    for eqn, mult in walk_eqns(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr):
+        name = eqn.primitive.name
+        if name in COLLECTIVE_PRIMS:
+            name = _PRIM_ALIASES.get(name, name)
+            counts[name] = counts.get(name, 0) + mult
     return counts
 
 
@@ -136,5 +146,5 @@ __all__ = [
     "Observability", "QueryQuarantined", "QueryTrace", "RetraceError",
     "STAGES", "TierTransition", "Tracer", "WorkerRestart",
     "get_default", "jaxpr_collective_counts", "profiler_session",
-    "render_prometheus", "sentinel",
+    "render_prometheus", "sentinel", "walk_eqns",
 ]

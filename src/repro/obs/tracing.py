@@ -176,15 +176,13 @@ class Tracer:
 def profiler_session(logdir: str) -> Iterator[None]:
     """Opt-in ``jax.profiler`` trace session (for real-TPU runs).
 
-    Wraps ``jax.profiler.trace`` so callers need no conditional import;
-    on builds without the profiler this degrades to a no-op context.
+    Wraps ``jax.profiler.trace`` so callers need no profiler import.  A
+    profiler that cannot start raises: a run that asked for a trace must
+    not finish without one.
     """
-    try:
-        import jax.profiler as _prof
-        ctx = _prof.trace(logdir)
-    except Exception:  # profiler unavailable in this build
-        ctx = contextlib.nullcontext()
-    with ctx:
+    import jax.profiler
+
+    with jax.profiler.trace(logdir):
         yield
 
 
