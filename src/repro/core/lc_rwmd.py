@@ -333,7 +333,8 @@ class LCRWMDEngine:
     def gather_queries(self, q_ids: Array) -> Array:
         """(B, h, m) query word embeddings from the FULL table."""
         b, h = q_ids.shape
-        return self.emb_full[q_ids.reshape(-1)].reshape(b, h, -1)
+        with jax.named_scope("gather_queries"):
+            return self.emb_full[q_ids.reshape(-1)].reshape(b, h, -1)
 
     def _d1_from_t(self, t_q: Array, valid_q: Array, b: int) -> Array:
         """Resident→query direction from pre-gathered (B*h, m) targets."""
@@ -481,13 +482,15 @@ class LCRWMDEngine:
         # PRE-GATHERED resident targets (built once at engine construction),
         # not from a per-call emb[ids] gather.
         flat = cand_idx.reshape(-1)
-        vals = wmd_candidate_values(
-            self._t_r.reshape(n, h1, -1)[flat], self.resident.weights[flat],
-            self.gather_queries(q_ids), q_w,
-            use_kernel=self.use_kernel, bf16_matmul=self.bf16_matmul,
-            interpret=self.interpret or None, **dict(sink_items),
-        )
-        return topk_lib.topk_from_candidates(vals, cand_idx, k)
+        t_q = self.gather_queries(q_ids)
+        with jax.named_scope("rerank"):
+            vals = wmd_candidate_values(
+                self._t_r.reshape(n, h1, -1)[flat],
+                self.resident.weights[flat], t_q, q_w,
+                use_kernel=self.use_kernel, bf16_matmul=self.bf16_matmul,
+                interpret=self.interpret or None, **dict(sink_items),
+            )
+            return topk_lib.topk_from_candidates(vals, cand_idx, k)
 
     # -- public entry points ----------------------------------------------
     def _dense_dispatch(self, queries: DocSet, symmetric: bool) -> Array:
@@ -804,12 +807,14 @@ def _segmented_rerank(
     from repro.core import topk as topk_lib
     from repro.core.wmd import wmd_candidate_values
 
-    vals = wmd_candidate_values(
-        t1, w1, t_q, q_w,
-        use_kernel=use_kernel, bf16_matmul=bf16_matmul, **dict(sink_items),
-    )
-    vals = jnp.where(cand_valid.reshape(vals.shape), vals, _INF)
-    return topk_lib.topk_from_candidates(vals, cand_idx, k)
+    with jax.named_scope("rerank"):
+        vals = wmd_candidate_values(
+            t1, w1, t_q, q_w,
+            use_kernel=use_kernel, bf16_matmul=bf16_matmul,
+            **dict(sink_items),
+        )
+        vals = jnp.where(cand_valid.reshape(vals.shape), vals, _INF)
+        return topk_lib.topk_from_candidates(vals, cand_idx, k)
 
 
 class EngineSegment:
@@ -1078,7 +1083,8 @@ class SegmentedEngine:
 
     def gather_queries(self, q_ids: Array) -> Array:
         b, h = q_ids.shape
-        return self._gather_queries_flat(q_ids).reshape(b, h, -1)
+        with jax.named_scope("gather_queries"):
+            return self._gather_queries_flat(q_ids).reshape(b, h, -1)
 
     def _fold_topk(self, queries: DocSet, k: int, symmetric: bool):
         from repro.core.topk import TopK, merge_topk

@@ -90,8 +90,10 @@ class StreamingTopK:
         each query column receives the R candidates ``(d_block[:, j], gids)``.
         """
         r, b = d_block.shape
-        idx = jnp.broadcast_to(row_gids[None, :].astype(jnp.int32), (b, r))
-        return self.update(carry, d_block.T, idx)
+        with jax.named_scope("topk_fold"):
+            idx = jnp.broadcast_to(
+                row_gids[None, :].astype(jnp.int32), (b, r))
+            return self.update(carry, d_block.T, idx)
 
     def update_rows(self, carry: TopK, block: Array, col_gids: Array) -> TopK:
         """Fold a (R, C) block row-wise into an (R, k) carry (per-row top-k
@@ -140,12 +142,13 @@ def crossshard_topk(local: TopK, k: int, *, axis_names: Sequence[str]) -> TopK:
     ``local.indices`` must already be GLOBAL doc ids.  Communication: one
     all_gather of (B, k̃) pairs per axis.
     """
-    d_all = local.dists
-    i_all = local.indices
-    for ax in axis_names:
-        d_all = jax.lax.all_gather(d_all, ax, axis=-1, tiled=True)
-        i_all = jax.lax.all_gather(i_all, ax, axis=-1, tiled=True)
-    return lex_smallest(d_all, i_all, k)
+    with jax.named_scope("crossshard_topk"):
+        d_all = local.dists
+        i_all = local.indices
+        for ax in axis_names:
+            d_all = jax.lax.all_gather(d_all, ax, axis=-1, tiled=True)
+            i_all = jax.lax.all_gather(i_all, ax, axis=-1, tiled=True)
+        return lex_smallest(d_all, i_all, k)
 
 
 def distributed_topk(

@@ -21,7 +21,6 @@ makes the `pod` axis safe for DCN-speed links.
 from __future__ import annotations
 
 import functools
-import time
 from typing import NamedTuple
 
 import jax
@@ -31,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from repro.compat import shard_map as compat_shard_map
+from repro.obs import Observability
 from repro.obs import sentinel as _sentinel
 from repro.core.distances import dists, safe_sqrt, sq_dists
 from repro.core.topk import (
@@ -46,6 +46,12 @@ from repro.launch.mesh import DATA_AXIS, MODEL_AXIS, POD_AXIS
 Array = jax.Array
 _INF = 3.4e38
 
+# The serve step's named scopes (phase1, phase2, ...) live only in its ops'
+# metadata, which a profile reads.  JAX's persistent compile cache keys
+# leave metadata out by default, so a program loaded from the cache would
+# carry the metadata of whatever build compiled it first: key on it too.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 # Module-level cache of compiled serve-step callables.  Historically every
 # `build_serve_step` call created fresh `@jax.jit` objects, so each engine
 # swap / adaptive-budget rebuild / tenant switch re-traced from scratch even
@@ -54,6 +60,10 @@ _INF = 3.4e38
 # arguments (including the live-row mask) — lets same-shaped corpora share
 # one trace: multi-tenant engine caches hit this instead of XLA.
 _STEP_CACHE: dict = {}
+
+#: Spans of serve callables built without an Observability bundle: on the
+#: profiler's clock only (a disabled registry observes nothing).
+_NO_OBS = Observability(metrics_enabled=False, tracing_enabled=False)
 
 #: Count of engine-less `build_serve_step` calls (sentinel key suffix —
 #: each such build mints fresh jit objects that cannot share traces).
@@ -117,9 +127,11 @@ def _z_from_t(
     """Phase 1 against a local vocab shard: Z (v_local, B), distances."""
     v_l, m = emb_local.shape
     b, h, _ = t_q.shape
-    sq = sq_dists(emb_local, t_q.reshape(b * h, m), bf16_matmul=bf16_matmul)
-    sq = jnp.where(q_valid.reshape(-1)[None, :] > 0, sq, _INF)
-    return safe_sqrt(jnp.min(sq.reshape(v_l, b, h), axis=2))
+    with jax.named_scope("phase1"):
+        sq = sq_dists(emb_local, t_q.reshape(b * h, m),
+                      bf16_matmul=bf16_matmul)
+        sq = jnp.where(q_valid.reshape(-1)[None, :] > 0, sq, _INF)
+        return safe_sqrt(jnp.min(sq.reshape(v_l, b, h), axis=2))
 
 
 def _gather_query_embeddings(
@@ -139,13 +151,14 @@ def _phase2_partial(
     r_ids: Array, r_w: Array, z_local: Array, v_local: int
 ) -> Array:
     """Masked local ELL-SpMM contribution; full D after psum over model."""
-    mi = jax.lax.axis_index(MODEL_AXIS)
-    lo = (mi * v_local).astype(jnp.int32)
-    rel = r_ids - lo
-    inb = (rel >= 0) & (rel < v_local)
-    zg = z_local[jnp.clip(rel, 0, v_local - 1)]  # (n_l, h, B)
-    w = r_w * inb.astype(r_w.dtype)
-    return jnp.einsum("nh,nhb->nb", w, zg)
+    with jax.named_scope("phase2"):
+        mi = jax.lax.axis_index(MODEL_AXIS)
+        lo = (mi * v_local).astype(jnp.int32)
+        rel = r_ids - lo
+        inb = (rel >= 0) & (rel < v_local)
+        zg = z_local[jnp.clip(rel, 0, v_local - 1)]  # (n_l, h, B)
+        w = r_w * inb.astype(r_w.dtype)
+        return jnp.einsum("nh,nhb->nb", w, zg)
 
 
 def build_serve_step(
@@ -259,11 +272,14 @@ def build_serve_step(
     the tier a batch was served at.
 
     ``obs``: an optional :class:`repro.obs.Observability` bundle.  The
-    engine-path callables then record per-flush serve-step host time
-    (``serve_step_host_seconds`` histogram) and, once per build, the
-    step's mesh-collective counts from jaxpr inspection
-    (``serve_step_collectives_*`` gauges) — so a collective-schedule
-    regression shows up in a metrics diff, not a profiler session.
+    segmented and routed callables open one serve span per host stage
+    (``refresh``, ``gather_queries``, ``route``, ``step_launch``,
+    ``refine_launch``, ``rerank_launch``: ``serve.<stage>`` on the
+    profiler's clock, ``serving_stage_seconds{stage=...}`` in its
+    registry); without one the spans reach the profiler only.  On the
+    device, the compiled programs carry the named scopes ``phase1``,
+    ``phase2``, ``topk_fold``, ``crossshard_topk``, ``refine`` and
+    ``rerank`` in their op metadata.
 
     ``index``: a :class:`repro.index.ClusterIndex` over the (segmented)
     ``engine``.  The serve step then ROUTES each batch: the index's host
@@ -328,7 +344,7 @@ def build_serve_step(
             n_batch_shards=n_batch_shards, n_model=n_model,
             rerank_wmd=rerank_wmd, wmd_kw=wmd_kw, self_exclude=self_exclude,
             streaming=streaming if streaming is not None else True,
-            row_block=row_block, psum_batch=psum_batch, obs=obs,
+            row_block=row_block, psum_batch=psum_batch,
         )
     if self_exclude:
         raise ValueError("self_exclude requires an engine-backed serve step")
@@ -501,8 +517,11 @@ def _engine_step(
                     row[:, None] == q_gid[None, :], _INF, d_blk)
             return stk.update_cols(carry, d_blk, row), None
 
-        local_tk, _ = jax.lax.scan(
-            body, stk.init(b), (ids_b, w_b, live_b, los))
+        # phase2 scopes the slab loop (psum, masks); the carry fold inside
+        # it is `topk_fold`.
+        with jax.named_scope("phase2"):
+            local_tk, _ = jax.lax.scan(
+                body, stk.init(b), (ids_b, w_b, live_b, los))
         tk = crossshard_topk(local_tk, kc, axis_names=batch_axes)
         return tk.dists, tk.indices
 
@@ -542,52 +561,10 @@ def _engine_step(
     return step
 
 
-def _obs_step_instrument(obs, variant):
-    """Resolve per-build serve-step observability handles.
-
-    Returns ``(hist, probe)``: ``hist`` observes host wall time of each
-    compiled-step call (``serve_step_host_seconds{variant=...}``), and
-    ``probe(step, args)`` — called lazily on the FIRST step invocation of
-    this build — records the step's structural collective counts
-    (``serve_step_collectives_*`` gauges) from its jaxpr, so e.g. the
-    psum-batching win of PR 7 is a visible metric instead of profiler
-    archaeology.  Both are ``None`` when ``obs`` is absent.
-    """
-    if obs is None or getattr(obs, "metrics", None) is None:
-        return None, None
-    hist = obs.metrics.histogram(
-        "serve_step_host_seconds",
-        "Host wall time of one compiled serve-step call (async dispatch "
-        "returns futures; device time lands in device_compute spans).",
-        labels={"variant": variant})
-    done = [False]
-
-    def probe(step, args):
-        if done[0] or not obs.metrics.enabled:
-            return
-        done[0] = True  # never retried, even on failure
-        try:
-            from repro.obs import jaxpr_collective_counts
-            with _sentinel.expect("jaxpr collective inspection"):
-                counts = jaxpr_collective_counts(
-                    getattr(step, "__wrapped__", step), *args)
-            for cname, n in counts.items():
-                obs.metrics.gauge(
-                    f"serve_step_collectives_{cname}",
-                    "Collective ops issued per serve-step call "
-                    "(structural jaxpr count; scan bodies multiplied "
-                    "by trip count).",
-                    labels={"variant": variant}).set(n)
-        except Exception:
-            pass  # inspection is best-effort; serving must not care
-    return hist, probe
-
-
 def _build_engine_serve_step(
     mesh, engine, *, k, kc, refine, bf16_matmul, phase1_full_mesh,
     batch_axes, n_batch_shards, n_model, rerank_wmd=False, wmd_kw=None,
     self_exclude=False, streaming=True, row_block=128, psum_batch=8,
-    obs=None,
 ):
     """Engine-backed serve step: resident state prepped + placed at build.
 
@@ -641,8 +618,6 @@ def _build_engine_serve_step(
         "nh,nhm->nm", engine.resident.weights,
         engine._t_r.reshape(n_docs, h1_r, -1))
 
-    _m_step, _probe = _obs_step_instrument(obs, "mono")
-
     def serve(queries: DocSet, query_ids=None, *, tier: int = 0) -> ServeResult:
         """Tiered serve: ``tier`` walks the degradation ladder (see
         :class:`repro.core.pipeline.QualityTier`).  Tier 0 is the full
@@ -661,13 +636,7 @@ def _build_engine_serve_step(
                                 queries.weights, q_gid)
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
-        step_args = (r_ids, r_w, r_live, t_q, q_valid, q_gid, emb_r)
-        if _probe is not None:
-            _probe(step, step_args)
-        _t_step = time.perf_counter()
-        tk, d_local = step(*step_args)
-        if _m_step is not None:
-            _m_step.observe(time.perf_counter() - _t_step)
+        tk, d_local = step(r_ids, r_w, r_live, t_q, q_valid, q_gid, emb_r)
         if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
             tk = TopK(tk.dists[:, :k], tk.indices[:, :k])
             return ServeResult(
@@ -775,7 +744,11 @@ def _segmented_step(
                         row[:, None] == q_gid[None, :], _INF, d_blk)
                 return stk.update_cols(carry, d_blk, row), None
 
-            carry, _ = jax.lax.scan(body, carry, (ids_b, w_b, live_b, los))
+            # phase2 scopes the slab loop (psum, masks); the carry fold
+            # inside it is `topk_fold`.
+            with jax.named_scope("phase2"):
+                carry, _ = jax.lax.scan(
+                    body, carry, (ids_b, w_b, live_b, los))
         tk = crossshard_topk(carry, kc, axis_names=batch_axes)
         return tk.dists, tk.indices
 
@@ -826,7 +799,7 @@ def _build_segmented_serve_step(
              else P(MODEL_AXIS, None))
     emb_shards = n_model * (n_batch_shards if phase1_full_mesh else 1)
     state: dict = {"version": None}
-    _m_step, _probe = _obs_step_instrument(obs, "seg")
+    obs = obs if obs is not None else _NO_OBS
 
     def _refresh():
         if state["version"] == engine.version:
@@ -882,39 +855,39 @@ def _build_segmented_serve_step(
         if self_exclude and query_ids is None:
             raise ValueError("self_exclude serve step needs query_ids (B,)")
         tier = int(tier)
-        _refresh()
-        t_q = engine.gather_queries(queries.ids)
-        q_valid = (queries.weights > 0).astype(jnp.float32)
-        q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
-                 else jnp.full((queries.n_docs,), -1, jnp.int32))
+        with obs.span("refresh"):
+            _refresh()
+        with obs.span("gather_queries"):
+            t_q = engine.gather_queries(queries.ids)
+            q_valid = (queries.weights > 0).astype(jnp.float32)
+            q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
+                     else jnp.full((queries.n_docs,), -1, jnp.int32))
         if tier >= 2:  # QualityTier.WCD
             tk = _wcd_topk_step(k, self_exclude, state["cent"], t_q,
                                 queries.weights, q_gid)
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
-        step_args = (state["rids"], state["rw"], state["live"],
-                     state["offs"], t_q, q_valid, q_gid, state["embs"])
-        if _probe is not None:
-            _probe(state["step"], step_args)
-        _t_step = time.perf_counter()
-        tk = state["step"](*step_args)
-        if _m_step is not None:
-            _m_step.observe(time.perf_counter() - _t_step)
+        with obs.span("step_launch"):
+            tk = state["step"](
+                state["rids"], state["rw"], state["live"], state["offs"],
+                t_q, q_valid, q_gid, state["embs"])
         if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
             return ServeResult(
                 topk=TopK(tk.dists[:, :k], tk.indices[:, :k]),
                 d_local=None, pruned_exact=None, tier=tier)
-        cand_max_rwmd = tk.dists[:, -1]
+        with obs.span("refine_launch"):
+            cand_max_rwmd = tk.dists[:, -1]
+            if refine:
+                tk = _symmetric_refine(
+                    engine.resident, queries, engine.emb_full, tk)
         exact = None
-        if refine:
-            tk = _symmetric_refine(
-                engine.resident, queries, engine.emb_full, tk)
         if rerank_wmd:
-            tk = engine.rerank_topk(queries, tk.indices, k,
-                                    sinkhorn_kw=wmd_kw)
-            exact = cand_max_rwmd >= tk.dists[:, -1]
-            if kc >= engine.n_live:  # candidates cover every live doc
-                exact = jnp.ones_like(exact)
+            with obs.span("rerank_launch"):
+                tk = engine.rerank_topk(queries, tk.indices, k,
+                                        sinkhorn_kw=wmd_kw)
+                exact = cand_max_rwmd >= tk.dists[:, -1]
+                if kc >= engine.n_live:  # candidates cover every live doc
+                    exact = jnp.ones_like(exact)
         return ServeResult(topk=tk, d_local=None, pruned_exact=exact)
 
     return serve
@@ -1002,8 +975,9 @@ def _routed_step(
                         gid_blk[:, None] == q_gid[None, :], _INF, d_blk)
                 return stk.update_cols(carry, d_blk, gid_blk), None
 
-            carry, _ = jax.lax.scan(
-                body, carry, (ids_b, w_b, live_b, gid_b))
+            with jax.named_scope("phase2"):
+                carry, _ = jax.lax.scan(
+                    body, carry, (ids_b, w_b, live_b, gid_b))
         tk = crossshard_topk(carry, kc, axis_names=batch_axes)
         return tk.dists, tk.indices
 
@@ -1056,7 +1030,7 @@ def _build_routed_serve_step(
     bspec = batch_axes if len(batch_axes) > 1 else batch_axes[0]
     emb_shards = n_model * (n_batch_shards if phase1_full_mesh else 1)
     state: dict = {"key": None}
-    _m_step, _probe = _obs_step_instrument(obs, "routed")
+    obs = obs if obs is not None else _NO_OBS
 
     def _refresh():
         index._sync_live()  # raises if engine grew without index.add
@@ -1149,51 +1123,53 @@ def _build_routed_serve_step(
         if self_exclude and query_ids is None:
             raise ValueError("self_exclude serve step needs query_ids (B,)")
         tier = int(tier)
-        _refresh()
-        t_q = engine.gather_queries(queries.ids)
-        q_valid = (queries.weights > 0).astype(jnp.float32)
-        q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
-                 else jnp.full((queries.n_docs,), -1, jnp.int32))
+        with obs.span("refresh"):
+            _refresh()
+        with obs.span("gather_queries"):
+            t_q = engine.gather_queries(queries.ids)
+            q_valid = (queries.weights > 0).astype(jnp.float32)
+            q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
+                     else jnp.full((queries.n_docs,), -1, jnp.int32))
         if tier >= 2:  # QualityTier.WCD — no routing on the last rung
             tk = _wcd_topk_step(k, self_exclude, state["cent"], t_q,
                                 queries.weights, q_gid)
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
-        route = index.route(queries)
-        slots, q_route = _pack_slots(route, queries.n_docs)
+        with obs.span("route"):
+            route = index.route(queries)
+            slots, q_route = _pack_slots(route, queries.n_docs)
         step_args = (state["rids"], state["rw"], state["live"],
                      state["gids"], slots, q_route, t_q, q_valid, q_gid,
                      state["embs"])
-        if _probe is not None:
-            _probe(state["step"], step_args)
-        _t_step = time.perf_counter()
-        if state.pop("fresh", False):
-            with _sentinel.expect("routed index cell-shape change"):
+        with obs.span("step_launch"):
+            if state.pop("fresh", False):
+                with _sentinel.expect("routed index cell-shape change"):
+                    tk = state["step"](*step_args)
+            else:
                 tk = state["step"](*step_args)
-        else:
-            tk = state["step"](*step_args)
-        if _m_step is not None:
-            _m_step.observe(time.perf_counter() - _t_step)
         if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
             return ServeResult(
                 topk=TopK(tk.dists[:, :k], tk.indices[:, :k]),
                 d_local=None, pruned_exact=None, tier=tier)
-        cand_max_rwmd = tk.dists[:, -1]
+        with obs.span("refine_launch"):
+            cand_max_rwmd = tk.dists[:, -1]
+            if refine:
+                tk = _symmetric_refine(
+                    engine.resident, queries, engine.emb_full, tk)
         exact = None
-        if refine:
-            tk = _symmetric_refine(
-                engine.resident, queries, engine.emb_full, tk)
         if rerank_wmd:
-            tk = engine.rerank_topk(queries, tk.indices, k,
-                                    sinkhorn_kw=wmd_kw)
-            # Exactness is RELATIVE TO THE ROUTED CELLS (the pipeline's
-            # index-stage contract); promote to a corpus-wide certificate
-            # only when routing provably covered every live doc.
-            exact = cand_max_rwmd >= tk.dists[:, -1]
-            if (state["kc"] >= engine.n_live
-                    and route.cells.shape[1] == index.num_cells
-                    and bool(route.keep.all())):
-                exact = jnp.ones_like(exact)
+            with obs.span("rerank_launch"):
+                tk = engine.rerank_topk(queries, tk.indices, k,
+                                        sinkhorn_kw=wmd_kw)
+                # Exactness is RELATIVE TO THE ROUTED CELLS (the
+                # pipeline's index-stage contract); promote to a
+                # corpus-wide certificate only when routing provably
+                # covered every live doc.
+                exact = cand_max_rwmd >= tk.dists[:, -1]
+                if (state["kc"] >= engine.n_live
+                        and route.cells.shape[1] == index.num_cells
+                        and bool(route.keep.all())):
+                    exact = jnp.ones_like(exact)
         return ServeResult(topk=tk, d_local=None, pruned_exact=exact)
 
     return serve
@@ -1223,7 +1199,9 @@ def _symmetric_refine(
         order = jnp.argsort(d)
         return TopK(d[order], cand_idx[order])
 
-    return jax.vmap(per_query)(queries.ids, queries.weights, tk.indices, tk.dists)
+    with jax.named_scope("refine"):
+        return jax.vmap(per_query)(
+            queries.ids, queries.weights, tk.indices, tk.dists)
 
 
 # Module-level jit caches: the PR 5 fix made these trace once per shape —
@@ -1279,13 +1257,14 @@ def _wmd_rerank_jit(
     from repro.core.topk import topk_from_candidates
     from repro.core.wmd import wmd_candidate_values
 
-    flat = tk.indices.reshape(-1)
-    vals = wmd_candidate_values(
-        emb[resident.ids[flat]], resident.weights[flat],
-        emb[queries.ids], queries.weights,
-        **dict(kw_items),
-    )
-    return topk_from_candidates(vals, tk.indices, k)
+    with jax.named_scope("rerank"):
+        flat = tk.indices.reshape(-1)
+        vals = wmd_candidate_values(
+            emb[resident.ids[flat]], resident.weights[flat],
+            emb[queries.ids], queries.weights,
+            **dict(kw_items),
+        )
+        return topk_from_candidates(vals, tk.indices, k)
 
 
 _wmd_rerank_jit = _sentinel.wrap(

@@ -15,14 +15,17 @@ the re-trace sentinel is intentionally NOT per-bundle — it guards
 process-wide jit caches, so it lives as a process-wide singleton in
 :mod:`repro.obs.sentinel`.
 
-Also here: :func:`jaxpr_collective_counts`, a build-time structural
-probe that counts mesh collectives (psum / all_gather / …) in a traced
-function — recorded once per serve-step build as gauges, so collective
-regressions show up in a metrics diff instead of a profiler session — on
-top of :func:`walk_eqns`, the repo's one jaxpr walker.
+``obs.span(stage)`` opens one leaf span of the serve path
+(:class:`~repro.obs.tracing.Span`): a ``serve.<stage>`` annotation on the
+profiler's clock, one observation of ``serving_stage_seconds{stage=…}``
+and the stage's widening of the batch's :class:`BatchTrace`.
+
+Also here: :func:`walk_eqns`, the repo's one jaxpr walker.
 """
 
 from __future__ import annotations
+
+import threading
 
 from repro.obs import sentinel
 from repro.obs.events import (
@@ -36,17 +39,9 @@ from repro.obs.metrics import (
 from repro.obs.metrics import render_prometheus as _render_metrics
 from repro.obs.sentinel import RetraceError
 from repro.obs.tracing import (
-    BatchTrace, QueryTrace, STAGES, Tracer, profiler_session,
+    SERVE_SPANS, SPAN_SERIES, STAGE_SERIES, BatchTrace, QueryTrace, Span,
+    STAGES, Tracer,
 )
-
-#: Primitive names counted by :func:`jaxpr_collective_counts`.
-#: ``psum2`` is the shard_map-era spelling of psum; both are folded into
-#: the ``psum`` count.
-COLLECTIVE_PRIMS: tuple[str, ...] = (
-    "psum", "psum2", "all_gather", "all_reduce", "all_to_all", "ppermute",
-    "reduce_scatter",
-)
-_PRIM_ALIASES = {"psum2": "psum"}
 
 
 class Observability:
@@ -62,6 +57,46 @@ class Observability:
         self.metrics = MetricsRegistry(enabled=metrics_enabled)
         self.tracer = Tracer(enabled=tracing_enabled)
         self.events = EventLog(maxlen=event_capacity)
+        self._stage_hists: dict = {}
+        self._local = threading.local()   # the batch this thread serves
+
+    # -- serve spans -------------------------------------------------------
+    def stage_histogram(self, stage: str):
+        """The registry histogram that serve span ``stage`` observes."""
+        h = self._stage_hists.get(stage)
+        if h is None:
+            name = SPAN_SERIES.get(stage)
+            h = (self.metrics.histogram(name) if name is not None else
+                 self.metrics.histogram(
+                     STAGE_SERIES, "host time of one serve-path stage "
+                     "per batch", labels={"stage": stage}))
+            self._stage_hists[stage] = h
+        return h
+
+    def span(self, stage: str, *, batch: int | None = None,
+             trace: BatchTrace | None = None, observe: bool = True) -> Span:
+        """Leaf span ``serve.<stage>`` (see :data:`SERVE_SPANS`).
+
+        ``batch``/``trace`` default to those of the enclosing
+        :meth:`in_batch` on this thread.  ``observe=False`` leaves the
+        histogram to the caller (:meth:`observe_stage`), for a stage that
+        sums several spans into one observation per batch."""
+        if batch is None and trace is None:
+            ctx = getattr(self._local, "batch", None)
+            if ctx is not None:
+                batch, trace = ctx
+        hist = (self.stage_histogram(stage)
+                if observe and self.metrics.enabled else None)
+        return Span(stage, batch, hist, trace)
+
+    def observe_stage(self, stage: str, seconds: float) -> None:
+        if self.metrics.enabled:
+            self.stage_histogram(stage).observe(seconds)
+
+    def in_batch(self, batch: int | None, trace: BatchTrace | None = None):
+        """Context in which this thread's spans default to ``batch`` and
+        ``trace`` (the serve callable opens spans without knowing them)."""
+        return _InBatch(self._local, (batch, trace))
 
     @property
     def enabled(self) -> bool:
@@ -79,6 +114,21 @@ class Observability:
 
     def render_prometheus(self) -> str:
         return _render_metrics(self.metrics)
+
+
+class _InBatch:
+    __slots__ = ("_local", "_ctx", "_prev")
+
+    def __init__(self, local, ctx):
+        self._local, self._ctx = local, ctx
+
+    def __enter__(self) -> None:
+        self._prev = getattr(self._local, "batch", None)
+        self._local.batch = self._ctx
+
+    def __exit__(self, *exc) -> bool:
+        self._local.batch = self._prev
+        return False
 
 
 #: Module default bundle, for callers that don't thread their own.
@@ -102,9 +152,9 @@ def walk_eqns(jaxpr, mult: int = 1):
     sub-jaxprs (jit, scan, cond, shard_map bodies).
 
     ``mult`` is the product of the enclosing ``scan`` lengths — how many
-    times the equation runs per call.  The one jaxpr walker of the repo:
-    collective counts here and the benchmarks' intermediate-shape probe
-    both read the traced program through it.
+    times the equation runs per call.  The one jaxpr walker of the repo
+    (the benchmarks' intermediate-shape probe reads the traced program
+    through it).
     """
     import jax
 
@@ -119,32 +169,13 @@ def walk_eqns(jaxpr, mult: int = 1):
             yield from walk_eqns(getattr(sub, "jaxpr", sub), inner)
 
 
-def jaxpr_collective_counts(fn, *args, **kwargs) -> dict[str, int]:
-    """Count collective primitives in ``fn``'s jaxpr for these args.
-
-    Equations inside ``scan`` bodies are multiplied by the scan ``length``
-    so the numbers reflect per-call collective *issues*, matching what a
-    profiler would see (this is how the psum-batching win becomes a
-    visible metric).  Returns only nonzero entries.
-    """
-    import jax
-
-    counts: dict[str, int] = {}
-    for eqn, mult in walk_eqns(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr):
-        name = eqn.primitive.name
-        if name in COLLECTIVE_PRIMS:
-            name = _PRIM_ALIASES.get(name, name)
-            counts[name] = counts.get(name, 0) + mult
-    return counts
-
-
 __all__ = [
-    "BatchTrace", "BudgetRebuild", "COLLECTIVE_PRIMS", "COUNT_BUCKETS",
+    "BatchTrace", "BudgetRebuild", "COUNT_BUCKETS",
     "CorpusEvicted", "CorpusReadmitted", "Counter", "DEFAULT_BUCKETS",
     "Event", "EventLog", "Gauge", "Histogram", "IngestCrash",
     "MetricsRegistry",
     "Observability", "QueryQuarantined", "QueryTrace", "RetraceError",
-    "STAGES", "TierTransition", "Tracer", "WorkerRestart",
-    "get_default", "jaxpr_collective_counts", "profiler_session",
-    "render_prometheus", "sentinel", "walk_eqns",
+    "SERVE_SPANS", "STAGES", "STAGE_SERIES", "Span", "TierTransition",
+    "Tracer", "WorkerRestart", "get_default", "render_prometheus",
+    "sentinel", "walk_eqns",
 ]

@@ -28,14 +28,23 @@ Traces attach to results: ``Answer.trace`` / ``ServeFuture.trace`` hold
 the completed :class:`QueryTrace` (None when tracing is disabled).
 ``timeline()`` merges query- and batch-level spans sorted by start time;
 ``to_dict()`` is JSON-able for export.
+
+**Serve spans** (:class:`Span`, opened by ``Observability.span``) are the
+finer, per-batch leaf stages of the serve path (``SERVE_SPANS``).  Each
+one is a ``jax.profiler.TraceAnnotation`` named ``serve.<stage>`` with the
+batch number as an argument — so a profiler trace shows host work on the
+same clock as the device planes — and one observation of the registry
+histogram ``serving_stage_seconds{stage=<stage>}``; it widens the
+timeline stage above that it belongs to on the batch's
+:class:`BatchTrace`.  Spans on one thread never nest: a trace reader
+labels a device-idle gap with the host event that overlaps it most, and
+an enclosing span would win every gap.
 """
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
-from typing import Iterator
 
 #: Canonical stage names, in pipeline order.
 STAGES: tuple[str, ...] = (
@@ -46,34 +55,98 @@ STAGES: tuple[str, ...] = (
 _BATCH_STAGES = frozenset(
     {"batch_formation", "dispatch", "device_compute", "validation"})
 
+#: The serve path's leaf spans, in the order a batch meets them, and the
+#: timeline stage (``STAGES``) that each widens on its BatchTrace (None:
+#: none; ``device_compute`` is recorded from two timestamps, since the
+#: worker does other work while the device runs).
+SERVE_SPANS: dict[str, str | None] = {
+    "wait": None,                  # for fill, max_wait or a ready batch
+    "prep": None,                  # vectorize or staging-ring collect
+    "pad": "batch_formation",      # fixed-shape padding
+    "refresh": "dispatch",         # resident re-placement; 0 in steady state
+    "gather_queries": "dispatch",  # query-embedding gather launch
+    "route": "dispatch",           # cluster-index routing (routed steps)
+    "step_launch": "dispatch",     # serve-step (jit_step) launch
+    "refine_launch": "dispatch",   # symmetric-refine launch
+    "rerank_launch": "dispatch",   # Sinkhorn rerank launch
+    "collect": None,               # readback: the host blocked on the device
+    "validate": "validation",      # finiteness check, budget feedback
+    "deliver": None,               # future resolution, callers' callbacks
+}
+
+#: The registry histogram a serve span observes, labelled ``stage``.
+STAGE_SERIES = "serving_stage_seconds"
+
+#: Spans whose time an older series already holds, alone.
+SPAN_SERIES = {"collect": "serving_device_collect_seconds"}
+
+_annotation = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class Span:
+    """One leaf span of the serve path; a context manager.
+
+    On the profiler's clock it is ``serve.<stage>`` (``batch=`` as its
+    argument); on exit its length is ``seconds``, observed in ``hist``
+    and widening the stage's timeline stage on ``trace``, where given.
+    With no profiler session active the annotation costs about 1 µs.
+    """
+
+    __slots__ = ("stage", "_ann", "_hist", "_trace", "t0", "seconds")
+
+    def __init__(self, stage: str, batch: int | None = None, hist=None,
+                 trace: "BatchTrace | None" = None):
+        ann = _trace_annotation()
+        self.stage = stage
+        self._ann = (ann("serve." + stage) if batch is None
+                     else ann("serve." + stage, batch=batch))
+        self._hist = hist
+        self._trace = trace
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self.seconds = t1 - self.t0
+        if self._hist is not None:
+            self._hist.observe(self.seconds)
+        if self._trace is not None:
+            stage = SERVE_SPANS.get(self.stage)
+            if stage is not None:
+                self._trace.widen(stage, self.t0, t1)
+        return False
+
 
 class _SpanHolder:
     """Mutable span store: name -> (t_start, t_end)."""
 
-    __slots__ = ("spans", "_open")
+    __slots__ = ("spans",)
 
     def __init__(self):
         self.spans: dict[str, tuple[float, float]] = {}
-        self._open: dict[str, float] = {}
-
-    def begin(self, stage: str) -> None:
-        self._open[stage] = time.perf_counter()
-
-    def end(self, stage: str) -> None:
-        t0 = self._open.pop(stage, None)
-        if t0 is not None:
-            self.spans[stage] = (t0, time.perf_counter())
 
     def span(self, stage: str, t0: float, t1: float) -> None:
         self.spans[stage] = (t0, t1)
 
-    @contextlib.contextmanager
-    def timed(self, stage: str) -> Iterator[None]:
-        self.begin(stage)
-        try:
-            yield
-        finally:
-            self.end(stage)
+    def widen(self, stage: str, t0: float, t1: float) -> None:
+        """Stretch ``stage`` to cover [t0, t1] as well (open it if new)."""
+        old = self.spans.get(stage)
+        self.spans[stage] = ((t0, t1) if old is None
+                             else (min(old[0], t0), max(old[1], t1)))
 
 
 class BatchTrace(_SpanHolder):
@@ -92,9 +165,9 @@ class QueryTrace(_SpanHolder):
 
     __slots__ = ("t_admit", "batch", "done")
 
-    def __init__(self):
+    def __init__(self, t_admit: float | None = None):
         super().__init__()
-        self.t_admit = time.perf_counter()
+        self.t_admit = time.perf_counter() if t_admit is None else t_admit
         self.batch: BatchTrace | None = None
         self.done = False
         self.span("admission", self.t_admit, self.t_admit)
@@ -151,12 +224,12 @@ class Tracer:
         self._n_queries = 0
         self._n_batches = 0
 
-    def admit(self) -> QueryTrace | None:
+    def admit(self, t_admit: float | None = None) -> QueryTrace | None:
         if not self.enabled:
             return None
         with self._lock:
             self._n_queries += 1
-        return QueryTrace()
+        return QueryTrace(t_admit)
 
     def batch(self, seq: int) -> BatchTrace | None:
         if not self.enabled:
@@ -172,19 +245,5 @@ class Tracer:
                     "batches_traced": self._n_batches}
 
 
-@contextlib.contextmanager
-def profiler_session(logdir: str) -> Iterator[None]:
-    """Opt-in ``jax.profiler`` trace session (for real-TPU runs).
-
-    Wraps ``jax.profiler.trace`` so callers need no profiler import.  A
-    profiler that cannot start raises: a run that asked for a trace must
-    not finish without one.
-    """
-    import jax.profiler
-
-    with jax.profiler.trace(logdir):
-        yield
-
-
-__all__ = ["BatchTrace", "QueryTrace", "STAGES", "Tracer",
-           "profiler_session"]
+__all__ = ["BatchTrace", "QueryTrace", "SERVE_SPANS", "STAGES", "STAGE_SERIES",
+           "Span", "Tracer"]

@@ -262,6 +262,8 @@ class ServeFuture(concurrent.futures.Future):
     #: resolution time (None when tracing is off or the request failed
     #: with a shared, non-per-query error instance).
     trace = None
+    #: ``time.perf_counter()`` at submit: the queue wait starts here.
+    t_admit = 0.0
 
     def __await__(self):
         return asyncio.wrap_future(self).__await__()
@@ -276,6 +278,7 @@ class _InFlight(NamedTuple):
     qs: tuple = ()       # the real query histograms (validation retries)
     tier: int = 0        # degradation tier the batch was served at
     t0: float = 0.0      # dispatch wall-clock (latency EWMA)
+    t_launched: float = 0.0  # when the launch returned (device_compute opens)
     state: Any = None    # CorpusState the batch was served against
     traces: tuple = ()   # per-query QueryTraces (aligned with qs; may be empty)
     btrace: Any = None   # shared BatchTrace (None when tracing is off)
@@ -351,9 +354,9 @@ class _ServeCore:
         self._m_dispatch = m.histogram(
             "serving_dispatch_host_seconds",
             "host time in dispatch (pad + serve-step launch)")
-        self._m_collect = m.histogram(
-            "serving_device_collect_seconds",
-            "block_until_ready readback time at collect")
+        # Observed by the ``collect`` serve span.
+        m.histogram("serving_device_collect_seconds",
+                    "block_until_ready readback time at collect")
         self._m_e2e = m.histogram(
             "serving_e2e_latency_seconds",
             "dispatch-to-answers wall time per batch")
@@ -451,6 +454,11 @@ class _ServeCore:
         return snap
 
     @property
+    def next_seq(self) -> int:
+        """Sequence number the next dispatched batch will carry."""
+        return self._seq
+
+    @property
     def ewma_latency(self) -> float | None:
         """Observed EWMA batch latency; None until the first real batch."""
         return self._ewma
@@ -546,27 +554,22 @@ class _ServeCore:
         (latency, crashes, transient NaNs) are skipped — only sticky
         query-keyed poison re-applies — so bisection converges.
         """
-        t_pad0 = time.perf_counter()
-        queries = self.pad_batch(qs)
-        if btrace is not None:
+        with self.obs.span("pad", batch=batch_seq, trace=btrace):
+            queries = self.pad_batch(qs)
+        if btrace is not None and t_prep0 is not None:
             # batch_formation covers ALL host prep of this batch: the
-            # pipeline's vectorize/collect stage (from ``t_prep0``, when
-            # the caller timed it) plus the pad — NOT just the pad.  The
-            # prep half used to be misattributed to queue_wait, hiding
-            # exactly the cost the ingest pool removes.
-            btrace.span("batch_formation",
-                        t_pad0 if t_prep0 is None else t_prep0,
-                        time.perf_counter())
+            # pipeline's vectorize/collect stage (from ``t_prep0``) plus
+            # the pad — NOT just the pad, or the prep half hides in
+            # queue_wait, exactly the cost the ingest pool removes.
+            btrace.widen("batch_formation", t_prep0, t_prep0)
         if self.faults is not None and batch_seq is not None:
             self.faults.on_dispatch(batch_seq)
         # Tier 0 calls the step with its default signature so test spies /
-        # wrappers that only accept (queries,) keep working.
-        if btrace is not None:
-            btrace.begin("dispatch")
-        res = self._serve(queries) if tier == 0 else \
-            self._serve(queries, tier=tier)
-        if btrace is not None:
-            btrace.end("dispatch")
+        # wrappers that only accept (queries,) keep working.  The serve
+        # callable's own spans (refresh … rerank_launch) widen `dispatch`.
+        with self.obs.in_batch(batch_seq, btrace):
+            res = self._serve(queries) if tier == 0 else \
+                self._serve(queries, tier=tier)
         if self.faults is not None:
             res = self.faults.poison_result(batch_seq, res, qs)
         return res
@@ -575,6 +578,7 @@ class _ServeCore:
                  queue_depth: int = 0,
                  corpus_id: str | None = None,
                  traces: Sequence = (),
+                 admitted: Sequence[float] = (),
                  t_dequeue: float | None = None,
                  t_prep0: float | None = None) -> _InFlight:
         """Host-prep one ≤max_batch chunk and launch it on the device.
@@ -589,6 +593,10 @@ class _ServeCore:
         manager lock is held across activation + serve-step launch so a
         concurrent ingest/delete/compact lands between batches, never
         mid-dispatch.
+
+        ``admitted`` holds each query's admission time
+        (``time.perf_counter()``): its queue wait, observed whether or not
+        the query carries a trace.
 
         ``t_dequeue``/``t_prep0`` let a pipelined caller pin the trace
         boundaries to when the batch actually LEFT the queue and when its
@@ -615,10 +623,9 @@ class _ServeCore:
         with self.manager.lock:
             state = self._activate(corpus_id)
             res = self._raw_serve(qs, tier, seq, btrace=bt, t_prep0=t_prep0)
-        if bt is not None:
-            # Device span: opens when the async-dispatched step returns,
-            # closes at collect's block_until_ready readback.
-            bt.begin("device_compute")
+        # device_compute opens when the async-dispatched launches return
+        # and closes at collect's block_until_ready readback.
+        t_launched = time.perf_counter()
         with self._stats_lock:
             self.stats["queries"] += len(qs)
             self.stats["batches"] += 1
@@ -633,11 +640,11 @@ class _ServeCore:
             self._m_batch_size.observe(len(qs))
             self._m_queue_depth.set(queue_depth)
             self._m_dispatch.observe(time.perf_counter() - t0)
-            for tr in traces:
-                if tr is not None:
-                    self._m_queue_wait.observe(t_dequeue - tr.t_admit)
+            for t_admit in admitted:
+                self._m_queue_wait.observe(t_dequeue - t_admit)
         return _InFlight(result=res, n_real=len(qs), seq=seq,
-                         qs=tuple(qs), tier=tier, t0=t0, state=state,
+                         qs=tuple(qs), tier=tier, t0=t0,
+                         t_launched=t_launched, state=state,
                          traces=tuple(traces), btrace=bt)
 
     def collect(self, inflight: _InFlight) -> list:
@@ -657,52 +664,29 @@ class _ServeCore:
         Returns one entry per real query, in order: an :class:`Answer` or
         a :class:`ServingError` instance (quarantined poison).
         """
-        res, n_real, tier = inflight.result, inflight.n_real, inflight.tier
+        res, n_real = inflight.result, inflight.n_real
         bt = inflight.btrace
         if inflight.state is not None:
             # Budget feedback, rebuilds, and validation retries must hit the
             # corpus this batch was served against, not whichever corpus a
             # later pipelined dispatch activated.
             self._active = inflight.state
-        t_read0 = time.perf_counter()
-        tk_i = np.asarray(res.topk.indices)   # blocks on the device result
-        tk_d = np.asarray(res.topk.dists)
+        with self.obs.span("collect", batch=inflight.seq):
+            tk_i = np.asarray(res.topk.indices)  # blocks on the device result
+            tk_d = np.asarray(res.topk.dists)
         if bt is not None:
-            bt.end("device_compute")
-        if self.obs.metrics.enabled:
-            self._m_collect.observe(time.perf_counter() - t_read0)
+            bt.span("device_compute", inflight.t_launched, time.perf_counter())
         if self.trace is not None:
             self.trace.append(("collect", inflight.seq))
-        if bt is not None:
-            bt.begin("validation")
-        finite = np.isfinite(tk_d[:n_real]).all(axis=1)
-        if self.cfg.validate_results and not finite.all():
+        with self.obs.span("validate", batch=inflight.seq, trace=bt):
+            finite = np.isfinite(tk_d[:n_real]).all(axis=1)
+            clean = not self.cfg.validate_results or finite.all()
+            if clean:
+                answers = self._clean_answers(inflight, tk_i, tk_d)
+        if not clean:
+            # Bisection re-serves batch parts: its own pad and launch
+            # spans, after (never inside) this batch's validate span.
             answers = self._validated_answers(inflight, tk_i, tk_d, finite)
-        else:
-            if self.controller is not None:
-                self.controller.note_success()
-            if (self.budget is not None and res.pruned_exact is not None
-                    and tier == 0):
-                # Feed only the REAL queries' exactness flags (padding
-                # queries are all-zero histograms, flags meaningless).
-                old = self.budget.budget
-                new = self.budget.update(np.asarray(res.pruned_exact)[:n_real])
-                if new != old:
-                    # A budget change legitimately builds (and traces) a
-                    # new serve step — tell the armed sentinel so.
-                    with sentinel.expect("adaptive budget rebuild"):
-                        self._serve = self._build_serve(new)
-                    with self._stats_lock:
-                        self.stats["budget_rebuilds"] += 1
-                        self.stats["budget_trajectory"].append(new)
-                    self.obs.events.append(BudgetRebuild(
-                        corpus_id=self._active.corpus_id,
-                        old_budget=old, new_budget=new))
-                    self._m_budget.set(new)
-            answers = [Answer(tk_i[j], tk_d[j], tier=tier)
-                       for j in range(n_real)]
-        if bt is not None:
-            bt.end("validation")
         if inflight.t0:
             dt = time.perf_counter() - inflight.t0
             prev = self._ewma
@@ -726,6 +710,34 @@ class _ServeCore:
                     except (AttributeError, TypeError):
                         pass  # exotic answer type without a __dict__
         return answers
+
+    def _clean_answers(self, inflight: _InFlight, tk_i, tk_d) -> list:
+        """Answers of a batch whose distances are all finite, after the
+        budget feedback (whose change rebuilds the serve step)."""
+        res, tier = inflight.result, inflight.tier
+        if self.controller is not None:
+            self.controller.note_success()
+        if (self.budget is not None and res.pruned_exact is not None
+                and tier == 0):
+            # Feed only the REAL queries' exactness flags (padding
+            # queries are all-zero histograms, flags meaningless).
+            old = self.budget.budget
+            new = self.budget.update(
+                np.asarray(res.pruned_exact)[:inflight.n_real])
+            if new != old:
+                # A budget change legitimately builds (and traces) a
+                # new serve step — tell the armed sentinel so.
+                with sentinel.expect("adaptive budget rebuild"):
+                    self._serve = self._build_serve(new)
+                with self._stats_lock:
+                    self.stats["budget_rebuilds"] += 1
+                    self.stats["budget_trajectory"].append(new)
+                self.obs.events.append(BudgetRebuild(
+                    corpus_id=self._active.corpus_id,
+                    old_budget=old, new_budget=new))
+                self._m_budget.set(new)
+        return [Answer(tk_i[j], tk_d[j], tier=tier)
+                for j in range(inflight.n_real)]
 
     def _validated_answers(self, inflight: _InFlight, tk_i, tk_d,
                            finite) -> list:
@@ -923,8 +935,9 @@ class QueryServer:
             if self.cfg.admission_control and float(deadline) <= 0:
                 raise QueryRejected(
                     f"deadline {deadline!r}s already expired at submit")
+        t_admit = time.perf_counter()
         self._pending.append((ids, weights, abs_deadline, cid,
-                              self._core.obs.tracer.admit()))
+                              self._core.obs.tracer.admit(t_admit), t_admit))
 
     def _flush_chunk(self, qs: list, corpus_id: str):
         """Serve one ≤max_batch same-corpus chunk at the FIXED
@@ -953,7 +966,8 @@ class QueryServer:
                 self._core.dispatch([qs[j][:2] for j in live],
                                     queue_depth=len(self._pending),
                                     corpus_id=corpus_id,
-                                    traces=[qs[j][4] for j in live]))
+                                    traces=[qs[j][4] for j in live],
+                                    admitted=[qs[j][5] for j in live]))
             for j, a in zip(live, answers):
                 out[j] = a
         return out
@@ -1233,7 +1247,8 @@ class AsyncQueryServer:
             abs_deadline = time.monotonic() + float(deadline)
         payload: QueryLike = (ids, weights)
         fut = ServeFuture()
-        tr = self._core.obs.tracer.admit()
+        fut.t_admit = time.perf_counter()
+        tr = self._core.obs.tracer.admit(fut.t_admit)
         with self._lock:
             if self._closed:
                 raise ServerClosed("submit() on a closed AsyncQueryServer")
@@ -1591,7 +1606,8 @@ class AsyncQueryServer:
             else:
                 out.append(a)
         self._crash_victims = []
-        self._resolve(futures, out)
+        with self._core.obs.span("deliver", batch=handle.seq):
+            self._resolve(futures, out)
 
     def _oldest_ready(self) -> bool:
         if not self._inflight:
@@ -1601,28 +1617,38 @@ class AsyncQueryServer:
         return bool(getattr(dists, "is_ready", lambda: True)())
 
     def _run(self) -> None:
+        core = self._core
+        waited = 0.0    # worker wait since the last batch left the queue
         while True:
-            batch, expired = self._next_batch(
-                have_inflight=bool(self._inflight),
-                inflight_ready=self._oldest_ready)
+            with core.obs.span("wait", batch=core.next_seq,
+                               observe=False) as wait:
+                batch, expired = self._next_batch(
+                    have_inflight=bool(self._inflight),
+                    inflight_ready=self._oldest_ready)
+            waited += wait.seconds
             if expired:
                 self._expire(expired)
                 continue
             if batch is not None:
+                core.obs.observe_stage("wait", waited)
+                waited = 0.0
                 # The batch leaves the queue HERE: queue_wait ends and
                 # host prep (batch_formation) starts now, not after
                 # _prep_entries — otherwise vectorize time (the very cost
                 # the ingest pool removes) hides inside queue_wait.
                 t_pop = time.perf_counter()
-                qs, futures, deadlines, traces = self._prep_entries(batch)
+                with core.obs.span("prep", batch=core.next_seq):
+                    qs, futures, deadlines, traces = self._prep_entries(batch)
                 if qs:
                     with self._lock:
                         depth = len(self._queue)
                     self._crash_victims = futures
                     try:
-                        handle = self._core.dispatch(
+                        handle = core.dispatch(
                             qs, queue_depth=depth, corpus_id=batch[0][3],
-                            traces=traces, t_dequeue=t_pop, t_prep0=t_pop)
+                            traces=traces,
+                            admitted=[f.t_admit for f in futures],
+                            t_dequeue=t_pop, t_prep0=t_pop)
                     except Exception as e:  # typed forwarding; crashes escape
                         err = _as_serving_error(e, "batch dispatch failed")
                         self._crash_victims = []
