@@ -161,6 +161,63 @@ def _phase2_partial(
         return jnp.einsum("nh,nhb->nb", w, zg)
 
 
+def _phase2_slot_sum(
+    r_ids: Array, r_w: Array, z_local: Array, v_local: int
+) -> Array:
+    """:func:`_phase2_partial` summed over slots in slot order.
+
+    A row's sum is then the same whatever number of trailing zero-weight
+    slots the slab's width class gives it (each adds an exact 0), which a
+    reduction that XLA associates by the slab's shape would not promise.
+    """
+    with jax.named_scope("phase2"):
+        mi = jax.lax.axis_index(MODEL_AXIS)
+        lo = (mi * v_local).astype(jnp.int32)
+        rel = r_ids.T - lo                               # (h, n_l): slot-major
+        inb = (rel >= 0) & (rel < v_local)
+        zg = z_local[jnp.clip(rel, 0, v_local - 1)]      # (h, n_l, B)
+        w = jax.lax.broadcast_in_dim(
+            r_w.T * inb.astype(r_w.dtype), zg.shape, (0, 1))
+        # lax ops, not jnp's: each jnp operator call traces a jaxpr of its own.
+        acc = jax.lax.mul(w[0], zg[0])
+        for j in range(1, zg.shape[0]):
+            acc = jax.lax.add(acc, jax.lax.mul(w[j], zg[j]))
+        return acc
+
+
+def _slab_branch(r_ids, r_w, z_local, *, wd: int, v_span: int) -> Array:
+    """One width class of a slab: phase 2 over its first ``wd`` slots."""
+    return _phase2_slot_sum(r_ids[:, :wd], r_w[:, :wd], z_local, v_span)
+
+
+def _width_classes(h1: int) -> tuple[int, ...]:
+    """The slot widths a phase-2 slab may gather: multiples of 8, and h1."""
+    return tuple(sorted({min(h1, w) for w in range(8, h1 + 8, 8)}))
+
+
+def _length_order(
+    r_w: np.ndarray, n_shards: int, blk: int, widths: tuple[int, ...]
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Length-ordered placement of one segment's padded rows (host).
+
+    Each data shard's contiguous row range is stable-sorted by filled
+    length (the last nonzero weight's slot, plus 1).  Returns ``perm``
+    (the source row of each placed row), ``row_map`` (int32, each placed
+    row's shard-local source row) and ``cls`` (int32, per ``blk``-row
+    slab: the index in ``widths`` of the narrowest width that holds its
+    longest row).
+    """
+    n, h1 = r_w.shape
+    nz = r_w != 0
+    length = np.where(nz.any(axis=1), h1 - np.argmax(nz[:, ::-1], axis=1), 0)
+    by_shard = length.reshape(n_shards, n // n_shards)
+    order = np.argsort(by_shard, axis=1, kind="stable")
+    perm = (order + (np.arange(n_shards) * (n // n_shards))[:, None]).ravel()
+    slab_len = np.take_along_axis(by_shard, order, 1).reshape(-1, blk).max(1)
+    cls = np.searchsorted(np.asarray(widths), slab_len)
+    return perm, order.ravel().astype(np.int32), cls.astype(np.int32)
+
+
 def build_serve_step(
     mesh: jax.sharding.Mesh,
     *,
@@ -670,7 +727,8 @@ def _build_engine_serve_step(
 
 
 def _segmented_step(
-    mesh, *, kc, rbs, gs, self_exclude, bf16_matmul, phase1_full_mesh,
+    mesh, *, kc, rbs, gs, widths, self_exclude, bf16_matmul,
+    phase1_full_mesh,
 ):
     """Compiled segmented shard step (one per segment-shape signature).
 
@@ -684,9 +742,15 @@ def _segmented_step(
     per-segment slab geometries (their length fixes the segment count);
     everything else — tensors, live masks, id offsets — is traced, so
     deletes and same-shape delta appends reuse the cached trace.
+
+    Rows arrive length-ordered (:func:`_length_order`): each slab carries
+    a traced class, an index into its segment's ``widths``, and gathers
+    only that many ELL slots; each row's traced ``row_map`` entry names
+    its shard-local source row, so global ids are unchanged.  One program
+    serves every draw of lengths.
     """
-    key = ("seg", _mesh_key(mesh), kc, rbs, gs, self_exclude, bf16_matmul,
-           phase1_full_mesh)
+    key = ("seg", _mesh_key(mesh), kc, rbs, gs, widths, self_exclude,
+           bf16_matmul, phase1_full_mesh)
     step = _STEP_CACHE.get(key)
     if step is not None:
         return step
@@ -712,8 +776,8 @@ def _segmented_step(
             offset = offset * mesh.shape[a] + jax.lax.axis_index(a)
         return offset * n_local
 
-    def kernel(seg_rids, seg_rw, seg_live, seg_offs, t_q, q_valid, q_gid,
-               seg_embs):
+    def kernel(seg_rids, seg_rw, seg_live, seg_map, seg_cls, seg_offs, t_q,
+               q_valid, q_gid, seg_embs):
         b = t_q.shape[0]
         total_local = sum(r.shape[0] for r in seg_rids)
         stk = StreamingTopK(min(kc, total_local))
@@ -723,7 +787,7 @@ def _segmented_step(
             n_local, h1 = rids.shape
             z_local, v_span = _z_and_span(t_q, q_valid, seg_embs[s])
             # Rows of this shard's slice of segment s own the global ids
-            # [seg_offs[s] + shard_off, ...) — offsets are traced, so
+            # seg_offs[s] + shard_off + row_map — offsets are traced, so
             # compaction's offset rewrite reuses the cached trace too.
             row0 = seg_offs[s] + _shard_offset(n_local)
             blk = rbs[s] * gs[s]
@@ -731,13 +795,17 @@ def _segmented_step(
             ids_b = rids.reshape(nb, blk, h1)
             w_b = rw.reshape(nb, blk, h1)
             live_b = live.reshape(nb, blk)
-            los = row0 + jnp.arange(nb, dtype=jnp.int32) * blk
+            map_b = seg_map[s].reshape(nb, blk)
+            branches = [functools.partial(_slab_branch, wd=wd, v_span=v_span)
+                        for wd in widths[s]]
 
-            def body(carry, xs, z_local=z_local, v_span=v_span, blk=blk):
-                ids_blk, w_blk, live_blk, lo = xs
-                partial = _phase2_partial(ids_blk, w_blk, z_local, v_span)
+            def body(carry, xs, z_local=z_local, row0=row0,
+                     branches=branches):
+                ids_blk, w_blk, live_blk, map_blk, cls = xs
+                partial = jax.lax.switch(cls, branches, ids_blk, w_blk,
+                                         z_local)
                 d_blk = jax.lax.psum(partial, MODEL_AXIS)    # (g·rb, B)
-                row = lo + jnp.arange(blk, dtype=jnp.int32)  # GLOBAL ids
+                row = row0 + map_blk                         # GLOBAL ids
                 d_blk = jnp.where(live_blk[:, None], d_blk, _INF)
                 if self_exclude:
                     d_blk = jnp.where(
@@ -748,7 +816,7 @@ def _segmented_step(
             # inside it is `topk_fold`.
             with jax.named_scope("phase2"):
                 carry, _ = jax.lax.scan(
-                    body, carry, (ids_b, w_b, live_b, los))
+                    body, carry, (ids_b, w_b, live_b, map_b, seg_cls[s]))
         tk = crossshard_topk(carry, kc, axis_names=batch_axes)
         return tk.dists, tk.indices
 
@@ -759,16 +827,17 @@ def _segmented_step(
     seg = lambda spec: tuple(spec for _ in range(n_segments))  # noqa: E731
     shmapped = compat_shard_map(
         kernel, mesh=mesh,
-        in_specs=(seg(rspec), seg(rspec), seg(lspec), P(None),
-                  P(None, None, None), P(None, None), P(None), seg(espec)),
+        in_specs=(seg(rspec), seg(rspec), seg(lspec), seg(lspec), seg(lspec),
+                  P(None), P(None, None, None), P(None, None), P(None),
+                  seg(espec)),
         out_specs=(P(None, None), P(None, None)),
     )
 
     @jax.jit
-    def step(seg_rids, seg_rw, seg_live, seg_offs, t_q, q_valid, q_gid,
-             seg_embs):
-        tk_d, tk_i = shmapped(seg_rids, seg_rw, seg_live, seg_offs,
-                              t_q, q_valid, q_gid, seg_embs)
+    def step(seg_rids, seg_rw, seg_live, seg_map, seg_cls, seg_offs, t_q,
+             q_valid, q_gid, seg_embs):
+        tk_d, tk_i = shmapped(seg_rids, seg_rw, seg_live, seg_map, seg_cls,
+                              seg_offs, t_q, q_valid, q_gid, seg_embs)
         return TopK(tk_d, tk_i)
 
     step = _sentinel.wrap(
@@ -788,8 +857,15 @@ def _build_segmented_serve_step(
     embedding shards) are placed on the mesh lazily and re-placed whenever
     ``engine.version`` changes, so the SAME callable keeps serving across
     ingest/delete/compact — no rebuild, and no re-trace unless the segment
-    shape signature is new.  Tier-2 centroids are refreshed on the same
+    shape signature is new.  A segment's rows are placed length-ordered
+    within each data shard (:func:`_length_order`) once; a delete only
+    re-permutes its live mask.  Tier-2 centroids are refreshed on the same
     version check with tombstoned rows pushed to an unreachable distance.
+
+    Each batch the step serves adds the ELL slots phase 2 gathers and the
+    slots among them that hold a word to the registry counters
+    ``serving_phase2_gathered_slots_total`` and
+    ``serving_phase2_filled_slots_total``.
     """
     from jax.sharding import NamedSharding
 
@@ -799,43 +875,63 @@ def _build_segmented_serve_step(
              else P(MODEL_AXIS, None))
     emb_shards = n_model * (n_batch_shards if phase1_full_mesh else 1)
     state: dict = {"version": None}
+    placed: dict = {}   # id(segment) -> (segment, its placement)
     obs = obs if obs is not None else _NO_OBS
+    gathered_slots = obs.metrics.counter(
+        "serving_phase2_gathered_slots_total",
+        "ELL slots gathered by phase 2 of the segmented serve step")
+    filled_slots = obs.metrics.counter(
+        "serving_phase2_filled_slots_total",
+        "ELL slots gathered by phase 2 that hold a word")
+
+    def _place(seg) -> dict:
+        rb_s, g_s, row_mult = _slab_geometry(
+            seg.n_rows, n_batch_shards, row_block, psum_batch, True)
+        r_ids = np.asarray(_pad_rows_mult(seg.tensors.r_ids, row_mult))
+        r_w = np.asarray(_pad_rows_mult(seg.tensors.r_w, row_mult))
+        widths = _width_classes(r_w.shape[1])
+        perm, row_map, cls = _length_order(
+            r_w, n_batch_shards, rb_s * g_s, widths)
+        put = lambda x, spec: jax.device_put(  # noqa: E731
+            x, NamedSharding(mesh, spec))
+        return dict(
+            rb=rb_s, g=g_s, widths=widths, perm=perm,
+            rids=put(r_ids[perm], rspec), rw=put(r_w[perm], rspec),
+            map=put(row_map, lspec), cls=put(cls, lspec),
+            emb=put(_pad_rows_mult(seg.tensors.emb_r, emb_shards), espec),
+            gathered=rb_s * g_s * int(np.take(widths, cls).sum()),
+            filled=int(np.count_nonzero(r_w)))
 
     def _refresh():
         if state["version"] == engine.version:
             return
         if not engine.segments:
             raise ValueError("segmented serve step needs a non-empty engine")
-        rbs, gs, rids, rw, live, embs, offs = [], [], [], [], [], [], []
-        for seg, lv in zip(engine.segments, engine._live):
-            rb_s, g_s, row_mult = _slab_geometry(
-                seg.n_rows, n_batch_shards, row_block, psum_batch, True)
-            lv_pad = np.zeros(
-                seg.n_rows + (-seg.n_rows) % row_mult, dtype=bool)
+        now = {}
+        for seg in engine.segments:
+            now[id(seg)] = placed.get(id(seg)) or (seg, _place(seg))
+        placed.clear()
+        placed.update(now)
+        segs = [now[id(seg)][1] for seg in engine.segments]
+        live = []
+        for seg, lv, p in zip(engine.segments, engine._live, segs):
+            lv_pad = np.zeros(p["perm"].size, dtype=bool)
             lv_pad[:seg.n_rows] = lv
-            rbs.append(rb_s)
-            gs.append(g_s)
-            rids.append(jax.device_put(
-                _pad_rows_mult(seg.tensors.r_ids, row_mult),
-                NamedSharding(mesh, rspec)))
-            rw.append(jax.device_put(
-                _pad_rows_mult(seg.tensors.r_w, row_mult),
-                NamedSharding(mesh, rspec)))
-            live.append(jax.device_put(
-                jnp.asarray(lv_pad), NamedSharding(mesh, lspec)))
-            embs.append(jax.device_put(
-                _pad_rows_mult(seg.tensors.emb_r, emb_shards),
-                NamedSharding(mesh, espec)))
-            offs.append(seg.offset)
+            live.append(jax.device_put(lv_pad[p["perm"]],
+                                       NamedSharding(mesh, lspec)))
         state["step"] = _segmented_step(
-            mesh, kc=kc, rbs=tuple(rbs), gs=tuple(gs),
+            mesh, kc=kc, rbs=tuple(p["rb"] for p in segs),
+            gs=tuple(p["g"] for p in segs),
+            widths=tuple(p["widths"] for p in segs),
             self_exclude=self_exclude, bf16_matmul=bf16_matmul,
             phase1_full_mesh=phase1_full_mesh)
-        state["rids"] = tuple(rids)
-        state["rw"] = tuple(rw)
+        for f in ("rids", "rw", "map", "cls", "emb"):
+            state[f] = tuple(p[f] for p in segs)
         state["live"] = tuple(live)
-        state["embs"] = tuple(embs)
-        state["offs"] = jnp.asarray(offs, dtype=jnp.int32)
+        state["offs"] = jnp.asarray([seg.offset for seg in engine.segments],
+                                    dtype=jnp.int32)
+        state["slots"] = (sum(p["gathered"] for p in segs),
+                          sum(p["filled"] for p in segs))
         # Tier-2 WCD shortlist: per-segment centroids from the pre-gathered
         # resident embeddings; tombstoned rows sit at distance ~1e18 so the
         # shortlist can never surface them.
@@ -869,8 +965,11 @@ def _build_segmented_serve_step(
                                tier=tier)
         with obs.span("step_launch"):
             tk = state["step"](
-                state["rids"], state["rw"], state["live"], state["offs"],
-                t_q, q_valid, q_gid, state["embs"])
+                state["rids"], state["rw"], state["live"], state["map"],
+                state["cls"], state["offs"], t_q, q_valid, q_gid,
+                state["emb"])
+        gathered_slots.inc(state["slots"][0])
+        filled_slots.inc(state["slots"][1])
         if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
             return ServeResult(
                 topk=TopK(tk.dists[:, :k], tk.indices[:, :k]),

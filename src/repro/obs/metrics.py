@@ -242,12 +242,13 @@ class MetricsRegistry:
                 out[name] = {"kind": fam["kind"], "help": fam["help"],
                              "series": series}
         # Percentiles take the lock per histogram; compute them outside the
-        # snapshot lock to keep its critical section dict-copy-short.
+        # snapshot lock to keep its critical section dict-copy-short, over
+        # the children copied under it (a series registered since then
+        # would change the live dict while it is iterated).
         for name, fam in out.items():
             if fam["kind"] != "histogram":
                 continue
-            for entry, ch in zip(fam["series"],
-                                 self._families[name]["children"].values()):
+            for entry, ch in zip(fam["series"], fams[name]["children"]):
                 entry["p50"] = ch.percentile(0.50)
                 entry["p95"] = ch.percentile(0.95)
                 entry["p99"] = ch.percentile(0.99)

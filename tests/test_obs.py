@@ -154,6 +154,27 @@ def test_snapshot_is_jsonable():
     json.dumps(obs.snapshot())   # must not raise
 
 
+def test_snapshot_survives_a_series_registered_meanwhile(monkeypatch):
+    """A series registered while the snapshot computes percentiles (outside
+    its lock) neither breaks the snapshot nor enters it half-made."""
+    reg = MetricsRegistry()
+    for stage in ("a", "b"):
+        reg.histogram("h", labels={"stage": stage}).observe(0.5)
+    hist_cls = type(reg.histogram("h", labels={"stage": "a"}))
+    percentile = hist_cls.percentile
+
+    def registering(self, p):
+        reg.histogram("h", labels={"stage": f"new{p}"}).observe(1.0)
+        return percentile(self, p)
+
+    monkeypatch.setattr(hist_cls, "percentile", registering)
+    snap = reg.snapshot()
+    assert [s["labels"] for s in snap["h"]["series"]] == [
+        {"stage": "a"}, {"stage": "b"}]
+    assert snap["h"]["series"][0]["p50"] == pytest.approx(
+        percentile(reg.histogram("h", labels={"stage": "a"}), 0.5))
+
+
 def test_event_ring_is_bounded():
     log = EventLog(maxlen=4)
     for i in range(10):

@@ -8,6 +8,7 @@ so they say nothing about results or times.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,18 +76,22 @@ def test_sinkhorn_kernel_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_served_segmented_step_fits_one_chip(topo):
+@pytest.fixture(scope="module")
+def served_step(topo):
+    """The segmented serve step at the smoke corpus's sizes, compiled for
+    one described v5e chip."""
     from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-    from repro.distributed.lcrwmd_dist import _segmented_step, _slab_geometry
+    from repro.distributed.lcrwmd_dist import (
+        _segmented_step, _slab_geometry, _width_classes)
     from repro.launch.mesh import DATA_AXIS, MODEL_AXIS
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                 (DATA_AXIS, MODEL_AXIS), axis_types=(AxisType.Auto,) * 2)
     rb, g, _ = _slab_geometry(N_DOCS, 1, 128, 8, True)
     step = _segmented_step(mesh, kc=16, rbs=(rb,), gs=(g,),
-                           self_exclude=False, bf16_matmul=False,
-                           phase1_full_mesh=True)
+                           widths=(_width_classes(H),), self_exclude=False,
+                           bf16_matmul=False, phase1_full_mesh=True)
 
     def s(shape, dtype, *spec):
         return jax.ShapeDtypeStruct(shape, dtype,
@@ -95,11 +100,25 @@ def test_served_segmented_step_fits_one_chip(topo):
     args = ((s((N_DOCS, H), jnp.int32, DATA_AXIS, None),),
             (s((N_DOCS, H), jnp.float32, DATA_AXIS, None),),
             (s((N_DOCS,), jnp.bool_, DATA_AXIS),),
+            (s((N_DOCS,), jnp.int32, DATA_AXIS),),
+            (s((N_DOCS // (rb * g),), jnp.int32, DATA_AXIS),),
             s((1,), jnp.int32, None),
             s((B, H, M), jnp.float32, None, None, None),
             s((B, H), jnp.float32, None, None),
             s((B,), jnp.int32, None),
             (s((V_E, M), jnp.float32, (MODEL_AXIS, DATA_AXIS), None),))
-    mem = step.lower(*args).compile().memory_analysis()
+    return step.lower(*args).compile()
+
+
+def test_served_segmented_step_fits_one_chip(served_step):
+    mem = served_step.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB of 16 GiB"
+
+
+def test_width_class_branches_read_z_in_place(served_step):
+    """Z enters each slab's width-class branch by reference: its one copy
+    is the layout change after phase 1, none per slab."""
+    text = served_step.as_text()
+    assert "conditional(" in text
+    assert len(re.findall(rf"= f32\[{V_E},{B}\]\S* copy\(", text)) <= 1
