@@ -792,29 +792,34 @@ def _segment_phase1(
     )
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _segmented_rerank(
-    k: int, sink_items: tuple, use_kernel: bool, bf16_matmul: bool,
-    t1: Array, w1: Array, t_q: Array, q_w: Array,
-    cand_idx: Array, cand_valid: Array,
+    k: int, sink_items: tuple, emb: Array, ids1: Array, w1: Array,
+    t_q: Array, q_w: Array, cand_idx: Array, cand_valid: Array,
 ):
-    """Sinkhorn re-rank over pre-gathered candidates with a validity mask.
+    """Sinkhorn re-rank of gathered candidates with a validity mask.
 
-    Invalid candidates (empty top-k slots, tombstoned docs) get +inf WMD so
-    they can never displace a live candidate.  With an all-True mask this is
-    value-identical to :meth:`LCRWMDEngine.rerank_topk`.
+    ``ids1``/``w1`` (B·budget, h1) are the candidates' histograms, query
+    major; their word embeddings are gathered from ``emb`` here, inside the
+    scope ``rerank_cost`` with the cost.  Invalid candidates (empty top-k
+    slots, tombstoned docs) and the candidates of padding queries solve as
+    empty pairs and get +inf WMD, so they can never displace a live
+    candidate.  Returns the (B, k) :class:`~repro.core.topk.TopK` and the
+    batch's :func:`repro.core.wmd.sinkhorn_work` sums.
     """
     from repro.core import topk as topk_lib
-    from repro.core.wmd import wmd_candidate_values
+    from repro.core.wmd import candidate_sinkhorn, sinkhorn_work
 
     with jax.named_scope("rerank"):
-        vals = wmd_candidate_values(
-            t1, w1, t_q, q_w,
-            use_kernel=use_kernel, bf16_matmul=bf16_matmul,
-            **dict(sink_items),
-        )
-        vals = jnp.where(cand_valid.reshape(vals.shape), vals, _INF)
-        return topk_lib.topk_from_candidates(vals, cand_idx, k)
+        with jax.named_scope("rerank_cost"):
+            t1 = emb[ids1]                               # (B·budget, h1, m)
+        solved = cand_valid & jnp.any(q_w > 0, axis=1)[:, None]
+        w1 = jnp.where(solved.reshape(-1, 1), w1, 0.0)
+        res = candidate_sinkhorn(t1, w1, t_q, q_w, **dict(sink_items))
+        vals = jnp.where(cand_valid, res.cost.reshape(cand_valid.shape), _INF)
+        work = sinkhorn_work(
+            res, w1, jnp.repeat(q_w, cand_valid.shape[1], axis=0))
+        return topk_lib.topk_from_candidates(vals, cand_idx, k), work
 
 
 class EngineSegment:
@@ -1139,30 +1144,30 @@ class SegmentedEngine:
         return self._dense(queries, symmetric=True)
 
     def rerank_topk(self, queries: DocSet, cand_indices: Array, k: int,
-                    *, sinkhorn_kw: dict | None = None):
+                    *, sinkhorn_kw: dict | None = None,
+                    with_work: bool = False):
         """Batched Sinkhorn-WMD re-rank of global candidate doc ids.
 
         Same contract as :meth:`LCRWMDEngine.rerank_topk`; empty (-1) and
-        tombstoned candidates are masked to +inf WMD.  The candidate gathers
-        run eagerly at fixed (B, budget) shapes, so corpus churn (which
-        changes ``n_docs``) never re-traces the jitted solve.
+        tombstoned candidates are masked to +inf WMD.  The candidates'
+        histograms are gathered eagerly at fixed (B, budget) shapes, so
+        corpus churn (which changes ``n_docs``) never re-traces the jitted
+        solve.  ``with_work`` also returns the solve's per-batch sums
+        (:func:`repro.core.wmd.sinkhorn_work`, a device array).
         """
         items = tuple(sorted((sinkhorn_kw or {}).items()))
         res = self.resident
         n = self.n_docs
         cand = jnp.asarray(cand_indices)
         safe = jnp.clip(cand.reshape(-1), 0, n - 1)
-        ids1 = res.ids[safe]                                 # (B*budget, h1)
-        t1 = self.emb_full[ids1.reshape(-1)].reshape(
-            ids1.shape[0], ids1.shape[1], -1)
-        w1 = res.weights[safe]
         cand_valid = (cand >= 0) & jnp.take(
             self.live_mask_device(), jnp.clip(cand, 0, n - 1))
-        return _segmented_rerank(
-            k, items, self.use_kernel, self.bf16_matmul,
-            t1, w1, self.gather_queries(queries.ids), queries.weights,
-            cand, cand_valid,
+        tk, work = _segmented_rerank(
+            k, items, self.emb_full, res.ids[safe], res.weights[safe],
+            self.gather_queries(queries.ids), queries.weights, cand,
+            cand_valid,
         )
+        return (tk, work) if with_work else tk
 
     # -- corpus-analytics (query-tile) entry points ------------------------
     def resident_tile(self, idx: Array) -> DocSet:

@@ -15,13 +15,12 @@ log-kernel), which zeroes their transport plan mass exactly.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.distances import dists
 from repro.data.docs import DocSet
 
 Array = jax.Array
@@ -32,6 +31,8 @@ class SinkhornResult(NamedTuple):
     cost: Array       # ⟨P, C⟩ transport cost (the WMD estimate)
     n_iters: Array    # iterations executed (across all ε levels)
     marginal_err: Array  # final L1 violation of the row marginal
+    loop_iters: Array | None = None  # (levels,) sweeps of the batched
+    #                                 loop (batched solver only)
 
 
 def _logsumexp(x: Array, axis: int) -> Array:
@@ -40,6 +41,26 @@ def _logsumexp(x: Array, axis: int) -> Array:
     return jnp.squeeze(m, axis) + jnp.log(
         jnp.sum(jnp.exp(x - m), axis=axis) + 1e-38
     )
+
+
+def _eps_levels(eps: float, eps_scaling: int, eps_start: float) -> np.ndarray:
+    """The ε ladder as float32 constants: geometric from ``eps_start``."""
+    if eps_scaling <= 1:
+        return np.array([eps], np.float32)
+    return np.geomspace(eps_start, eps, eps_scaling).astype(np.float32)
+
+
+def word_costs(t1: Array, t2: Array) -> Array:
+    """(…, h1, h2) word distances ‖t1_i − t2_j‖ in direct form.
+
+    ``t1`` (…, h1, m) and ``t2`` (…, h2, m) broadcast over their leading
+    axes.  Unlike the Gram expansion of :func:`repro.core.distances.dists`,
+    a word shared by both documents costs exactly 0 here; the expansion
+    leaves it at ≈ sqrt(f32 rounding of ‖a‖²) (≈ 0.04 at Set-2 widths),
+    which ε = 0.02 turns into a visible change of the plan.
+    """
+    d = t1[..., :, None, :] - t2[..., None, :, :]
+    return jnp.sqrt(jnp.sum(d * d, axis=-1))
 
 
 def sinkhorn_log(
@@ -123,159 +144,97 @@ def sinkhorn_log_batched(
     eps_start: float = 1.0,
     max_iters: int = 500,
     tol: float = 1e-5,
-    absorb_every: int = 4,
 ) -> SinkhornResult:
-    """Batched stabilized Sinkhorn with ε-scaling over a leading pairs axis.
+    """Batched log-domain Sinkhorn with ε-scaling over a leading pairs axis.
 
-    a:(P,h1), b:(P,h2), cost:(P,h1,h2).  All P problems share ONE
+    a:(P,h1), b:(P,h2), cost:(P,h1,h2).  Zero-mass entries (padding) are
+    excluded via −inf log-marginals.  All P problems share ONE
     ``while_loop`` per ε level with **per-pair convergence masks**: a pair
-    whose row-marginal violation drops below ``tol`` freezes its scalings
-    (and its iteration counter) while the still-live pairs keep iterating, so
-    the result matches P independent :func:`sinkhorn_log` solves but a
-    single slow pair no longer serializes the rest.
+    whose row-marginal L1 violation drops to ``tol`` freezes its potentials
+    (and its iteration counter) while the still-live pairs keep iterating,
+    so each pair's result is that of a solve on its own.  A level ends when
+    every pair has stopped or after ``max_iters`` sweeps; the final plan's
+    rows are rescaled to ``a`` (the rounding step of Altschuler et al.
+    2017) and the *unregularized* cost ⟨P, C⟩ is returned.
 
-    Unlike the scalar reference, the hot loop runs in the **stabilized
-    exp domain** (Sinkhorn-Knopp with log-domain absorption, the parallel
-    formulation of Tithi & Petrini 2020/2021): each iteration is two batched
-    kernel matvecs ``K v`` / ``Kᵀ u`` plus elementwise divisions — zero
-    transcendentals — and every ``absorb_every`` iterations the scalings
-    ``u, v`` are absorbed into the log-domain potentials ``f, g`` and the
-    kernel matrix is refreshed, which reproduces the log-domain iterates
-    exactly (same update map, same per-iteration marginal-error stopping
-    rule) while keeping f32 magnitudes bounded.
+    Every sweep is the log-domain update f = ε(log a − LSE_j((g − C)/ε)),
+    g = ε(log b − LSE_i((f − C)/ε)).  An exp-domain iteration (K v, Kᵀ u on
+    a kernel refreshed every few sweeps) is not the same map here: at
+    ε = 0.02 and word distances of 20–110, whole columns of exp(−C/ε)
+    underflow between refreshes, and the clamped scalings then move the
+    potentials by less than the log-domain step.  The row marginal of a
+    sweep, exp(f/ε + LSE_j((g − C)/ε)), is the next sweep's f-update
+    LSE, so each sweep reads the cost twice, not three times.
 
-    Returns a :class:`SinkhornResult` of per-pair (P,) arrays.
+    Arrays are held pairs-minor — (h1, h2, P) — so the pairs axis fills the
+    vector lanes and both reductions run across whole registers.
+
+    Returns a :class:`SinkhornResult` of per-pair (P,) arrays, with
+    ``loop_iters`` the (levels,) sweeps of the shared loop.
     """
-    p, h1 = a.shape
-    h2 = b.shape[1]
+    levels = _eps_levels(eps, eps_scaling, eps_start)
+    a, b = a.T, b.T                                    # (h1, P), (h2, P)
     valid_a = a > 0
     valid_b = b > 0
-    big = jnp.where(
-        valid_a[:, :, None] & valid_b[:, None, :], cost, jnp.inf
-    )  # (P, h1, h2)  — masked slots get K = exp(-inf) = 0 exactly
+    log_a = jnp.where(valid_a, jnp.log(jnp.maximum(a, 1e-38)), _NEG_INF)
+    log_b = jnp.where(valid_b, jnp.log(jnp.maximum(b, 1e-38)), _NEG_INF)
+    # Mask padding in the cost so exp(-C/eps) underflows to 0 there.
+    big = jnp.where(valid_a[:, None, :] & valid_b[None, :, :],
+                    jnp.transpose(cost, (1, 2, 0)), jnp.inf)  # (h1, h2, P)
+    p = a.shape[1]
 
-    if eps_scaling <= 1:
-        eps_levels = jnp.array([eps], dtype=jnp.float32)
-    else:
-        eps_levels = jnp.geomspace(eps_start, eps, eps_scaling).astype(jnp.float32)
+    f = jnp.zeros(a.shape, jnp.float32)
+    g = jnp.zeros(b.shape, jnp.float32)
+    iters = jnp.zeros((p,), jnp.int32)
+    loop_iters, err = [], None
+    for lev in levels:
+        lev = jnp.float32(lev)
 
-    def run_level(carry, level_eps):
-        f, g, it_total = carry
+        def lse_f(g, lev=lev):       # LSE_j((g − C)/ε): (h1, P)
+            return _logsumexp((g[None, :, :] - big) / lev, 1)
 
-        def refresh(f, g):
-            """Row-max-stabilized kernel: K'[i,:] = exp(lk[i,:] - m[i]).
-
-            Every live row's max entry is exactly 1, so ``K' v`` never
-            underflows to a zero row (the log-domain LSE trick applied once
-            per refresh instead of once per iteration).  The stored row
-            scaling is ``w = u * exp(m)``: the u-update ``w' = a / (K' v)``
-            and v-update ``t = K'ᵀ w'`` are then algebraically identical to
-            the unscaled iteration, and ``w ⊙ (K' v)`` IS the true row
-            marginal.
-            """
-            lk = (f[:, :, None] + g[:, None, :] - big) / level_eps
-            m = jnp.max(lk, axis=2)
-            m = jnp.where(m > -1e35, m, 0.0)  # fully-masked rows
-            return jnp.exp(lk - m[:, :, None]), m
-
-        kmat0, m0 = refresh(f, g)
-        w0 = jnp.ones((p, h1), jnp.float32)
-        v0 = jnp.ones((p, h2), jnp.float32)
-        s0 = jnp.sum(kmat0, axis=2)  # K' v with v = 1
+        def body(state, lev=lev, lse_f=lse_f):
+            f, g, lf, it_pair, it, err = state
+            live = err > tol  # (P,) pairs still iterating at this level
+            f2 = jnp.where(valid_a, lev * (log_a - lf), _NEG_INF)
+            g2 = jnp.where(valid_b, lev * (log_b - _logsumexp(
+                (f2[:, None, :] - big) / lev, 0)), _NEG_INF)
+            lf2 = lse_f(g2)
+            # Row marginal under (f2, g2): exp(f2/ε + LSE_j((g2 − C)/ε)).
+            row = jnp.where(valid_a, jnp.exp(f2 / lev + lf2), 0.0)
+            err2 = jnp.sum(jnp.abs(row - a), axis=0)
+            keep = live[None, :]
+            return (jnp.where(keep, f2, f), jnp.where(keep, g2, g),
+                    jnp.where(keep, lf2, lf), it_pair + live.astype(jnp.int32),
+                    it + 1, jnp.where(live, err2, err))
 
         def cond(state):
-            it, err = state[-2], state[-1]
-            return jnp.logical_and(it < max_iters, jnp.any(err > tol))
+            return jnp.logical_and(state[4] < max_iters,
+                                   jnp.any(state[5] > tol))
 
-        def body(state):
-            w, v, s, kmat, m, f, g, it_pair, it, err = state
-            live = err > tol  # (P,) pairs still iterating at this level
-            # One Sinkhorn-Knopp sweep: u-update, v-update, and the row
-            # marginal of the NEW iterate — whose matvec is also next
-            # iteration's ``s``, so the error check costs nothing extra.
-            w_new = jnp.where(valid_a, a / jnp.maximum(s, 1e-30), 0.0)
-            t = jnp.einsum("pij,pi->pj", kmat, w_new)
-            v_new = jnp.where(valid_b, b / jnp.maximum(t, 1e-30), 0.0)
-            # The min/max clamps keep a cold-start transient (columns of K'
-            # fully underflown before the first absorption re-centers the
-            # potentials) finite instead of spawning 0·inf NaNs; clamped
-            # iterates are repaired by the next log-domain refresh.
-            s_new = jnp.minimum(
-                jnp.einsum("pij,pj->pi", kmat, v_new), 3e37)
-            err_new = jnp.sum(
-                jnp.abs(jnp.minimum(w_new * s_new, 3e37) - a), axis=1)
-            # Converged pairs freeze: scalings, error and per-pair iteration
-            # counts stop exactly where the pairwise solver would stop them.
-            w = jnp.where(live[:, None], w_new, w)
-            v = jnp.where(live[:, None], v_new, v)
-            s = jnp.where(live[:, None], s_new, s)
-            err = jnp.where(live, err_new, err)
-            it_pair = it_pair + live.astype(jnp.int32)
-            it = it + 1
-
-            def absorb(args):
-                w, v, s, kmat, m, f, g = args
-                # Fold the live pairs' scalings into the potentials and
-                # refresh K'; frozen pairs keep w, v, m (their K'/m recompute
-                # is idempotent: f, g unchanged since they froze).
-                f2 = jnp.where(
-                    live[:, None] & valid_a,
-                    f + level_eps * (jnp.log(jnp.maximum(w, 1e-30)) - m), f)
-                g2 = jnp.where(
-                    live[:, None] & valid_b,
-                    g + level_eps * jnp.log(jnp.maximum(v, 1e-30)), g)
-                k2, m2 = refresh(f2, g2)
-                # True u resets to 1, stored as w = exp(m): the end-of-level
-                # fold-in (log w - m) then contributes exactly zero.  |m| is
-                # clamped so w stays finite through cold-start overshoots
-                # (the next sweep recomputes w from scratch anyway).
-                w2 = jnp.where(
-                    live[:, None], jnp.exp(jnp.clip(m2, -80.0, 80.0)), w)
-                v2 = jnp.where(live[:, None], 1.0, v)
-                m2 = jnp.where(live[:, None], m2, m)
-                s2 = jnp.einsum("pij,pj->pi", k2, v2)
-                s2 = jnp.where(live[:, None], s2, s)
-                return w2, v2, s2, k2, m2, f2, g2
-
-            w, v, s, kmat, m, f, g = jax.lax.cond(
-                it % absorb_every == 0, absorb, lambda x: x,
-                (w, v, s, kmat, m, f, g))
-            return w, v, s, kmat, m, f, g, it_pair, it, err
-
-        w, v, _, _, m, f, g, it_pair, _, err = jax.lax.while_loop(
+        f, g, _, iters, it, err = jax.lax.while_loop(
             cond, body,
-            (w0, v0, s0, kmat0, m0, f, g, jnp.zeros((p,), jnp.int32),
-             jnp.int32(0), jnp.full((p,), jnp.inf, jnp.float32)),
-        )
-        # End-of-level absorption carries pure log-domain potentials forward.
-        f = jnp.where(
-            valid_a,
-            f + level_eps * (jnp.log(jnp.maximum(w, 1e-30)) - m), _NEG_INF)
-        g = jnp.where(
-            valid_b, g + level_eps * jnp.log(jnp.maximum(v, 1e-30)), _NEG_INF)
-        return (f, g, it_total + it_pair), err
+            (f, g, lse_f(g), iters, jnp.int32(0),
+             jnp.full((p,), jnp.inf, jnp.float32)))
+        loop_iters.append(it)
 
-    f0 = jnp.zeros((p, h1), jnp.float32)
-    g0 = jnp.zeros((p, h2), jnp.float32)
-    (f, g, iters), errs = jax.lax.scan(
-        run_level, (f0, g0, jnp.zeros((p,), jnp.int32)), eps_levels
-    )
-
-    log_p = (f[:, :, None] + g[:, None, :] - big) / eps_levels[-1]
+    log_p = (f[:, None, :] + g[None, :, :] - big) / jnp.float32(levels[-1])
     # Row-max stabilization: the per-row shift cancels in the row rescale
     # below, but keeps exp() finite when an unconverged pair's potentials
     # overshoot (exp(log_p) alone can overflow to inf -> inf/inf NaNs).
-    mrow = jnp.max(log_p, axis=2, keepdims=True)
-    mrow = jnp.where(mrow > -1e35, mrow, 0.0)
+    mrow = jnp.max(log_p, axis=1, keepdims=True)
+    mrow = jnp.where(jnp.isfinite(mrow), mrow, 0.0)
     plan = jnp.exp(log_p - mrow)
-    row = jnp.sum(plan, axis=2)
+    row = jnp.sum(plan, axis=1)
     # Rescale rows to satisfy the row marginal exactly (rounding step of
     # Altschuler et al. 2017) so the reported cost is a valid feasible value.
-    plan = plan * jnp.where(valid_a, a / jnp.maximum(row, 1e-30), 0.0)[:, :, None]
+    plan = plan * jnp.where(
+        valid_a, a / jnp.maximum(row, 1e-30), 0.0)[:, None, :]
     cost_val = jnp.sum(
-        jnp.where(jnp.isfinite(big), plan * big, 0.0), axis=(1, 2)
+        jnp.where(jnp.isfinite(big), plan * big, 0.0), axis=(0, 1)
     )
-    return SinkhornResult(cost=cost_val, n_iters=iters, marginal_err=errs[-1])
+    return SinkhornResult(cost=cost_val, n_iters=iters, marginal_err=err,
+                          loop_iters=jnp.stack(loop_iters))
 
 
 def wmd_batched_from_t(
@@ -286,8 +245,7 @@ def wmd_batched_from_t(
     t1:(P,h1,m), w1:(P,h1), t2:(P,h2,m), w2:(P,h2) — builds the (P,h1,h2)
     cost stack and solves all pairs in one batched Sinkhorn.  Returns (P,).
     """
-    c = jax.vmap(dists)(t1, t2)
-    return sinkhorn_log_batched(w1, w2, c, **sink_kw).cost
+    return sinkhorn_log_batched(w1, w2, word_costs(t1, t2), **sink_kw).cost
 
 
 def wmd_batched(
@@ -297,12 +255,19 @@ def wmd_batched(
     return wmd_batched_from_t(emb[ids1], w1, emb[ids2], w2, **sink_kw)
 
 
-# Solver kwargs understood by the fused Pallas kernel; the jnp-only extras
-# are dropped when routing to it, and anything else is rejected up front so
-# a typo'd option cannot silently change behavior on one backend only.
-_KERNEL_SINK_KEYS = frozenset(
-    {"eps", "eps_scaling", "eps_start", "max_iters", "tol"})
-_JNP_ONLY_SINK_KEYS = frozenset({"absorb_every"})
+# Solver kwargs: the jnp solver and the fused Pallas kernel take the same
+# set, and anything else is rejected up front so a typo'd option cannot
+# silently change behavior on one backend only.
+_SINK_KEYS = frozenset({"eps", "eps_scaling", "eps_start", "max_iters", "tol"})
+
+#: The per-batch sums :func:`sinkhorn_work` returns, in order.
+SINKHORN_WORK = ("pairs", "cells", "words", "cell_iters", "swept_cells")
+
+
+def _check_sink_kw(sink_kw: dict) -> None:
+    unknown = set(sink_kw) - _SINK_KEYS
+    if unknown:
+        raise TypeError(f"unknown sinkhorn kwargs: {sorted(unknown)}")
 
 
 def wmd_batched_dispatch(
@@ -316,40 +281,83 @@ def wmd_batched_dispatch(
     """Backend dispatch for batched WMD from pre-gathered embeddings.
 
     The single place that maps a user ``sinkhorn_kw`` dict onto either the
-    jnp batched solver or the fused Pallas kernel (whose signature accepts
-    only :data:`_KERNEL_SINK_KEYS`); every rerank/refine path routes through
-    here so the two backends cannot drift.
+    jnp batched solver or the fused Pallas kernel; ``bf16_matmul`` applies
+    to the kernel's Gram-form cost tile only.
     """
-    unknown = set(sink_kw) - _KERNEL_SINK_KEYS - _JNP_ONLY_SINK_KEYS
-    if unknown:
-        raise TypeError(f"unknown sinkhorn kwargs: {sorted(unknown)}")
+    _check_sink_kw(sink_kw)
     if use_kernel:
         from repro.kernels import ops as kops
 
-        kw = {k: v for k, v in sink_kw.items() if k in _KERNEL_SINK_KEYS}
         return kops.sinkhorn_wmd(
             t1, w1, t2, w2, bf16_matmul=bf16_matmul, interpret=interpret,
-            **kw)
+            **sink_kw)
     return wmd_batched_from_t(t1, w1, t2, w2, **sink_kw)
 
 
-def wmd_candidate_values(
-    t1_flat: Array, w1_flat: Array, t_q: Array, q_w: Array, **dispatch_kw
-) -> Array:
-    """(B, budget) WMD values for B-major flattened candidate pairs.
+def candidate_sinkhorn(
+    t1_flat: Array, w1_flat: Array, t_q: Array, q_w: Array, **sink_kw
+) -> SinkhornResult:
+    """Batched Sinkhorn over B-major flattened candidate pairs.
 
     t1_flat/w1_flat: (B·budget, h1[, m]) candidate word embeddings+weights
-    in query-major order (row ``q*budget + c`` is query q's c-th candidate);
-    t_q/q_w: (B, h2, m)/(B, h2) query tensors, expanded here.  Shared by
-    every refine/rerank site so the pair expansion cannot drift.
+    in query-major order (row ``q*budget + c`` is query q's c-th
+    candidate); t_q/q_w: (B, h2, m)/(B, h2) query tensors.  Each query's
+    embeddings are broadcast over its ``budget`` candidates inside the cost
+    (scope ``rerank_cost``), never repeated into a (B·budget, h2, m) copy;
+    the solve is scope ``sinkhorn``.
+    """
+    _check_sink_kw(sink_kw)
+    b, h2 = q_w.shape
+    budget = t1_flat.shape[0] // b
+    with jax.named_scope("rerank_cost"):
+        t1 = t1_flat.reshape(b, budget, *t1_flat.shape[1:])
+        cost = word_costs(t1, t_q[:, None]).reshape(b * budget, -1, h2)
+    with jax.named_scope("sinkhorn"):
+        return sinkhorn_log_batched(
+            w1_flat, jnp.repeat(q_w, budget, axis=0), cost, **sink_kw)
+
+
+def sinkhorn_work(res: SinkhornResult, a: Array, b: Array) -> Array:
+    """Per-batch sums of one batched solve, float32 (5,) in the order of
+    :data:`SINKHORN_WORK`: pairs with a word on each side; their real cells
+    Σ h1·h2; their real words Σ (h1 + h2); Σ over pairs of the pair's
+    iterations (all levels) × its real cells; and the cells the shared loop
+    swept, Σ over levels of its sweeps × P × the padded h1·h2."""
+    n1 = jnp.sum(a > 0, axis=1).astype(jnp.float32)
+    n2 = jnp.sum(b > 0, axis=1).astype(jnp.float32)
+    real = (n1 > 0) & (n2 > 0)
+    cells = jnp.where(real, n1 * n2, 0.0)
+    swept = jnp.sum(res.loop_iters).astype(jnp.float32) * float(
+        a.shape[0] * a.shape[1] * b.shape[1])
+    return jnp.stack([
+        jnp.sum(real.astype(jnp.float32)), jnp.sum(cells),
+        jnp.sum(jnp.where(real, n1 + n2, 0.0)),
+        jnp.sum(res.n_iters.astype(jnp.float32) * cells), swept])
+
+
+def wmd_candidate_values(
+    t1_flat: Array, w1_flat: Array, t_q: Array, q_w: Array,
+    *,
+    use_kernel: bool = False,
+    bf16_matmul: bool = False,
+    interpret: bool | None = None,
+    **sink_kw,
+) -> Array:
+    """(B, budget) WMD values for B-major flattened candidate pairs
+    (layout as in :func:`candidate_sinkhorn`).  Shared by every
+    refine/rerank site so the pair expansion cannot drift; the fused
+    kernel takes per-pair query tensors, so only its branch repeats them.
     """
     b = t_q.shape[0]
     budget = t1_flat.shape[0] // b
-    vals = wmd_batched_dispatch(
-        t1_flat, w1_flat,
-        jnp.repeat(t_q, budget, axis=0), jnp.repeat(q_w, budget, axis=0),
-        **dispatch_kw,
-    )
+    if use_kernel:
+        vals = wmd_batched_dispatch(
+            t1_flat, w1_flat,
+            jnp.repeat(t_q, budget, axis=0), jnp.repeat(q_w, budget, axis=0),
+            use_kernel=True, bf16_matmul=bf16_matmul, interpret=interpret,
+            **sink_kw)
+    else:
+        vals = candidate_sinkhorn(t1_flat, w1_flat, t_q, q_w, **sink_kw).cost
     return vals.reshape(b, budget)
 
 
@@ -357,7 +365,7 @@ def wmd_pair(
     ids1: Array, w1: Array, ids2: Array, w2: Array, emb: Array, **sink_kw
 ) -> Array:
     """WMD (Sinkhorn) between two padded histograms; returns scalar f32."""
-    c = dists(emb[ids1], emb[ids2])
+    c = word_costs(emb[ids1], emb[ids2])
     return sinkhorn_log(w1, w2, c, **sink_kw).cost
 
 
@@ -376,7 +384,6 @@ def wmd_one_vs_many(
 # ---------------------------------------------------------------------------
 def emd_exact_lp(a, b, cost) -> float:
     """Exact EMD via scipy linprog (HiGHS). Host-side oracle, NOT jittable."""
-    import numpy as np
     from scipy.optimize import linprog
 
     a = np.asarray(a, dtype=np.float64)

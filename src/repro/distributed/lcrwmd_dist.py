@@ -115,6 +115,9 @@ class ServeResult(NamedTuple):
     #                        WMD top-k (candidate RWMD bound beat the cutoff)
     tier: int = 0     # QualityTier the batch was served at (python int,
     #                        stamped outside jit; 0 = full configured cascade)
+    rerank_work: Array | None = None  # (5,) f32 sums of the batch's WMD
+    #                        rerank (core.wmd.SINKHORN_WORK); segmented and
+    #                        routed steps with rerank_wmd only
 
 
 def _batch_axes(mesh) -> tuple[str, ...]:
@@ -336,7 +339,10 @@ def build_serve_step(
     registry); without one the spans reach the profiler only.  On the
     device, the compiled programs carry the named scopes ``phase1``,
     ``phase2``, ``topk_fold``, ``crossshard_topk``, ``refine`` and
-    ``rerank`` in their op metadata.
+    ``rerank`` (with ``rerank_cost`` and ``sinkhorn`` inside it) in their
+    op metadata.  A batch that reranks returns its solve's sums in
+    ``ServeResult.rerank_work``, which the serving core adds to its
+    registry at collect.
 
     ``index``: a :class:`repro.index.ClusterIndex` over the (segmented)
     ``engine``.  The serve step then ROUTES each batch: the index's host
@@ -979,15 +985,17 @@ def _build_segmented_serve_step(
             if refine:
                 tk = _symmetric_refine(
                     engine.resident, queries, engine.emb_full, tk)
-        exact = None
+        exact = work = None
         if rerank_wmd:
             with obs.span("rerank_launch"):
-                tk = engine.rerank_topk(queries, tk.indices, k,
-                                        sinkhorn_kw=wmd_kw)
+                tk, work = engine.rerank_topk(queries, tk.indices, k,
+                                              sinkhorn_kw=wmd_kw,
+                                              with_work=True)
                 exact = cand_max_rwmd >= tk.dists[:, -1]
                 if kc >= engine.n_live:  # candidates cover every live doc
                     exact = jnp.ones_like(exact)
-        return ServeResult(topk=tk, d_local=None, pruned_exact=exact)
+        return ServeResult(topk=tk, d_local=None, pruned_exact=exact,
+                           rerank_work=work)
 
     return serve
 
@@ -1255,11 +1263,12 @@ def _build_routed_serve_step(
             if refine:
                 tk = _symmetric_refine(
                     engine.resident, queries, engine.emb_full, tk)
-        exact = None
+        exact = work = None
         if rerank_wmd:
             with obs.span("rerank_launch"):
-                tk = engine.rerank_topk(queries, tk.indices, k,
-                                        sinkhorn_kw=wmd_kw)
+                tk, work = engine.rerank_topk(queries, tk.indices, k,
+                                              sinkhorn_kw=wmd_kw,
+                                              with_work=True)
                 # Exactness is RELATIVE TO THE ROUTED CELLS (the
                 # pipeline's index-stage contract); promote to a
                 # corpus-wide certificate only when routing provably
@@ -1269,7 +1278,8 @@ def _build_routed_serve_step(
                         and route.cells.shape[1] == index.num_cells
                         and bool(route.keep.all())):
                     exact = jnp.ones_like(exact)
-        return ServeResult(topk=tk, d_local=None, pruned_exact=exact)
+        return ServeResult(topk=tk, d_local=None, pruned_exact=exact,
+                           rerank_work=work)
 
     return serve
 

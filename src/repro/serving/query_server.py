@@ -371,6 +371,20 @@ class _ServeCore:
             "(0 until seeded by the first collected batch)")
         self._m_budget = m.gauge(
             "serving_rerank_budget", "current adaptive rerank budget")
+        # The WMD rerank's work, from the sums its program returns with
+        # each batch's answers (core.wmd.SINKHORN_WORK order).
+        self._m_rerank = tuple(m.counter(name, help_) for name, help_ in (
+            ("serving_rerank_pairs_total",
+             "candidate pairs the WMD rerank solved"),
+            ("serving_rerank_cells_total",
+             "real cost cells h_c*h_q of the solved pairs"),
+            ("serving_rerank_words_total",
+             "real words h_c + h_q of the solved pairs"),
+            ("serving_sinkhorn_cell_iters_total",
+             "per pair, its Sinkhorn iterations times its real cells"),
+            ("serving_sinkhorn_swept_cells_total",
+             "sweeps of the batched Sinkhorn loop times its padded cells"),
+        ))
         # All resident-side prep (vocab restriction, padding, placement on
         # the mesh, resident-embedding gathers) happens ONCE per corpus
         # (and once per ingested delta SEGMENT — O(delta), not O(corpus));
@@ -674,6 +688,10 @@ class _ServeCore:
         with self.obs.span("collect", batch=inflight.seq):
             tk_i = np.asarray(res.topk.indices)  # blocks on the device result
             tk_d = np.asarray(res.topk.dists)
+            work = getattr(res, "rerank_work", None)
+            if work is not None and self.obs.metrics.enabled:
+                for counter, v in zip(self._m_rerank, np.asarray(work)):
+                    counter.inc(float(v))
         if bt is not None:
             bt.span("device_compute", inflight.t_launched, time.perf_counter())
         if self.trace is not None:

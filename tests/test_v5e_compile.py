@@ -122,3 +122,31 @@ def test_width_class_branches_read_z_in_place(served_step):
     text = served_step.as_text()
     assert "conditional(" in text
     assert len(re.findall(rf"= f32\[{V_E},{B}\]\S* copy\(", text)) <= 1
+
+
+def test_wmd_rerank_fits_and_repeats_no_query_tensor(one_chip):
+    """The cascade's rerank at ``set2_wmd``'s sizes (B 64, kc 32, h 48,
+    m 300, the full vocabulary): it fits one chip, and the only
+    (B·kc·h, m) tensor it makes is the candidates' gathered embeddings —
+    each query's are broadcast over its candidates inside the cost."""
+    from repro.core.lc_rwmd import _segmented_rerank
+
+    kc, vocab = 32, 292_492
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    sink = tuple(sorted(dict(eps=0.02, eps_scaling=3, max_iters=200).items()))
+    c = _segmented_rerank.lower(
+        16, sink, s((vocab, M)), s((B * kc, H), jnp.int32), s((B * kc, H)),
+        s((B, H, M)), s((B, H)), s((B, kc), jnp.int32),
+        s((B, kc), jnp.bool_)).compile()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    entry = c.as_text().split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    big = []
+    for dims, op in re.findall(r"= f32\[([\d,]+)\]\S* (\w[\w-]*)\(", entry):
+        if op != "bitcast" and np.prod([int(d) for d in dims.split(",")]) \
+                == B * kc * H * M:
+            big.append((dims, op))
+    assert len(big) == 1, big
