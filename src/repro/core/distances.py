@@ -18,7 +18,9 @@ Array = jax.Array
 _EPS = 0.0  # distances are clamped at 0; sqrt(0) grads are guarded below.
 
 
-def sq_dists(a: Array, b: Array, *, bf16_matmul: bool = False) -> Array:
+def sq_dists(
+    a: Array, b: Array, *, bf16_matmul: bool = False, a2: Array | None = None
+) -> Array:
     """Squared Euclidean distances between rows of ``a`` (p,m) and ``b`` (q,m).
 
     Returns (p, q) float32.  ``bf16_matmul=True`` downcasts the GEMM inputs to
@@ -26,10 +28,12 @@ def sq_dists(a: Array, b: Array, *, bf16_matmul: bool = False) -> Array:
     adaptation of the paper's fp32 CUBLAS call.  The f32 GEMM asks for
     ``HIGHEST`` precision: a TPU's default f32 matmul rounds its inputs to
     bf16, and ‖a‖² + ‖b‖² − 2ab cancels that error into the distances.
+    ``a2``: the rows' squared norms of ``a`` (p,), where a caller that
+    reuses ``a`` across calls has them already.
     """
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
-    a2 = jnp.sum(a * a, axis=-1)[:, None]
+    a2 = (jnp.sum(a * a, axis=-1) if a2 is None else a2)[:, None]
     b2 = jnp.sum(b * b, axis=-1)[None, :]
     if bf16_matmul:
         ab = jax.lax.dot_general(

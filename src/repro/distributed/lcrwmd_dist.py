@@ -118,6 +118,9 @@ class ServeResult(NamedTuple):
     rerank_work: Array | None = None  # (5,) f32 sums of the batch's WMD
     #                        rerank (core.wmd.SINKHORN_WORK); segmented and
     #                        routed steps with rerank_wmd only
+    phase1_cols: Array | None = None  # (2,) int32: the columns phase 1
+    #                        computed and the query words among them;
+    #                        segmented step only
 
 
 def _batch_axes(mesh) -> tuple[str, ...]:
@@ -196,6 +199,82 @@ def _slab_branch(r_ids, r_w, z_local, *, wd: int, v_span: int) -> Array:
 def _width_classes(h1: int) -> tuple[int, ...]:
     """The slot widths a phase-2 slab may gather: multiples of 8, and h1."""
     return tuple(sorted({min(h1, w) for w in range(8, h1 + 8, 8)}))
+
+
+#: Queries per phase-1 group of the segmented step: 16 queries of a width
+#: class of 8 slots fill the MXU's 128 lanes.
+_QUERY_GROUP = 16
+
+
+def _query_groups(q_valid: Array) -> tuple[Array, Array, tuple[int, ...]]:
+    """Length order of a query batch for phase 1 (traced).
+
+    Returns ``order`` (the batch stable-sorted by filled length, longest
+    first: the last slot with ``q_valid > 0``, plus 1, as
+    :func:`_length_order`; padding queries last), ``cls`` (per group of
+    :data:`_QUERY_GROUP` ordered queries: the index in ``widths`` of the
+    narrowest width that holds its longest query) and ``widths`` (0, then
+    :func:`_width_classes`).
+
+    Every group's GEMM then has N = 16·w columns, a multiple of 128, and
+    a query's distances do not depend on its batch-mates.  A batch that
+    16 does not divide stays in its own order, in one group at the full
+    width.
+    """
+    b, h = q_valid.shape
+    widths = (0,) + _width_classes(h)
+    if b % _QUERY_GROUP:
+        return (jnp.arange(b), jnp.full((1,), len(widths) - 1, jnp.int32),
+                widths)
+    slot = jnp.arange(1, h + 1, dtype=jnp.int32)
+    length = jnp.max(jnp.where(q_valid > 0, slot, 0), axis=1)
+    order = jnp.argsort(-length, stable=True)
+    longest = length[order][::_QUERY_GROUP]
+    cls = jnp.sum(longest[:, None] > jnp.asarray(widths)[None, :], axis=1)
+    return order, cls.astype(jnp.int32), widths
+
+
+def _z_grouped(
+    emb_local: Array, t_q: Array, q_valid: Array, cls: Array,
+    widths: tuple[int, ...], *, bf16_matmul: bool = False
+) -> Array:
+    """Phase 1 over length-ordered query groups: Z (v_local, B).
+
+    Group g (``len(cls)`` equal groups of the batch's queries) computes
+    its distances over the first ``widths[cls[g]]`` slots only, in a
+    ``lax.switch`` over the width classes, and writes its columns of Z in
+    place.  A query's column is :func:`_z_from_t`'s: the slots a class
+    leaves out are padding, which the min there skips too; a width-0 group
+    holds only padding queries and writes their value.
+    """
+    v_l, m = emb_local.shape
+    b, h, _ = t_q.shape
+    n_groups = cls.shape[0]
+    size = b // n_groups
+    e2 = jnp.sum(emb_local * emb_local, axis=-1)   # once, not per group
+
+    def branch(w):
+        def run(t, valid):
+            if w == 0:
+                return jnp.full((v_l, size), safe_sqrt(jnp.float32(_INF)))
+            sq = sq_dists(emb_local, t[:, :w].reshape(size * w, m),
+                          bf16_matmul=bf16_matmul, a2=e2)
+            sq = jnp.where(valid[:, :w].reshape(-1)[None, :] > 0, sq, _INF)
+            return safe_sqrt(jnp.min(sq.reshape(v_l, size, w), axis=2))
+        return run
+
+    branches = [branch(w) for w in widths]
+
+    def body(z, xs):
+        t, valid, c, col = xs
+        z_g = jax.lax.switch(c, branches, t, valid)
+        return jax.lax.dynamic_update_slice(z, z_g, (0, col)), None
+
+    z, _ = jax.lax.scan(
+        body, jnp.zeros((v_l, b), jnp.float32),
+        (t_q.reshape(n_groups, size, h, m), q_valid.reshape(n_groups, size, h),
+         cls, jnp.arange(n_groups, dtype=jnp.int32) * size))
+    return z
 
 
 def _length_order(
@@ -341,8 +420,9 @@ def build_serve_step(
     ``phase2``, ``topk_fold``, ``crossshard_topk``, ``refine`` and
     ``rerank`` (with ``rerank_cost`` and ``sinkhorn`` inside it) in their
     op metadata.  A batch that reranks returns its solve's sums in
-    ``ServeResult.rerank_work``, which the serving core adds to its
-    registry at collect.
+    ``ServeResult.rerank_work``, and a batch of the segmented step its
+    phase-1 column counts in ``ServeResult.phase1_cols``; the serving core
+    adds both to its registry at collect.
 
     ``index``: a :class:`repro.index.ClusterIndex` over the (segmented)
     ``engine``.  The serve step then ROUTES each batch: the index's host
@@ -752,8 +832,15 @@ def _segmented_step(
     Rows arrive length-ordered (:func:`_length_order`): each slab carries
     a traced class, an index into its segment's ``widths``, and gathers
     only that many ELL slots; each row's traced ``row_map`` entry names
-    its shard-local source row, so global ids are unchanged.  One program
-    serves every draw of lengths.
+    its shard-local source row, so global ids are unchanged.  Queries are
+    length-ordered the same way inside the step (:func:`_query_groups`):
+    phase 1 computes each group's columns of Z over its width class only
+    (:func:`_z_grouped`), and the answers return in the batch's order.  One
+    program serves every draw of lengths.
+
+    The step returns the batch's top-k and a (2,) int32: the columns phase
+    1 computed (Σ over groups of its queries × its width) and the query
+    words among them.
     """
     key = ("seg", _mesh_key(mesh), kc, rbs, gs, widths, self_exclude,
            bf16_matmul, phase1_full_mesh)
@@ -767,9 +854,11 @@ def _segmented_step(
     for a in batch_axes:
         n_batch_shards *= mesh.shape[a]
 
-    def _z_and_span(t_q, q_valid, emb_local):
+    def _z_and_span(t_q, q_valid, q_cls, q_widths, emb_local):
         v_local = emb_local.shape[0]
-        z_local = _z_from_t(emb_local, t_q, q_valid, bf16_matmul=bf16_matmul)
+        with jax.named_scope("phase1"):
+            z_local = _z_grouped(emb_local, t_q, q_valid, q_cls, q_widths,
+                                 bf16_matmul=bf16_matmul)
         if phase1_full_mesh:
             for a in reversed(batch_axes):
                 z_local = jax.lax.all_gather(z_local, a, axis=0, tiled=True)
@@ -788,10 +877,16 @@ def _segmented_step(
         total_local = sum(r.shape[0] for r in seg_rids)
         stk = StreamingTopK(min(kc, total_local))
         carry = stk.init(b)
+        # Z's columns, the self-exclusion ids and the top-k rows follow the
+        # queries' length order until the answers are put back.
+        with jax.named_scope("phase1"):
+            order, q_cls, q_widths = _query_groups(q_valid)
+            t_q, q_valid, q_gid = t_q[order], q_valid[order], q_gid[order]
         for s in range(n_segments):
             rids, rw, live = seg_rids[s], seg_rw[s], seg_live[s]
             n_local, h1 = rids.shape
-            z_local, v_span = _z_and_span(t_q, q_valid, seg_embs[s])
+            z_local, v_span = _z_and_span(t_q, q_valid, q_cls, q_widths,
+                                          seg_embs[s])
             # Rows of this shard's slice of segment s own the global ids
             # seg_offs[s] + shard_off + row_map — offsets are traced, so
             # compaction's offset rewrite reuses the cached trace too.
@@ -824,7 +919,11 @@ def _segmented_step(
                 carry, _ = jax.lax.scan(
                     body, carry, (ids_b, w_b, live_b, map_b, seg_cls[s]))
         tk = crossshard_topk(carry, kc, axis_names=batch_axes)
-        return tk.dists, tk.indices
+        back = jnp.argsort(order)
+        cols = jnp.stack([
+            (b // q_cls.shape[0]) * jnp.asarray(q_widths)[q_cls].sum(),
+            jnp.sum(q_valid > 0)]).astype(jnp.int32)
+        return tk.dists[back], tk.indices[back], cols
 
     rspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None)
     lspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0])
@@ -836,15 +935,16 @@ def _segmented_step(
         in_specs=(seg(rspec), seg(rspec), seg(lspec), seg(lspec), seg(lspec),
                   P(None), P(None, None, None), P(None, None), P(None),
                   seg(espec)),
-        out_specs=(P(None, None), P(None, None)),
+        out_specs=(P(None, None), P(None, None), P(None)),
     )
 
     @jax.jit
     def step(seg_rids, seg_rw, seg_live, seg_map, seg_cls, seg_offs, t_q,
              q_valid, q_gid, seg_embs):
-        tk_d, tk_i = shmapped(seg_rids, seg_rw, seg_live, seg_map, seg_cls,
-                              seg_offs, t_q, q_valid, q_gid, seg_embs)
-        return TopK(tk_d, tk_i)
+        tk_d, tk_i, cols = shmapped(seg_rids, seg_rw, seg_live, seg_map,
+                                    seg_cls, seg_offs, t_q, q_valid, q_gid,
+                                    seg_embs)
+        return TopK(tk_d, tk_i), cols
 
     step = _sentinel.wrap(
         f"step_cache.seg[kc={kc},segs={n_segments}]", step)
@@ -871,7 +971,8 @@ def _build_segmented_serve_step(
     Each batch the step serves adds the ELL slots phase 2 gathers and the
     slots among them that hold a word to the registry counters
     ``serving_phase2_gathered_slots_total`` and
-    ``serving_phase2_filled_slots_total``.
+    ``serving_phase2_filled_slots_total``, and returns phase 1's column
+    counts in ``ServeResult.phase1_cols``.
     """
     from jax.sharding import NamedSharding
 
@@ -970,7 +1071,7 @@ def _build_segmented_serve_step(
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
         with obs.span("step_launch"):
-            tk = state["step"](
+            tk, cols = state["step"](
                 state["rids"], state["rw"], state["live"], state["map"],
                 state["cls"], state["offs"], t_q, q_valid, q_gid,
                 state["emb"])
@@ -979,7 +1080,7 @@ def _build_segmented_serve_step(
         if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
             return ServeResult(
                 topk=TopK(tk.dists[:, :k], tk.indices[:, :k]),
-                d_local=None, pruned_exact=None, tier=tier)
+                d_local=None, pruned_exact=None, tier=tier, phase1_cols=cols)
         with obs.span("refine_launch"):
             cand_max_rwmd = tk.dists[:, -1]
             if refine:
@@ -995,7 +1096,7 @@ def _build_segmented_serve_step(
                 if kc >= engine.n_live:  # candidates cover every live doc
                     exact = jnp.ones_like(exact)
         return ServeResult(topk=tk, d_local=None, pruned_exact=exact,
-                           rerank_work=work)
+                           rerank_work=work, phase1_cols=cols)
 
     return serve
 

@@ -385,6 +385,15 @@ class _ServeCore:
             ("serving_sinkhorn_swept_cells_total",
              "sweeps of the batched Sinkhorn loop times its padded cells"),
         ))
+        # Phase 1's columns, from the counts the segmented step returns
+        # with each batch's answers (ServeResult.phase1_cols order).
+        self._m_phase1 = tuple(m.counter(name, help_) for name, help_ in (
+            ("serving_phase1_computed_cols_total",
+             "query columns phase 1 computed: Σ over groups of queries × "
+             "the group's width class"),
+            ("serving_phase1_word_cols_total",
+             "computed phase-1 columns that hold a query word"),
+        ))
         # All resident-side prep (vocab restriction, padding, placement on
         # the mesh, resident-embedding gathers) happens ONCE per corpus
         # (and once per ingested delta SEGMENT — O(delta), not O(corpus));
@@ -688,10 +697,12 @@ class _ServeCore:
         with self.obs.span("collect", batch=inflight.seq):
             tk_i = np.asarray(res.topk.indices)  # blocks on the device result
             tk_d = np.asarray(res.topk.dists)
-            work = getattr(res, "rerank_work", None)
-            if work is not None and self.obs.metrics.enabled:
-                for counter, v in zip(self._m_rerank, np.asarray(work)):
-                    counter.inc(float(v))
+            for counters, sums in (
+                    (self._m_rerank, getattr(res, "rerank_work", None)),
+                    (self._m_phase1, getattr(res, "phase1_cols", None))):
+                if sums is not None and self.obs.metrics.enabled:
+                    for counter, v in zip(counters, np.asarray(sums)):
+                        counter.inc(float(v))
         if bt is not None:
             bt.span("device_compute", inflight.t_launched, time.perf_counter())
         if self.trace is not None:

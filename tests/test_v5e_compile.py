@@ -124,6 +124,21 @@ def test_width_class_branches_read_z_in_place(served_step):
     assert len(re.findall(rf"= f32\[{V_E},{B}\]\S* copy\(", text)) <= 1
 
 
+def test_phase1_runs_per_query_group(served_step):
+    """Phase 1 makes no (V_E, B·H) block: each group of 16 queries has a
+    GEMM per width class, whose N = 16·w fills whole 128-lane tiles."""
+    from repro.distributed.lcrwmd_dist import _width_classes
+
+    text = served_step.as_text()
+    assert f"f32[{V_E},{B * H}]" not in text
+    dots = re.findall(r"= f32\[(\d+),(\d+)\]\S* (?:convolution|dot)\("
+                      r"[^\n]*op_name=\"([^\"]+)\"", text)
+    n = sorted(int(c) for v, c, name in dots
+               if "/phase1/" in name and int(v) == V_E)
+    assert n == [16 * w for w in _width_classes(H)], n
+    assert all(c % 128 == 0 for c in n)
+
+
 def test_wmd_rerank_fits_and_repeats_no_query_tensor(one_chip):
     """The cascade's rerank at ``set2_wmd``'s sizes (B 64, kc 32, h 48,
     m 300, the full vocabulary): it fits one chip, and the only
