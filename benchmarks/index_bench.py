@@ -113,9 +113,8 @@ def run():
     # (+2 headroom over p_star for the batch's route union).
     idx_serve = ClusterIndex(eng, num_cells=N_CELLS, top_p=p_star,
                              probe_cap=p_star + 2, seed=0)
-    flat_step = build_serve_step(mesh, engine=eng, k=K, streaming=True)
-    routed_step = build_serve_step(mesh, engine=eng, index=idx_serve, k=K,
-                                   streaming=True)
+    flat_step = build_serve_step(mesh, engine=eng, k=K)
+    routed_step = build_serve_step(mesh, engine=eng, index=idx_serve, k=K)
     t_flat = time_fn(flat_step, l_queries)
     t_routed = time_fn(routed_step, l_queries)
     recall = _recall(np.asarray(routed_step(l_queries).topk.indices),

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import BenchResult, intermediate_shapes, time_fn
-from repro.core import LCRWMDEngine, lc_rwmd_symmetric, topk_smallest
+from repro.core import SegmentedEngine, lc_rwmd_symmetric, topk_smallest
 from repro.data.synth import CorpusSpec, make_bimodal_corpus, make_corpus
 from repro.workloads import (
     SelfPairScheduler,
@@ -42,7 +42,7 @@ def _tiled_footprint_bench() -> list[BenchResult]:
         n_docs=n, vocab_size=2048, emb_dim=48, h_max=16, mean_h=10.0,
         n_classes=4, seed=11))
     emb = jnp.asarray(c.emb)
-    engine = LCRWMDEngine(c.docs, emb)
+    engine = SegmentedEngine(c.docs, emb)
     v_e = engine.emb_restricted.shape[0]
     h = c.docs.h_max
 
@@ -50,7 +50,8 @@ def _tiled_footprint_bench() -> list[BenchResult]:
     sched = SelfPairScheduler(engine, tile=tile)
     idx = jnp.arange(tile, dtype=jnp.int32)
     z = engine.phase1_resident(idx)
-    step_shapes = intermediate_shapes(sched._step_impl, z, z, idx, idx)
+    step_shapes = intermediate_shapes(sched._step_impl, z, z, idx, idx,
+                                      sched._live)
     assert (n, n) not in step_shapes, "tiled step materialized (n, n)"
     assert (tile, tile) in step_shapes, "step should emit (tile, tile) blocks"
     biggest = max(int(np.prod(s)) for s in step_shapes if s)
@@ -113,7 +114,7 @@ def _clustering_bench() -> list[BenchResult]:
         n_docs=192, vocab_size=1024, emb_dim=32, h_max=24, mean_h=16.0,
         n_classes=4, topic_noise=0.1, emb_topic_scale=4.0,
         emb_word_scale=1.0, seed=5))
-    engine = LCRWMDEngine(c.docs, jnp.asarray(c.emb))
+    engine = SegmentedEngine(c.docs, jnp.asarray(c.emb))
 
     t0 = time.perf_counter()
     rw = kmedoids(engine, 4, n_iters=8)
@@ -157,7 +158,7 @@ def _neighbors_bench() -> BenchResult:
     from repro.data.docs import DocSet
 
     docs = DocSet(ids=jnp.asarray(ids), weights=jnp.asarray(w))
-    engine = LCRWMDEngine(docs, jnp.asarray(c.emb))
+    engine = SegmentedEngine(docs, jnp.asarray(c.emb))
     t0 = time.perf_counter()
     g = near_duplicate_graph(engine, 0.05, tile=64)
     t_us = (time.perf_counter() - t0) * 1e6

@@ -14,7 +14,7 @@ word-level transport is not:
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import LCRWMDEngine
+from repro.core import SegmentedEngine
 from repro.data.docs import DocSet
 from repro.data.synth import CorpusSpec, make_bimodal_corpus
 from repro.workloads import (
@@ -40,7 +40,7 @@ def main():
         ids[dst] = ids[src]
         w[dst] = w[src]
     docs = DocSet(ids=jnp.asarray(ids), weights=jnp.asarray(w))
-    engine = LCRWMDEngine(docs, jnp.asarray(corpus.emb))
+    engine = SegmentedEngine(docs, jnp.asarray(corpus.emb))
 
     seeds = kcenters(engine, 4)
     print(f"k-centers seeds: {seeds.tolist()} "

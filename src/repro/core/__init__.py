@@ -3,7 +3,6 @@
 from repro.core.distances import dists, sq_dists
 from repro.core.lc_rwmd import (
     EngineSegment,
-    LCRWMDEngine,
     SegmentedEngine,
     SegmentTensors,
     lc_rwmd_one_sided,
@@ -54,7 +53,7 @@ from repro.core.wmd import (
 
 __all__ = [
     "dists", "sq_dists",
-    "EngineSegment", "LCRWMDEngine", "SegmentTensors", "SegmentedEngine",
+    "EngineSegment", "SegmentTensors", "SegmentedEngine",
     "lc_rwmd_one_sided", "lc_rwmd_streaming",
     "lc_rwmd_symmetric", "phase1_z", "phase1_z_from_t", "phase2_spmm",
     "restrict_vocab",

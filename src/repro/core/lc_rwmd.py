@@ -36,11 +36,10 @@ _INF = jnp.float32(jnp.inf)
 class SegmentTensors(NamedTuple):
     """Device tensors of one immutable engine segment (a jit-able pytree).
 
-    Both :class:`LCRWMDEngine` (one implicit segment) and
-    :class:`EngineSegment` reduce to this record, and the module-level jitted
-    segment kernels take it as a *traced* argument — so every segment with
-    the same shapes shares ONE compiled trace (appending a delta segment of a
-    previously seen shape never re-traces anything).
+    Every :class:`EngineSegment` reduces to this record, and the
+    module-level jitted segment kernels take it as a *traced* argument — so
+    every segment with the same shapes shares ONE compiled trace (appending a
+    delta segment of a previously seen shape never re-traces anything).
     """
 
     emb_r: Array     # (v_e, m) restricted embedding rows (phase-1 input)
@@ -222,420 +221,6 @@ def lc_rwmd_symmetric(
     return jnp.maximum(d1, d2.T)
 
 
-class LCRWMDEngine:
-    """Precompiled serve-time LC-RWMD against a fixed resident corpus.
-
-    Built ONCE from a resident :class:`DocSet` + embedding table, the engine
-    hoists everything that does not depend on the query batch out of the
-    serve path:
-
-      * the paper's ``v_e`` vocabulary restriction (phase 1 / phase 2 only
-        ever touch resident-used vocab rows — queries still gather from the
-        FULL table, so out-of-resident-vocab query words stay exact, which
-        plain :func:`restrict_vocab` usage cannot guarantee);
-      * the resident-side word-embedding gather ``emb[resident.ids]`` that
-        the symmetric bound's swapped direction needs (the seed path
-        re-gathered it per call);
-      * float32 casts, alignment padding, and the jit compilation of the
-        ``one_sided`` / ``symmetric`` / ``topk`` entry points (query buffers
-        optionally donated on accelerator backends via ``donate_queries``).
-
-    Serve-time top-k is STREAMING (:meth:`topk_streaming` /
-    :meth:`symmetric_topk_streaming`, and :meth:`topk` which routes through
-    them): phase-2 row blocks fold straight into a
-    :class:`~repro.core.topk.StreamingTopK` carry, so the (n, B) distance
-    matrix never reaches HBM when only the top-k is consumed — peak per-query
-    state is O(k) plus one ``row_block``-row slab.  Results equal the
-    materialized ``lax.top_k`` exactly, ties included (shared lexicographic
-    (distance, doc id) order).
-
-    The symmetric path also shares ONE query-embedding gather between both
-    directions and restricts the swapped direction's vocab axis to the
-    batch's own query words — O(B·h·n·h̄·m) instead of the seed's full
-    O(v·n·h̄·m) second phase-1 pass, exactly equal in value.
-
-    ``vocab_chunk`` bounds the phase-1 intermediate at (vocab_chunk, B)
-    (streaming mode); ``use_kernel`` routes through the Pallas kernels.
-    """
-
-    def __init__(
-        self,
-        resident: DocSet,
-        emb: Array,
-        *,
-        restrict: bool = True,
-        bf16_matmul: bool = False,
-        vocab_chunk: int | None = None,
-        use_kernel: bool = False,
-        interpret: bool = False,
-        jit_methods: bool = True,
-        donate_queries: bool = False,
-        row_block: int = 128,
-    ):
-        self.resident = resident
-        self.emb_full = jnp.asarray(emb, dtype=jnp.float32)
-        self.bf16_matmul = bf16_matmul
-        self.vocab_chunk = vocab_chunk
-        self.use_kernel = use_kernel
-        self.interpret = interpret
-        self.row_block = max(1, min(row_block, resident.n_docs))
-
-        if restrict:
-            sub, emb_r, old_to_new = restrict_vocab(resident, self.emb_full)
-        else:
-            sub, emb_r = resident, self.emb_full
-            old_to_new = jnp.arange(self.emb_full.shape[0], dtype=jnp.int32)
-        self.resident_restricted = sub
-        self.emb_restricted = emb_r
-        self.old_to_new = old_to_new
-
-        # Pre-gathered side-2 targets: the resident docs' word embeddings.
-        n, h1 = resident.ids.shape
-        self._t_r = self.emb_full[resident.ids.reshape(-1)]  # (n*h1, m)
-        self._valid_r = (resident.weights > 0).reshape(-1)   # (n*h1,)
-        # All-rows-live mask: the monolithic engine routes its non-kernel
-        # query paths through the SAME module-level segment kernels the
-        # SegmentedEngine uses (tensors passed as traced arguments, never
-        # closed over as jaxpr constants — constant folding is what made
-        # bound-method jits drift from the eager oracle by low-order bits).
-        self._row_valid_all = jnp.ones(n, dtype=bool)
-
-        if jit_methods:
-            # ``donate_queries`` lets XLA reuse the per-call query buffers on
-            # accelerator backends.  Opt-in ONLY: the caller must not touch
-            # the DocSet again after the call (pruned_wmd_topk's refine stage
-            # re-reads it, so the pipeline path keeps this off).
-            donate = (
-                (0, 1)
-                if donate_queries and jax.default_backend() != "cpu"
-                else ()
-            )
-            self._one_sided = jax.jit(self._one_sided_impl, donate_argnums=donate)
-            self._symmetric = jax.jit(self._symmetric_impl, donate_argnums=donate)
-            self._topk_stream = jax.jit(
-                self._topk_stream_impl, static_argnums=(0, 1),
-                donate_argnums=(2, 3) if donate else (),
-            )
-            self._rerank = jax.jit(self._rerank_impl, static_argnums=(0, 1))
-            self._symmetric_resident = jax.jit(self._symmetric_resident_impl)
-            self._phase1_resident = jax.jit(self._phase1_resident_impl)
-            self._one_sided_rows = jax.jit(self._one_sided_rows_impl)
-        else:
-            self._one_sided = self._one_sided_impl
-            self._symmetric = self._symmetric_impl
-            self._topk_stream = self._topk_stream_impl
-            self._rerank = self._rerank_impl
-            self._symmetric_resident = self._symmetric_resident_impl
-            self._phase1_resident = self._phase1_resident_impl
-            self._one_sided_rows = self._one_sided_rows_impl
-
-    # -- internals --------------------------------------------------------
-    def gather_queries(self, q_ids: Array) -> Array:
-        """(B, h, m) query word embeddings from the FULL table."""
-        b, h = q_ids.shape
-        with jax.named_scope("gather_queries"):
-            return self.emb_full[q_ids.reshape(-1)].reshape(b, h, -1)
-
-    def _d1_from_t(self, t_q: Array, valid_q: Array, b: int) -> Array:
-        """Resident→query direction from pre-gathered (B*h, m) targets."""
-        if self.use_kernel:
-            from repro.kernels import ops as kops
-
-            h = t_q.shape[0] // b
-            z1 = kops.lc_rwmd_phase1_pregathered(
-                self.emb_restricted, t_q.reshape(b, h, -1),
-                valid_q.reshape(b, h).astype(jnp.float32),
-                bf16_matmul=self.bf16_matmul, interpret=self.interpret,
-            )
-            return kops.spmm_ell(
-                self.resident_restricted.ids, self.resident_restricted.weights,
-                z1, interpret=self.interpret,
-            )
-        z1 = phase1_z_from_t(
-            self.emb_restricted, t_q, valid_q, b,
-            bf16_matmul=self.bf16_matmul, vocab_chunk=self.vocab_chunk,
-        )
-        return phase2_spmm(self.resident_restricted, z1)
-
-    def _gather_flat(self, q_ids: Array) -> Array:
-        """(B*h, m) EAGER query gather from the full table.
-
-        Kept OUTSIDE the jitted impls on purpose: fusing the gather into the
-        phase-1 distance matmul lets XLA pick a different contraction
-        schedule per program, which perturbs low-order bits (amplified near
-        zero by the sqrt).  With the gather hoisted, every engine path —
-        monolithic or segmented — feeds bit-identical pre-gathered targets
-        through shape-stable kernels, which is what makes segmented-vs-
-        monolithic parity exact.
-        """
-        return self.emb_full[jnp.asarray(q_ids).reshape(-1)]
-
-    def _one_sided_impl(self, t_q: Array, q_w: Array) -> Array:
-        return self._d1_from_t(t_q, (q_w > 0).reshape(-1), q_w.shape[0])
-
-    def _symmetric_from_t(self, t_q: Array, q_w: Array, b: int) -> Array:
-        """Symmetric bound from pre-gathered (B*h2, m) query targets."""
-        h2 = q_w.shape[1]
-        n, h1 = self.resident.ids.shape
-        valid_q = (q_w > 0).reshape(-1)
-        d1 = self._d1_from_t(t_q, valid_q, b)            # (n, B)
-
-        # Swapped direction with the vocab axis restricted to the batch's own
-        # query words: Z2 rows are only ever read at q_ids, so computing just
-        # those rows against the pre-gathered resident targets is exact.
-        sq = sq_dists(t_q, self._t_r, bf16_matmul=self.bf16_matmul)
-        sq = jnp.where(self._valid_r[None, :], sq, _INF)
-        z2 = safe_sqrt(jnp.min(sq.reshape(b * h2, n, h1), axis=2))
-        d2 = jnp.einsum("bh,bhn->bn", q_w, z2.reshape(b, h2, n))
-        return jnp.maximum(d1, d2.T)
-
-    def _symmetric_impl(self, t_q: Array, q_w: Array) -> Array:
-        # ONE (eager, pre-hoisted) query gather feeds both directions.
-        return self._symmetric_from_t(t_q, q_w, q_w.shape[0])
-
-    def _resident_query_tensors(self, idx: Array):
-        """Query-side tensors for resident docs ``idx`` (B,), sliced from the
-        PRE-GATHERED resident targets — no embedding-table gather at all."""
-        n, h1 = self.resident.ids.shape
-        b = idx.shape[0]
-        safe = jnp.clip(idx, 0, n - 1)  # padded tile slots gather row n-1 ...
-        t_q = self._t_r.reshape(n, h1, -1)[safe].reshape(b * h1, -1)
-        # ... but carry zero weights, so they behave as empty histograms.
-        q_w = jnp.where((idx >= 0)[:, None] & (idx < n)[:, None],
-                        self.resident.weights[safe], 0.0)
-        return t_q, q_w, b
-
-    def _symmetric_resident_impl(self, idx: Array) -> Array:
-        return self._symmetric_from_t(*self._resident_query_tensors(idx))
-
-    def _phase1_resident_impl(self, idx: Array) -> Array:
-        t_q, q_w, b = self._resident_query_tensors(idx)
-        return phase1_z_from_t(
-            self.emb_restricted, t_q, (q_w > 0).reshape(-1), b,
-            bf16_matmul=self.bf16_matmul, vocab_chunk=self.vocab_chunk,
-        )
-
-    def _one_sided_rows_impl(self, row_idx: Array, z: Array) -> Array:
-        n = self.resident.n_docs
-        safe = jnp.clip(row_idx, 0, n - 1)
-        sub = DocSet(
-            ids=self.resident_restricted.ids[safe],
-            weights=jnp.where(
-                (row_idx >= 0)[:, None] & (row_idx < n)[:, None],
-                self.resident_restricted.weights[safe], 0.0),
-        )
-        return phase2_spmm(sub, z)
-
-    def _segment_tensors(self) -> "SegmentTensors":
-        """This engine's precomputed state as one :class:`SegmentTensors`."""
-        return SegmentTensors(
-            emb_r=self.emb_restricted,
-            r_ids=self.resident_restricted.ids,
-            r_w=self.resident_restricted.weights,
-            t_r=self._t_r, valid_r=self._valid_r,
-        )
-
-    def _topk_stream_impl(self, k: int, symmetric: bool, t_q: Array,
-                          q_w: Array, row_valid: Array | None = None):
-        """Streaming top-k: phase-2 row blocks fold into a (B, k) carry.
-
-        Phase 1 runs ONCE (kernel or jnp) at (v_e, B); the shared
-        :func:`_topk_stream_from_z` fold then scans resident rows in
-        ``row_block`` slabs — the one-sided term via the blocked ELL SpMM,
-        the swapped direction (symmetric=True) via the engine's pre-gathered
-        resident targets restricted to the slab — and every slab folds into
-        a :class:`~repro.core.topk.StreamingTopK` carry.  No (n, B) (nor
-        (B, n)) intermediate exists; exactly equal to ``topk_smallest_cols``
-        of the materialized matrix, ties included.  ``row_valid`` (traced)
-        masks tombstoned rows without recompiling.
-        """
-        b, h2 = q_w.shape
-        valid_q = (q_w > 0).reshape(-1)
-        if self.use_kernel:
-            from repro.kernels import ops as kops
-
-            z1 = kops.lc_rwmd_phase1_pregathered(
-                self.emb_restricted, t_q.reshape(b, h2, -1),
-                valid_q.reshape(b, h2).astype(jnp.float32),
-                bf16_matmul=self.bf16_matmul, interpret=self.interpret,
-            )
-        else:
-            z1 = phase1_z_from_t(
-                self.emb_restricted, t_q, valid_q, b,
-                bf16_matmul=self.bf16_matmul, vocab_chunk=self.vocab_chunk,
-            )
-        return _topk_stream_from_z(
-            self._segment_tensors(), z1, t_q, q_w, row_valid,
-            k=k, symmetric=symmetric, row_block=self.row_block,
-            bf16_matmul=self.bf16_matmul,
-        )
-
-    def _rerank_impl(
-        self, k: int, sink_items: tuple, q_ids: Array, q_w: Array,
-        cand_idx: Array,
-    ):
-        from repro.core import topk as topk_lib
-        from repro.core.wmd import wmd_candidate_values
-
-        n, h1 = self.resident.ids.shape
-        # The candidates' word embeddings come straight from the engine's
-        # PRE-GATHERED resident targets (built once at engine construction),
-        # not from a per-call emb[ids] gather.
-        flat = cand_idx.reshape(-1)
-        t_q = self.gather_queries(q_ids)
-        with jax.named_scope("rerank"):
-            vals = wmd_candidate_values(
-                self._t_r.reshape(n, h1, -1)[flat],
-                self.resident.weights[flat], t_q, q_w,
-                use_kernel=self.use_kernel, bf16_matmul=self.bf16_matmul,
-                interpret=self.interpret or None, **dict(sink_items),
-            )
-            return topk_lib.topk_from_candidates(vals, cand_idx, k)
-
-    # -- public entry points ----------------------------------------------
-    def _dense_dispatch(self, queries: DocSet, symmetric: bool) -> Array:
-        if self.use_kernel:
-            fn = self._symmetric if symmetric else self._one_sided
-            return fn(self._gather_flat(queries.ids), queries.weights)
-        return _segment_dense(
-            self._segment_tensors(), self._gather_flat(queries.ids),
-            queries.weights, self._row_valid_all,
-            symmetric=symmetric, bf16_matmul=self.bf16_matmul,
-            vocab_chunk=self.vocab_chunk,
-        )
-
-    def _topk_dispatch(self, queries: DocSet, k: int, symmetric: bool):
-        t_q = self._gather_flat(queries.ids)
-        if self.use_kernel:
-            return self._topk_stream(k, symmetric, t_q, queries.weights)
-        return _segment_topk(
-            self._segment_tensors(), t_q, queries.weights,
-            self._row_valid_all, k=k, symmetric=symmetric,
-            row_block=self.row_block, bf16_matmul=self.bf16_matmul,
-            vocab_chunk=self.vocab_chunk,
-        )
-
-    def one_sided(self, queries: DocSet) -> Array:
-        """D1 (n, B): cost of moving each resident doc into each query."""
-        return self._dense_dispatch(queries, symmetric=False)
-
-    def symmetric(self, queries: DocSet) -> Array:
-        """Tight symmetric bound max(D1, D2ᵀ), shape (n, B)."""
-        return self._dense_dispatch(queries, symmetric=True)
-
-    def topk(self, queries: DocSet, k: int):
-        """Per-query top-k smallest symmetric LC-RWMD: TopK (B, k).
-
-        Streaming since the top-k unification: alias of
-        :meth:`symmetric_topk_streaming` (exact results, O(k·B) peak)."""
-        return self._topk_dispatch(queries, k, symmetric=True)
-
-    def topk_streaming(self, queries: DocSet, k: int):
-        """Per-query top-k smallest ONE-SIDED LC-RWMD (D1), streamed.
-
-        Args:
-          queries: DocSet with ids/weights (B, h); ids index the FULL
-            embedding table (out-of-resident-vocab words stay exact).
-          k: results per query.  JIT-STATIC — one compile per distinct
-            ``k`` (and per query batch shape); serve at a fixed ``k``.
-
-        Returns a :class:`~repro.core.topk.TopK` of (B, k): ascending
-        distances + global resident doc ids.  Matches the distributed
-        serve step's candidate semantics.  The (n, B) matrix never
-        materializes (resident rows fold into the carry in ``row_block``
-        slabs — the ctor knob); exactly ``lax.top_k`` of
-        :meth:`one_sided`'s transpose, ties included."""
-        return self._topk_dispatch(queries, k, symmetric=False)
-
-    def symmetric_topk_streaming(self, queries: DocSet, k: int):
-        """Per-query top-k smallest SYMMETRIC bound max(D1, D2ᵀ), streamed.
-
-        Same signature/shape contract as :meth:`topk_streaming` (``k`` is
-        jit-static, result (B, k), O(k·B + row_block·B) peak).  The pruning
-        cascade's stage-1 candidate selector: both directions are evaluated
-        per row slab and folded into the (B, k) carry."""
-        return self._topk_dispatch(queries, k, symmetric=True)
-
-    # -- corpus-analytics (query-tile) entry points ------------------------
-    #
-    # The corpus workloads in repro.workloads stream tiles of the RESIDENT
-    # corpus itself through the engine as the query side.  These entry points
-    # accept (pre-padded, ELL) resident-doc tiles by INDEX and feed them from
-    # the engine's pre-gathered resident tensors, so a tile costs zero
-    # embedding-table gathers.  Out-of-range indices (tile padding) act as
-    # empty histograms: their distance columns come out +inf (symmetric) or
-    # garbage-but-masked (one-sided rows); schedulers mask by global index.
-    def resident_tile(self, idx: Array) -> DocSet:
-        """The (pre-padded) resident docs named by ``idx`` as a query DocSet."""
-        n = self.resident.n_docs
-        safe = jnp.clip(jnp.asarray(idx, jnp.int32), 0, n - 1)
-        inb = (jnp.asarray(idx) >= 0) & (jnp.asarray(idx) < n)
-        return DocSet(
-            ids=self.resident.ids[safe],
-            weights=jnp.where(inb[:, None], self.resident.weights[safe], 0.0),
-        )
-
-    def symmetric_resident(self, idx: Array) -> Array:
-        """Tight symmetric bound (n, B) whose queries are resident docs ``idx``.
-
-        Args:
-          idx: (B,) int32 resident doc ids; out-of-range entries (tile
-            padding, e.g. -1) behave as empty histograms and produce +inf
-            columns.  Keep ``B`` fixed across calls — the jit cache is
-            keyed on the tile shape.
-
-        Returns (n, B) f32.  Both directions run from the engine's
-        pre-gathered resident targets (no per-call ``emb[ids]`` gather),
-        and phase 1 sees only the restricted vocabulary — exact, since
-        resident words are by construction inside ``v_e``.
-        """
-        return self._symmetric_resident(jnp.asarray(idx, jnp.int32))
-
-    def phase1_resident(self, idx: Array) -> Array:
-        """Phase-1 Z (v_e, B) for resident-doc queries ``idx`` — the tile
-        primitive of the all-pairs scheduler (computed ONCE per corpus tile,
-        then consumed by many cheap :meth:`one_sided_rows` phase-2 calls)."""
-        return self._phase1_resident(jnp.asarray(idx, jnp.int32))
-
-    def one_sided_rows(self, row_idx: Array, z: Array) -> Array:
-        """Phase-2 ELL SpMM restricted to resident rows ``row_idx``: (R, B).
-
-        ``z`` is a :meth:`phase1_resident` tile; the result is the one-sided
-        LC-RWMD block D1[row_idx, tile] — O(R·h) per query column instead of
-        O(n·h), which is what makes the pair-tiled all-pairs scan linear in
-        the number of visited blocks.
-        """
-        return self._one_sided_rows(jnp.asarray(row_idx, jnp.int32), z)
-
-    def rerank_topk(
-        self, queries: DocSet, cand_indices: Array, k: int,
-        *, sinkhorn_kw: dict | None = None,
-    ):
-        """Batched Sinkhorn-WMD re-rank of per-query candidate doc ids.
-
-        Args:
-          queries: DocSet (B, h) — same batch the candidates were selected
-            for.
-          cand_indices: (B, budget) int32 resident doc ids (e.g. an RWMD
-            top-``budget`` from :meth:`topk_streaming`).
-          k: results per query (k ≤ budget).  JIT-STATIC.
-          sinkhorn_kw: solver knobs (eps, eps_scaling, max_iters, …),
-            forwarded to :func:`repro.core.wmd.wmd_candidate_values`.
-            JIT-STATIC — hashed as a sorted items tuple, so pass plain
-            scalars and reuse the same dict across calls to stay on one
-            compile.
-
-        Returns a :class:`~repro.core.topk.TopK` of (B, k): ascending WMD +
-        global doc ids.  All B·budget pairs are solved in ONE batched
-        log-domain Sinkhorn call fed by the engine's pre-gathered resident
-        embeddings (the ``use_kernel`` engine flag routes it through the
-        fused Pallas SDDMM+iteration kernel).
-        """
-        items = tuple(sorted((sinkhorn_kw or {}).items()))
-        return self._rerank(k, items, queries.ids, queries.weights,
-                            cand_indices)
-
-
 def restrict_vocab(resident: DocSet, emb: Array) -> tuple[DocSet, Array, Array]:
     """The paper's v_e optimization: drop vocab rows unused by the resident set.
 
@@ -669,12 +254,11 @@ def _topk_stream_from_z(
 ):
     """The streaming top-k fold over ONE segment's rows (post-phase-1).
 
-    Shared verbatim between :class:`LCRWMDEngine` (monolithic) and the
-    per-segment kernels, which is what makes the segmented-vs-monolithic
-    parity *bit*-exact: the same fold, the same slab schedule, the same
-    lexicographic (distance, doc id) tie order.  ``row_valid=None`` and an
-    all-True mask are exactly equal (a ``where`` with a true mask is the
-    identity).
+    Every segment kernel runs this one fold: the same slab schedule and the
+    same lexicographic (distance, doc id) tie order, so equal distances
+    rank by ascending doc id in every segment and after
+    :func:`repro.core.topk.merge_topk`.  ``row_valid=None`` and an all-True
+    mask are exactly equal (a ``where`` with a true mask is the identity).
     """
     from repro.core.topk import StreamingTopK, TopK
 
@@ -826,9 +410,10 @@ class EngineSegment:
     """One immutable unit of a :class:`SegmentedEngine`.
 
     Owns a contiguous global doc-id range ``[offset, offset + n_real)`` and
-    the same precomputed state an :class:`LCRWMDEngine` would build for it:
-    the per-segment ``v_e`` vocab restriction, the remapped ELL resident
-    matrix, and the pre-gathered full-table resident word embeddings.  Rows
+    its precomputed state: the per-segment ``v_e`` vocab restriction (queries
+    still gather from the FULL table, so out-of-resident-vocab query words
+    stay exact), the remapped ELL resident matrix, and the pre-gathered
+    full-table resident word embeddings.  Rows
     may be padded to ``n_pad`` (zero-weight, non-live) and the restricted
     vocab to a ``vocab_pad`` multiple so repeated delta shapes hit the same
     jit trace.
@@ -878,11 +463,20 @@ class EngineSegment:
 
 
 class SegmentedEngine:
-    """LC-RWMD engine over a base + delta segment list: churn without rebuild.
+    """Serve-time LC-RWMD over a base + delta segment list.
 
-    Same query surface as :class:`LCRWMDEngine` (``one_sided`` / ``symmetric``
-    / streaming ``topk*`` / ``rerank_topk`` / the corpus-analytics tile entry
-    points), plus a corpus lifecycle:
+    Built from a resident :class:`DocSet` and the embedding table (one base
+    segment; ``resident=None`` starts empty), the engine hoists what does not
+    depend on the query batch out of the query path: the ``v_e`` vocabulary
+    restriction, the resident-side word-embedding gather the symmetric
+    bound's swapped direction needs, and float32 casts.  Its query surface is
+    ``one_sided`` / ``symmetric`` (materialized (n_docs, B)), the streaming
+    ``topk`` / ``topk_streaming`` / ``symmetric_topk_streaming`` (resident
+    rows fold into a (B, k) carry in ``row_block`` slabs, so the (n, B)
+    matrix never forms), ``rerank_topk``, and the corpus-analytics tile
+    entry points.  The distributed serve step
+    (:func:`repro.distributed.lcrwmd_dist.build_serve_step`) places the same
+    segments on a mesh.  Its corpus lifecycle:
 
       * :meth:`append` builds ONE small :class:`EngineSegment` over the new
         docs (its own v_e restriction + gathers) — cost O(delta), not
@@ -897,10 +491,9 @@ class SegmentedEngine:
 
     Queries run phase-1/phase-2 per segment through module-level jitted
     kernels and fold per-segment (distance, global id) top-k candidates with
-    :func:`repro.core.topk.merge_topk`.  Because every segment uses the exact
-    fold of the monolithic engine and the shared lexicographic tie order,
-    results are bit-identical (indices AND distances) to a monolithic rebuild
-    over the merged live corpus — see tests/test_segments.py.
+    :func:`repro.core.topk.merge_topk`.  Every segment runs the same fold
+    (:func:`_topk_stream_from_z`) with the lexicographic (distance, doc id)
+    tie order: equal distances rank by ascending global doc id.
     """
 
     def __init__(
@@ -917,8 +510,6 @@ class SegmentedEngine:
         self.emb_full = jnp.asarray(emb, dtype=jnp.float32)
         self.bf16_matmul = bf16_matmul
         self.vocab_chunk = vocab_chunk
-        self.use_kernel = False   # segment kernels are the pure-jnp fold
-        self.interpret = False
         self.row_block = max(1, int(row_block))
         self.delta_pad = delta_pad
         self.vocab_pad = vocab_pad
@@ -1148,11 +739,23 @@ class SegmentedEngine:
                     with_work: bool = False):
         """Batched Sinkhorn-WMD re-rank of global candidate doc ids.
 
-        Same contract as :meth:`LCRWMDEngine.rerank_topk`; empty (-1) and
-        tombstoned candidates are masked to +inf WMD.  The candidates'
-        histograms are gathered eagerly at fixed (B, budget) shapes, so
-        corpus churn (which changes ``n_docs``) never re-traces the jitted
-        solve.  ``with_work`` also returns the solve's per-batch sums
+        Args:
+          queries: DocSet (B, h) — the batch the candidates were selected
+            for.
+          cand_indices: (B, budget) int32 global doc ids (e.g. a top-budget
+            from :meth:`topk_streaming`); empty (-1) and tombstoned
+            candidates are masked to +inf WMD.
+          k: results per query (k ≤ budget).  JIT-STATIC.
+          sinkhorn_kw: solver knobs (eps, eps_scaling, max_iters, …) for
+            :func:`repro.core.wmd.candidate_sinkhorn`.  JIT-STATIC — hashed
+            as a sorted items tuple, so pass plain scalars.
+
+        Returns a :class:`~repro.core.topk.TopK` of (B, k): ascending WMD +
+        global doc ids.  All B·budget pairs are solved in ONE batched
+        log-domain Sinkhorn call.  The candidates' histograms are gathered
+        eagerly at fixed (B, budget) shapes, so corpus churn (which changes
+        ``n_docs``) never re-traces the jitted solve.  ``with_work`` also
+        returns the solve's per-batch sums
         (:func:`repro.core.wmd.sinkhorn_work`, a device array).
         """
         items = tuple(sorted((sinkhorn_kw or {}).items()))
@@ -1207,10 +810,9 @@ class SegmentedEngine:
             for seg in self.segments
         )
 
-    def _one_sided_rows_impl(self, row_idx: Array, z) -> Array:
-        zs = z if isinstance(z, (tuple, list)) else (z,)
+    def _one_sided_rows_impl(self, row_idx: Array, z: tuple) -> Array:
         total = None
-        for seg, zz in zip(self.segments, zs):
+        for seg, zz in zip(self.segments, z):
             local = row_idx - seg.offset
             owner = (local >= 0) & (local < seg.n_real)
             safe = jnp.clip(local, 0, seg.n_rows - 1)
@@ -1223,7 +825,7 @@ class SegmentedEngine:
             total = d if total is None else total + d
         return total
 
-    def one_sided_rows(self, row_idx: Array, z) -> Array:
+    def one_sided_rows(self, row_idx: Array, z: tuple) -> Array:
         """Phase-2 restricted to global rows ``row_idx``: (R, B).
 
         ``z`` is a :meth:`phase1_resident` tuple; each row's contribution

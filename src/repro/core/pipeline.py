@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import topk as topk_lib
-from repro.core.lc_rwmd import LCRWMDEngine, lc_rwmd_symmetric
+from repro.core.lc_rwmd import SegmentedEngine, lc_rwmd_symmetric
 from repro.core.wmd import wmd_candidate_values
 from repro.data.docs import DocSet
 
@@ -69,7 +69,7 @@ class QualityTier(enum.IntEnum):
 
 
 def cascade_topk(
-    engine: LCRWMDEngine,
+    engine: SegmentedEngine,
     queries: DocSet,
     k: int,
     *,
@@ -93,9 +93,8 @@ def cascade_topk(
         c_r = centroids(engine.resident, engine.emb_full)        # (n, m)
         c_q = centroids(queries, engine.emb_full)                # (B, m)
         d = dists(c_r, c_q)                                      # (n, B)
-        live = getattr(engine, "live_mask_device", None)
-        if live is not None:  # segmented engine: tombstones never shortlist
-            d = jnp.where(live()[:, None], d, jnp.inf)
+        # tombstoned docs never shortlist
+        d = jnp.where(engine.live_mask_device()[:, None], d, jnp.inf)
         return topk_lib.topk_smallest_cols(d, k)
     if tier >= QualityTier.LCRWMD:
         return engine.topk_streaming(queries, k)
@@ -121,8 +120,8 @@ def pruned_wmd_topk(
     k: int,
     refine_budget: int | None = None,
     sinkhorn_kw: dict | None = None,
-    engine: LCRWMDEngine | None = None,
-    use_kernel: bool | None = None,
+    engine: SegmentedEngine | None = None,
+    use_kernel: bool = False,
     interpret: bool = False,
     index=None,
     top_p: int | None = None,
@@ -140,18 +139,17 @@ def pruned_wmd_topk(
     ``min(4·k, n)`` and is clamped to ``[k, n]`` — feed
     :class:`AdaptiveRefineBudget` with ``pruned_exact`` to tune it online.
 
-    ``engine``: a prebuilt :class:`LCRWMDEngine` over the SAME resident set
-    and embeddings — stage 1 then reuses its restricted vocabulary and
-    pre-gathered resident tensors instead of re-deriving them per call
-    (the serve path in serving/query_server.py passes its engine here).
+    ``engine``: a prebuilt :class:`~repro.core.lc_rwmd.SegmentedEngine`
+    over the SAME resident set and embeddings — stage 1 then reuses its
+    restricted vocabulary and pre-gathered resident tensors instead of
+    re-deriving them per call.
 
     The refine stage runs ALL ``(B, budget)`` candidate pairs as ONE batched
     log-domain Sinkhorn solve (:func:`repro.core.wmd.sinkhorn_log_batched`)
     instead of the historical per-candidate ``jax.lax.map`` — per-pair
     convergence masks keep exact pairwise semantics while the whole stage is
     GEMM-shaped.  ``use_kernel`` routes it through the fused Pallas kernel
-    (cost tiles built in VMEM, see kernels/sinkhorn_wmd.py); defaults to the
-    engine's ``use_kernel`` flag when an engine is given.
+    (cost tiles built in VMEM, see kernels/sinkhorn_wmd.py).
 
     ``index``: a :class:`repro.index.ClusterIndex` — inserts the
     centroid/triangle-bound stage BEFORE phase 1, making the full cascade
@@ -168,8 +166,6 @@ def pruned_wmd_topk(
     n = resident.n_docs
     budget = refine_budget or min(4 * k, n)
     budget = min(max(budget, k), n)  # bootstrap needs k candidates
-    if use_kernel is None:
-        use_kernel = engine is not None and engine.use_kernel
 
     # Stage 0 (optional): cell routing + centroid/triangle bound — whole
     # cells leave the cascade before any phase-1 work.  Stage 1: LC-RWMD
@@ -198,9 +194,9 @@ def pruned_wmd_topk(
     # both outputs: candidates sort ascending, so the RWMD-only top-k is the
     # first k columns of the candidate set.
     rwmd_topk = topk_lib.TopK(cand.dists[:, :k], cand.indices[:, :k])
-    # Segmented engines may hand back unfilled (-1) candidate slots when
+    # The engine may hand back unfilled (-1) candidate slots when
     # fewer than `budget` live docs exist — clip the gather and re-inf the
-    # values so dead slots never win; a no-op for dense monolithic engines.
+    # values so dead slots never win; a no-op when every slot is filled.
     flat = jnp.clip(cand.indices, 0, n - 1).reshape(-1)  # (B*budget,)
     wmd_vals = wmd_candidate_values(
         emb[resident.ids[flat]], resident.weights[flat],

@@ -26,8 +26,8 @@ def centroids(ds: DocSet, emb: Array) -> Array:
 def centroids_from_t(weights: Array, t: Array) -> Array:
     """Centroids from PRE-GATHERED word embeddings t (n, h, m), w (n, h).
 
-    The engine-friendly variant: callers holding ``LCRWMDEngine._t_r`` (the
-    pre-gathered resident targets) skip the ``emb[ids]`` gather entirely
+    The engine-friendly variant: callers that already hold the resident
+    word embeddings (``emb_full[ids]``) skip a second ``emb[ids]`` gather
     (used by the k-medoids WCD prefilter in repro.workloads.clustering).
     """
     return jnp.einsum("nh,nhm->nm", weights, t)

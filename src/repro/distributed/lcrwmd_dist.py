@@ -33,6 +33,7 @@ from repro.compat import shard_map as compat_shard_map
 from repro.obs import Observability
 from repro.obs import sentinel as _sentinel
 from repro.core.distances import dists, safe_sqrt, sq_dists
+from repro.core.lc_rwmd import SegmentedEngine
 from repro.core.topk import (
     StreamingTopK,
     TopK,
@@ -80,7 +81,6 @@ def _mesh_key(mesh) -> tuple:
 
 def _slab_geometry(
     n_rows: int, n_batch_shards: int, row_block: int, psum_batch: int,
-    streaming: bool,
 ) -> tuple[int, int, int]:
     """(rb, g, row_mult): slab rows, slabs per collective, row pad multiple.
 
@@ -93,8 +93,8 @@ def _slab_geometry(
     """
     rows_per_shard = max(1, -(-n_rows // n_batch_shards))
     rb = max(1, min(row_block, rows_per_shard))
-    g = max(1, min(psum_batch, -(-rows_per_shard // rb))) if streaming else 1
-    return rb, g, n_batch_shards * (rb * g if streaming else 1)
+    g = max(1, min(psum_batch, -(-rows_per_shard // rb)))
+    return rb, g, n_batch_shards * rb * g
 
 
 def _pad_rows_mult(x, mult: int, value=0):
@@ -108,8 +108,8 @@ def _pad_rows_mult(x, mult: int, value=0):
 
 class ServeResult(NamedTuple):
     topk: TopK        # (B, k) replicated: global doc ids + distances
-    d_local: Array | None  # (n_local, B) shard distances (None when the
-    #                        streaming accumulator never materializes them)
+    d_local: Array | None  # (n_local, B) shard distances: engine-less step
+    #                        only (the engine-backed steps stream, None)
     pruned_exact: Array | None = None  # (B,) bool, rerank_wmd engine path:
     #                        True → WMD top-k provably equals the full-corpus
     #                        WMD top-k (candidate RWMD bound beat the cutoff)
@@ -312,31 +312,61 @@ def build_serve_step(
     rerank_budget: int | None = None,
     wmd_kw: dict | None = None,
     self_exclude: bool = False,
-    streaming: bool | None = None,
     row_block: int = 128,
     psum_batch: int = 8,
     obs=None,
     index=None,
 ):
-    """Returns jit'd ``serve(resident, queries, emb) -> ServeResult``.
+    """Returns the LC-RWMD serve callable for ``mesh``.
+
+    Three step builders stand behind it, picked by what is passed:
+
+      * no ``engine`` — the engine-less MATERIALIZED step,
+        ``serve(resident, queries, emb) -> ServeResult`` (jit'd).  Phase 1
+        runs over the whole table, every shard forms its (n_local, B)
+        distance block (returned as ``ServeResult.d_local``) and
+        :func:`~repro.core.topk.distributed_topk` merges the shards.  It is
+        the reference the mesh steps are tested against and the step the
+        dry-run cells lower from abstract shapes.
+      * a :class:`repro.core.lc_rwmd.SegmentedEngine` — the SEGMENTED step
+        (the served path), ``serve(queries) -> ServeResult``.  The shard
+        kernel scans base + delta segments back-to-back: each segment
+        phase-1s against its OWN restricted vocab (query words still gather
+        from the FULL table, so out-of-resident-vocab words stay exact),
+        streams phase-2 slabs with its tombstone mask and self-exclusion
+        applied locally, and folds (distance, global id) candidates into
+        one shared carry before the single cross-shard top-k collective.
+        Segment tensors are placed on the mesh once and re-placed whenever
+        ``engine.version`` changes, so ingest/delete/compact are admissible
+        between batches: deletes only change the traced live-mask VALUES
+        (no re-trace), and appends re-trace only for segment-shape
+        signatures not yet seen (pad deltas via ``delta_pad``/``vocab_pad``
+        to pin the shapes).
+      * a SegmentedEngine and an ``index`` — the ROUTED step (below).
+
+    Any other ``engine`` raises ``TypeError``.
 
     Shapes: ``queries`` (B, h) DocSet (keep B fixed — the compiled step is
     shape-specialized; the query servers pad to a fixed ``max_batch``) →
-    ``ServeResult.topk`` (B, k) replicated TopK of global doc ids,
-    ``d_local`` (n_local, B) shard distances (None when streaming), and
+    ``ServeResult.topk`` (B, k) replicated TopK of global doc ids, and
     ``pruned_exact`` (B,) bool (rerank path only).  Everything passed HERE
     — ``k``, ``refine``, ``rerank_wmd``/``rerank_budget``/``wmd_kw``,
-    ``streaming``/``row_block``, ``self_exclude``, ``bf16_matmul``,
+    ``row_block``/``psum_batch``, ``self_exclude``, ``bf16_matmul``,
     ``phase1_full_mesh`` — is baked into the compiled step; changing any of
     them means building a new serve step (the servers rebuild on adaptive-
     budget changes and count it in ``stats["budget_rebuilds"]``).
 
-    ``engine``: a prebuilt :class:`repro.core.lc_rwmd.LCRWMDEngine`.  When
-    given, the returned callable is ``serve(queries) -> ServeResult``: the
-    resident tensors and the (vocab-restricted, padded) embedding shards are
-    prepared and placed on the mesh ONCE here, and each serve call only
-    gathers the transient query embeddings from the full table — no
-    per-batch re-padding or re-gathering of resident state.
+    The engine-backed steps STREAM candidate selection: resident rows are
+    scanned in ``row_block`` slabs, each slab's psum'd distances fold into a
+    :class:`~repro.core.topk.StreamingTopK` carry, and the cross-shard top-k
+    collective consumes the (B, k)-sized per-shard partials — the
+    (n_shard, B) distance block is never materialized and
+    ``ServeResult.d_local`` is None.  Ties break as in the materialized
+    top-k, by (distance, doc id).  ``psum_batch`` batches the per-slab
+    model-axis psums: ``psum_batch`` consecutive ``row_block`` slabs are
+    reduced with ONE collective of the stacked (psum_batch·row_block, B)
+    partials per scan step (exactly equal results: psum is elementwise and
+    the top-k fold is grouping-invariant).
 
     ``phase1_full_mesh`` (§Perf lcrwmd iteration 1 — beyond-paper): the
     paper's GPU mapping replicates phase 1 across the resident-data shards
@@ -357,8 +387,8 @@ def build_serve_step(
     LC-RWMD (optionally refined) top-``rerank_budget`` (default 2k) become
     candidates for ONE batched Sinkhorn-WMD call (``wmd_kw`` forwarded), and
     the final top-k is by WMD.  With an engine this routes through
-    :meth:`LCRWMDEngine.rerank_topk` (pre-gathered resident embeddings feed
-    the fused kernel directly); without one, through the jnp batched solver.
+    :meth:`repro.core.lc_rwmd.SegmentedEngine.rerank_topk`; without one,
+    through the jnp batched solver.
 
     ``self_exclude=True`` (engine path only) is the corpus-analytics mode:
     the returned callable becomes ``serve(queries, query_ids)`` where
@@ -368,38 +398,6 @@ def build_serve_step(
     can stream through the serve step as query batches without self-matches
     eating a candidate slot (see
     :func:`repro.workloads.corpus_distance.corpus_self_topk_distributed`).
-
-    ``streaming`` (engine path; default True) fuses candidate selection into
-    the per-shard phase-2 accumulator: resident rows are scanned in
-    ``row_block`` slabs, each slab's psum'd distances fold into a
-    :class:`~repro.core.topk.StreamingTopK` carry, and the cross-shard
-    top-k collective consumes the (B, k)-sized per-shard partials — the
-    (n_shard, B) distance block is never materialized (O(n·B) → O(k·B) peak
-    serve-path memory per device) and ``ServeResult.d_local`` is None.
-    ``streaming=False`` keeps the materialized path with its ``d_local``
-    diagnostics; results are identical either way, ties included.  The
-    engine-less path is the paper-faithful materialized baseline and
-    rejects ``streaming=True``.
-
-    ``psum_batch`` (streaming path) batches the per-slab model-axis psums:
-    ``psum_batch`` consecutive ``row_block`` slabs are reduced with ONE
-    collective of the stacked (psum_batch·row_block, B) partials per scan
-    step — cutting collective launch count by that factor on small row
-    blocks, at proportionally higher (still O(row_block·B)) slab memory.
-    Exactly equal results (psum is elementwise; the top-k fold is
-    grouping-invariant).
-
-    ``engine`` may also be a :class:`repro.core.lc_rwmd.SegmentedEngine`:
-    the serve step then scans base + delta segments back-to-back inside the
-    shard kernel — each segment phase-1s against its OWN restricted vocab,
-    streams phase-2 slabs with its tombstone mask and per-segment
-    self-exclusion applied locally, and folds (distance, global id)
-    candidates into one shared carry — before the single cross-shard top-k
-    collective.  The returned callable re-places segment tensors whenever
-    ``engine.version`` changes, so ingest/delete/compact are admissible
-    between batches: deletes only change the traced live-mask VALUES (no
-    re-trace), and appends re-trace only for segment-shape signatures not
-    yet seen (pad deltas via ``delta_pad``/``vocab_pad`` to pin the shapes).
 
     The ENGINE-path callable additionally accepts a keyword-only
     ``tier=`` (:class:`repro.core.pipeline.QualityTier`): the serving
@@ -424,8 +422,8 @@ def build_serve_step(
     phase-1 column counts in ``ServeResult.phase1_cols``; the serving core
     adds both to its registry at collect.
 
-    ``index``: a :class:`repro.index.ClusterIndex` over the (segmented)
-    ``engine``.  The serve step then ROUTES each batch: the index's host
+    ``index``: a :class:`repro.index.ClusterIndex` over the ``engine``.
+    The serve step then ROUTES each batch: the index's host
     routing stage picks the batch's probed cells (top-p by centroid
     distance, triangle-bound pruned), and the compiled step scans ONLY
     those cells through ``index.probe_cap`` jit-static probe slots —
@@ -448,51 +446,27 @@ def build_serve_step(
     kc = (rerank_budget or 2 * k) if rerank_wmd else k
     kc = max(kc, k)  # the rerank stage must keep at least k candidates
     if engine is not None:
-        kc = min(kc, engine.n_docs if hasattr(engine, "segments")
-                 else engine.resident.n_docs)
-
-    if index is not None:
-        if engine is None or not hasattr(engine, "segments"):
-            raise ValueError(
-                "a ClusterIndex serve step needs a SegmentedEngine "
-                "(the index's cells are engine segments)")
-        if streaming is False:
-            raise ValueError(
-                "the routed serve step is streaming-only (d_local "
-                "diagnostics are a monolithic-engine feature)")
-        return _build_routed_serve_step(
-            mesh, engine, index, k=k, kc=kc, refine=refine,
-            bf16_matmul=bf16_matmul, phase1_full_mesh=phase1_full_mesh,
-            batch_axes=batch_axes, n_batch_shards=n_batch_shards,
-            n_model=n_model, rerank_wmd=rerank_wmd, wmd_kw=wmd_kw,
-            self_exclude=self_exclude, row_block=row_block,
-            psum_batch=psum_batch, obs=obs,
-        )
-    if engine is not None and hasattr(engine, "segments"):
-        if streaming is False:
-            raise ValueError(
-                "the segmented serve step is streaming-only (d_local "
-                "diagnostics are a monolithic-engine feature)")
-        return _build_segmented_serve_step(
-            mesh, engine, k=k, kc=kc, refine=refine, bf16_matmul=bf16_matmul,
+        if not isinstance(engine, SegmentedEngine):
+            raise TypeError(
+                "build_serve_step takes a SegmentedEngine, got "
+                f"{type(engine).__name__}")
+        kc = min(kc, engine.n_docs)
+        kw = dict(
+            k=k, kc=kc, refine=refine, bf16_matmul=bf16_matmul,
             phase1_full_mesh=phase1_full_mesh, batch_axes=batch_axes,
             n_batch_shards=n_batch_shards, n_model=n_model,
             rerank_wmd=rerank_wmd, wmd_kw=wmd_kw, self_exclude=self_exclude,
             row_block=row_block, psum_batch=psum_batch, obs=obs,
         )
-    if engine is not None:
-        return _build_engine_serve_step(
-            mesh, engine, k=k, kc=kc, refine=refine, bf16_matmul=bf16_matmul,
-            phase1_full_mesh=phase1_full_mesh, batch_axes=batch_axes,
-            n_batch_shards=n_batch_shards, n_model=n_model,
-            rerank_wmd=rerank_wmd, wmd_kw=wmd_kw, self_exclude=self_exclude,
-            streaming=streaming if streaming is not None else True,
-            row_block=row_block, psum_batch=psum_batch,
-        )
+        if index is not None:
+            return _build_routed_serve_step(mesh, engine, index, **kw)
+        return _build_segmented_serve_step(mesh, engine, **kw)
+    if index is not None:
+        raise ValueError(
+            "a ClusterIndex serve step needs a SegmentedEngine "
+            "(the index's cells are engine segments)")
     if self_exclude:
         raise ValueError("self_exclude requires an engine-backed serve step")
-    if streaming:
-        raise ValueError("streaming top-k requires an engine-backed serve step")
 
     def kernel(r_ids, r_w, q_ids, q_w, emb_local):
         v_local = emb_local.shape[0]
@@ -571,245 +545,6 @@ def build_serve_step(
     _ENGINELESS_BUILDS += 1
     return _sentinel.wrap(
         f"serve_step.engineless#{_ENGINELESS_BUILDS}", serve)
-
-
-def _engine_step(
-    mesh, *, kc, streaming, rb, g, self_exclude, bf16_matmul,
-    phase1_full_mesh,
-):
-    """Compiled monolithic-engine shard step from the module-level cache.
-
-    Every piece of resident state — ids, weights, the LIVE-row mask, query
-    tensors and embedding shards — is a *traced argument*, so one cached
-    step serves every same-shaped corpus: engine swaps (multi-tenant cache
-    readmits) and row tombstones change values, never traces.  The live
-    mask subsumes the old ``row < n_real`` padding closure.
-    """
-    key = ("mono", _mesh_key(mesh), kc, streaming, rb, g, self_exclude,
-           bf16_matmul, phase1_full_mesh)
-    step = _STEP_CACHE.get(key)
-    if step is not None:
-        return step
-
-    batch_axes = _batch_axes(mesh)
-    n_batch_shards = 1
-    for a in batch_axes:
-        n_batch_shards *= mesh.shape[a]
-
-    def _z_and_span(t_q, q_valid, emb_local):
-        """Phase-1 Z for this shard's vocab span (+ the span size)."""
-        v_local = emb_local.shape[0]
-        z_local = _z_from_t(emb_local, t_q, q_valid, bf16_matmul=bf16_matmul)
-        if phase1_full_mesh:
-            for a in reversed(batch_axes):
-                z_local = jax.lax.all_gather(z_local, a, axis=0, tiled=True)
-            return z_local, v_local * n_batch_shards
-        return z_local, v_local
-
-    def _shard_offset(n_local):
-        offset = jnp.int32(0)
-        for a in batch_axes:
-            offset = offset * mesh.shape[a] + jax.lax.axis_index(a)
-        return offset * n_local
-
-    def kernel(rids, rw, r_live, t_q, q_valid, q_gid, emb_local):
-        n_local = rids.shape[0]
-        z_local, v_span = _z_and_span(t_q, q_valid, emb_local)
-        partial = _phase2_partial(rids, rw, z_local, v_span)
-        d_local = jax.lax.psum(partial, MODEL_AXIS)  # (n_l, B)
-        offset = _shard_offset(n_local)
-
-        # Padded alignment rows AND tombstoned docs arrive as live=False.
-        d_local = jnp.where(r_live[:, None], d_local, _INF)
-        if self_exclude:
-            # Corpus mode: each query IS a resident doc; its own row must
-            # not consume a candidate slot.  Masked locally (only the shard
-            # owning the row sees a match), before the top-k collective.
-            row = offset + jnp.arange(n_local, dtype=jnp.int32)
-            d_local = jnp.where(row[:, None] == q_gid[None, :], _INF, d_local)
-
-        tk = distributed_topk(d_local, kc, axis_names=batch_axes,
-                              shard_offset=offset)
-        return (tk.dists, tk.indices), d_local
-
-    def kernel_streaming(rids, rw, r_live, t_q, q_valid, q_gid, emb_local):
-        n_local, h1 = rids.shape
-        b = t_q.shape[0]
-        z_local, v_span = _z_and_span(t_q, q_valid, emb_local)
-        offset = _shard_offset(n_local)
-
-        # `g` rb-row slabs are evaluated per scan step and reduced with ONE
-        # model-axis psum of the stacked (g·rb, B) partial — one collective
-        # (and one carry fold) per g slabs (see _slab_geometry).
-        blk = rb * g
-        nb = n_local // blk
-        ids_b = rids.reshape(nb, blk, h1)
-        w_b = rw.reshape(nb, blk, h1)
-        live_b = r_live.reshape(nb, blk)
-        los = offset + jnp.arange(nb, dtype=jnp.int32) * blk
-        stk = StreamingTopK(min(kc, n_local))
-
-        def body(carry, xs):
-            ids_blk, w_blk, live_blk, lo = xs
-            partial = _phase2_partial(ids_blk, w_blk, z_local, v_span)
-            d_blk = jax.lax.psum(partial, MODEL_AXIS)    # (g·rb, B)
-            row = lo + jnp.arange(blk, dtype=jnp.int32)  # GLOBAL doc ids
-            d_blk = jnp.where(live_blk[:, None], d_blk, _INF)
-            if self_exclude:
-                d_blk = jnp.where(
-                    row[:, None] == q_gid[None, :], _INF, d_blk)
-            return stk.update_cols(carry, d_blk, row), None
-
-        # phase2 scopes the slab loop (psum, masks); the carry fold inside
-        # it is `topk_fold`.
-        with jax.named_scope("phase2"):
-            local_tk, _ = jax.lax.scan(
-                body, stk.init(b), (ids_b, w_b, live_b, los))
-        tk = crossshard_topk(local_tk, kc, axis_names=batch_axes)
-        return tk.dists, tk.indices
-
-    rspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None)
-    lspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0])
-    espec = (P((MODEL_AXIS,) + batch_axes, None) if phase1_full_mesh
-             else P(MODEL_AXIS, None))
-    in_specs = (rspec, rspec, lspec, P(None, None, None), P(None, None),
-                P(None), espec)
-    if streaming:
-        shmapped = compat_shard_map(
-            kernel_streaming, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(None, None), P(None, None)),
-        )
-
-        @jax.jit
-        def step(rids, rw, r_live, t_q, q_valid, q_gid, emb_s):
-            tk_d, tk_i = shmapped(
-                rids, rw, r_live, t_q, q_valid, q_gid, emb_s)
-            return TopK(tk_d, tk_i), None
-    else:
-        shmapped = compat_shard_map(
-            kernel, mesh=mesh, in_specs=in_specs,
-            out_specs=((P(None, None), P(None, None)), rspec),
-        )
-
-        @jax.jit
-        def step(rids, rw, r_live, t_q, q_valid, q_gid, emb_s):
-            (tk_d, tk_i), d_local = shmapped(
-                rids, rw, r_live, t_q, q_valid, q_gid, emb_s)
-            return TopK(tk_d, tk_i), d_local
-
-    # Sentinel-metered: the WHOLE point of this cache is that same-shaped
-    # serves reuse one trace — a re-trace here is the PR 5 bug class.
-    step = _sentinel.wrap(f"step_cache.mono[kc={kc}]", step)
-    _STEP_CACHE[key] = step
-    return step
-
-
-def _build_engine_serve_step(
-    mesh, engine, *, k, kc, refine, bf16_matmul, phase1_full_mesh,
-    batch_axes, n_batch_shards, n_model, rerank_wmd=False, wmd_kw=None,
-    self_exclude=False, streaming=True, row_block=128, psum_batch=8,
-):
-    """Engine-backed serve step: resident state prepped + placed at build.
-
-    Phase 1 runs against the engine's RESTRICTED vocabulary (resident-used
-    rows only — the paper's v_e optimization), while query embeddings are
-    gathered from the FULL table outside the mesh kernel, so out-of-resident
-    -vocab query words remain exact.  Padded resident rows are masked to
-    +inf before top-k.
-
-    With ``streaming=True`` the shard kernel never forms its (n_local, B)
-    distance block: phase-2 runs in ``row_block`` slabs, each slab is
-    psum'd over the model axis, row-masked (doc padding + self-exclusion)
-    and folded into a per-query :class:`~repro.core.topk.StreamingTopK`
-    carry, and :func:`~repro.core.topk.crossshard_topk` merges the (B, k)
-    per-shard partials — the same collective, fed from O(k)-sized payloads.
-    """
-    from jax.sharding import NamedSharding
-
-    n_real = engine.resident.n_docs
-    # Streaming scans shard rows in (psum_batch · row_block)-row super-slabs:
-    # pad the doc axis so every shard holds a whole number of them (padding
-    # rows are live=False in the traced mask).
-    rb, g, row_mult = _slab_geometry(
-        n_real, n_batch_shards, row_block, psum_batch, streaming)
-    emb_shards = n_model * (n_batch_shards if phase1_full_mesh else 1)
-    emb_r = _pad_rows_mult(engine.emb_restricted, emb_shards)
-    r_ids = _pad_rows_mult(engine.resident_restricted.ids, row_mult)
-    r_w = _pad_rows_mult(engine.resident_restricted.weights, row_mult)
-    r_live = jnp.arange(r_ids.shape[0], dtype=jnp.int32) < n_real
-
-    rspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None)
-    lspec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0])
-    espec = (P((MODEL_AXIS,) + batch_axes, None) if phase1_full_mesh
-             else P(MODEL_AXIS, None))
-    r_ids = jax.device_put(r_ids, NamedSharding(mesh, rspec))
-    r_w = jax.device_put(r_w, NamedSharding(mesh, rspec))
-    r_live = jax.device_put(r_live, NamedSharding(mesh, lspec))
-    emb_r = jax.device_put(emb_r, NamedSharding(mesh, espec))
-
-    step = _engine_step(
-        mesh, kc=kc, streaming=streaming, rb=rb, g=g,
-        self_exclude=self_exclude, bf16_matmul=bf16_matmul,
-        phase1_full_mesh=phase1_full_mesh)
-
-    # Tier-2 (WCD shortlist) state: resident centroids, computed ONCE from
-    # the engine's pre-gathered resident word embeddings.  The step itself
-    # lives in the module-level (k, self_exclude)-keyed jit cache, so tier
-    # switches — and budget-driven serve-step rebuilds — never re-trace it.
-    n_docs, h1_r = engine.resident.ids.shape
-    cent_r = jnp.einsum(
-        "nh,nhm->nm", engine.resident.weights,
-        engine._t_r.reshape(n_docs, h1_r, -1))
-
-    def serve(queries: DocSet, query_ids=None, *, tier: int = 0) -> ServeResult:
-        """Tiered serve: ``tier`` walks the degradation ladder (see
-        :class:`repro.core.pipeline.QualityTier`).  Tier 0 is the full
-        configured cascade; tier 1 serves the LC-RWMD candidates directly
-        (refine + rerank shed — the SAME compiled phase-1/2 step, no
-        re-trace); tier 2 answers from the WCD centroid shortlist only."""
-        if self_exclude and query_ids is None:
-            raise ValueError("self_exclude serve step needs query_ids (B,)")
-        tier = int(tier)
-        t_q = engine.gather_queries(queries.ids)
-        q_valid = (queries.weights > 0).astype(jnp.float32)
-        q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
-                 else jnp.full((queries.n_docs,), -1, jnp.int32))
-        if tier >= 2:  # QualityTier.WCD
-            tk = _wcd_topk_step(k, self_exclude, cent_r, t_q,
-                                queries.weights, q_gid)
-            return ServeResult(topk=tk, d_local=None, pruned_exact=None,
-                               tier=tier)
-        tk, d_local = step(r_ids, r_w, r_live, t_q, q_valid, q_gid, emb_r)
-        if tier >= 1:  # QualityTier.LCRWMD: candidates ARE the answer
-            tk = TopK(tk.dists[:, :k], tk.indices[:, :k])
-            return ServeResult(
-                topk=tk,
-                d_local=None if d_local is None else d_local[:n_real],
-                pruned_exact=None, tier=tier)
-        # Largest candidate RWMD: every non-candidate's WMD is >= this
-        # (candidates are the kc smallest lower bounds), so it certifies
-        # rerank exactness against the k-th WMD cutoff below.
-        cand_max_rwmd = tk.dists[:, -1]
-        exact = None
-        if refine:
-            tk = _symmetric_refine(
-                engine.resident, queries, engine.emb_full, tk)
-        if rerank_wmd:
-            # Finish the cascade: ONE fused batched Sinkhorn-WMD call over
-            # the (B, kc) candidates, fed by the engine's pre-gathered
-            # resident embeddings.
-            tk = engine.rerank_topk(queries, tk.indices, k,
-                                    sinkhorn_kw=wmd_kw)
-            exact = cand_max_rwmd >= tk.dists[:, -1]
-            if kc >= n_real:  # no non-candidates exist: always exact
-                exact = jnp.ones_like(exact)
-        return ServeResult(
-            topk=tk,
-            d_local=None if d_local is None else d_local[:n_real],
-            pruned_exact=exact,
-        )
-
-    return serve
 
 
 def _segmented_step(
@@ -993,7 +728,7 @@ def _build_segmented_serve_step(
 
     def _place(seg) -> dict:
         rb_s, g_s, row_mult = _slab_geometry(
-            seg.n_rows, n_batch_shards, row_block, psum_batch, True)
+            seg.n_rows, n_batch_shards, row_block, psum_batch)
         r_ids = np.asarray(_pad_rows_mult(seg.tensors.r_ids, row_mult))
         r_w = np.asarray(_pad_rows_mult(seg.tensors.r_w, row_mult))
         widths = _width_classes(r_w.shape[1])
@@ -1247,7 +982,7 @@ def _build_routed_serve_step(
             return
         rows_cap = index.rows_cap
         rb, g, row_mult = _slab_geometry(
-            rows_cap, n_batch_shards, row_block, psum_batch, True)
+            rows_cap, n_batch_shards, row_block, psum_batch)
         live = engine.live_mask()
         rids, rw, lv, gids, embs = [], [], [], [], []
         for cell in index.cells:
@@ -1426,9 +1161,10 @@ def _wmd_rerank(
 ) -> TopK:
     """Re-rank (B, budget) candidates by batched Sinkhorn-WMD; keep top-k.
 
-    Engine-less serve path only (the engine path uses the already-jit'd
-    :meth:`LCRWMDEngine.rerank_topk`).  Dispatches through a jit cache keyed
-    on ``(k, wmd_kw)`` so the batched solve is traced once per shape."""
+    Engine-less serve path only (the engine-backed steps use
+    :meth:`repro.core.lc_rwmd.SegmentedEngine.rerank_topk`).  Dispatches
+    through a jit cache keyed on ``(k, wmd_kw)`` so the batched solve is
+    traced once per shape."""
     return _wmd_rerank_jit(resident, queries, emb, tk, k,
                            tuple(sorted((wmd_kw or {}).items())))
 

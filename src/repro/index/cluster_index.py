@@ -177,7 +177,7 @@ class ClusterIndex:
         if not hasattr(engine, "segments"):
             raise TypeError(
                 "ClusterIndex needs a SegmentedEngine (per-cell segments "
-                "reuse its kernels); wrap monolithic corpora in one")
+                "reuse its kernels)")
         if not 1 <= num_cells <= max(1, engine.n_docs):
             raise ValueError(
                 f"need 1 <= num_cells <= {engine.n_docs}, got {num_cells}")

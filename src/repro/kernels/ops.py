@@ -107,7 +107,7 @@ def lc_rwmd_phase1_pregathered(
     bf16_matmul: bool = False,
     interpret: bool | None = None,
 ) -> Array:
-    """Phase 1 with the query gather hoisted out (LCRWMDEngine shares it)."""
+    """Phase 1 with the query gather hoisted out (callers share the gather)."""
     if interpret is None:
         interpret = _on_cpu()
     v = emb.shape[0]

@@ -139,7 +139,6 @@ class ServerConfig:
     # budget change rebuilds the serve step (one recompile, O(log) times).
     adaptive_budget: bool = False
     budget_decay_after: int | None = 4
-    streaming_topk: bool = True     # fuse selection into the serve step
     # Async pipeline knobs (AsyncQueryServer only):
     queue_capacity: int | None = None  # pending-query bound; default 4*max_batch
     pipeline_depth: int = 2            # device batches in flight (2 = double buffer)
@@ -525,15 +524,12 @@ class _ServeCore:
             probe_cap=icfg.probe_cap, method=icfg.method, obs=self.obs)
 
     def _build_serve(self, rerank_budget: int):
-        # The segmented serve step is streaming-only, so the serving path
-        # always fuses selection (cfg.streaming_topk remains a knob for the
-        # monolithic/diagnostic entry points).
         cfg = self.cfg
         return build_serve_step(
             self._mesh, k=cfg.k, refine=cfg.refine_symmetric,
             bf16_matmul=False, engine=self.engine, rerank_wmd=cfg.rerank_wmd,
             rerank_budget=rerank_budget, wmd_kw=cfg.wmd_kw,
-            streaming=True, obs=self.obs, index=self._active.index)
+            obs=self.obs, index=self._active.index)
 
     def _activate(self, corpus_id: str | None) -> CorpusState:
         """Check out (readmitting if evicted) and make a corpus active."""

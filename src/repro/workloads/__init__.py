@@ -12,7 +12,7 @@ cover querying; this package covers the corpus-vs-corpus rest:
   * :mod:`neighbors` — threshold / k-NN near-duplicate graphs and
     duplicate-group extraction from the same tile stream.
 
-All entry points take a prebuilt :class:`~repro.core.lc_rwmd.LCRWMDEngine`
+All entry points take a prebuilt :class:`~repro.core.lc_rwmd.SegmentedEngine`
 (built once per corpus) and a ``tile`` knob that bounds every device
 intermediate at (tile, tile) — the memory model is tabulated in
 ``docs/ARCHITECTURE.md`` and EXPERIMENTS.md §Workloads.

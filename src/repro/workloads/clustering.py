@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core import topk as topk_lib
 from repro.core.distances import dists
-from repro.core.lc_rwmd import LCRWMDEngine
+from repro.core.lc_rwmd import SegmentedEngine
 from repro.core.rwmd import rwmd_pairs_from_t
 from repro.core.wcd import centroids_from_t
 from repro.core.wmd import wmd_batched_dispatch
@@ -52,7 +52,7 @@ class ClusterResult(NamedTuple):
 
 
 def kcenters(
-    engine: LCRWMDEngine, n_clusters: int, *, first: int | None = 0,
+    engine: SegmentedEngine, n_clusters: int, *, first: int | None = 0,
     seed: int | None = None,
 ) -> np.ndarray:
     """Greedy k-centers (farthest-first) seeding over the resident corpus.
@@ -113,18 +113,6 @@ def _assign_prefiltered(
     return labels.astype(jnp.int32), dist
 
 
-def _resident_t(engine, docs) -> Array:
-    """(n*h, m) resident word embeddings for any engine flavor.
-
-    The flat engine pre-gathers this as ``_t_r``; segmented engines keep
-    embeddings per segment, so gather from the full table on demand.
-    """
-    t_r = getattr(engine, "_t_r", None)
-    if t_r is None:
-        t_r = engine.emb_full[docs.ids.reshape(-1)]
-    return t_r
-
-
 @jax.jit
 def _assign_full(d_block: Array):
     """(n, k) engine block → (labels, dist)."""
@@ -147,7 +135,7 @@ def _medoid_cost_batched(block: Array, labels: Array, k: int, c: int):
 
 
 def kmedoids(
-    engine: LCRWMDEngine,
+    engine: SegmentedEngine,
     n_clusters: int,
     *,
     n_iters: int = 8,
@@ -188,9 +176,8 @@ def kmedoids(
     if prefilter is not None:
         prefilter = max(1, min(prefilter, n_clusters))
     docs = engine.resident
-    n_h = docs.ids.shape[1]
-    t_r = _resident_t(engine, docs).reshape(n, n_h, -1)
-    cen = centroids_from_t(docs.weights, t_r)  # WCD centroids, gather-free
+    t_r = engine.emb_full[docs.ids]            # (n, h, m)
+    cen = centroids_from_t(docs.weights, t_r)  # WCD centroids
     sink_items = tuple(sorted((sinkhorn_kw or {}).items()))
 
     medoids = np.asarray(
@@ -248,7 +235,7 @@ def kmedoids(
 
 
 def kmedoids_wcd_baseline(
-    engine: LCRWMDEngine, n_clusters: int, *, n_iters: int = 8,
+    engine: SegmentedEngine, n_clusters: int, *, n_iters: int = 8,
 ) -> ClusterResult:
     """WCD-only k-medoids — the cheap baseline the bench compares against.
 
@@ -258,7 +245,7 @@ def kmedoids_wcd_baseline(
     """
     n = engine.resident.n_docs
     docs = engine.resident
-    t_r = _resident_t(engine, docs).reshape(n, docs.ids.shape[1], -1)
+    t_r = engine.emb_full[docs.ids]            # (n, h, m)
     cen = np.asarray(centroids_from_t(docs.weights, t_r))
 
     # Farthest-first on WCD for seeding (mirrors kcenters).

@@ -2,7 +2,7 @@
 
 The paper motivates LC-RWMD with querying, *clustering*, and classifying
 large document sets; the serve engine covers querying only.  This module
-turns :class:`~repro.core.lc_rwmd.LCRWMDEngine` into a corpus-vs-corpus
+turns :class:`~repro.core.lc_rwmd.SegmentedEngine` into a corpus-vs-corpus
 machine without ever materializing the (n, n) distance matrix in HBM.
 
 Self all-pairs (the clustering / dedup substrate)
@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import topk as topk_lib
-from repro.core.lc_rwmd import LCRWMDEngine
+from repro.core.lc_rwmd import SegmentedEngine
 from repro.data.docs import DocSet
 
 Array = jax.Array
@@ -75,37 +75,36 @@ class SelfPairScheduler:
     (top-k, threshold graphs) iterate :meth:`blocks`.
     """
 
-    def __init__(self, engine: LCRWMDEngine, *, tile: int = 64):
+    def __init__(self, engine: SegmentedEngine, *, tile: int = 64):
         self.engine = engine
         self.n = engine.resident.n_docs
         self.tile = min(tile, self.n)
         self.starts = _tile_starts(self.n, self.tile)
-        self._z: list[Array] = []  # phase-1 cache, one (v_e, tile) per tile
-        # Segmented engines carry tombstones: snapshot the live mask at
+        self._z: list[tuple] = []  # phase-1 cache: per tile, one (v_e, tile)
+        #                              Z per segment
+        # The engine carries tombstones: snapshot the live mask at
         # construction (the phase-1 cache is a same-version snapshot anyway)
         # and pass it as a TRACED argument so deletes elsewhere never force
         # a re-trace of the block step.  A dead doc's row AND column are
         # masked — it has no neighbors and is no one's neighbor.
-        self._live = (engine.live_mask_device()
-                      if hasattr(engine, "live_mask_device") else None)
+        self._live = engine.live_mask_device()
         self._step = jax.jit(self._step_impl)
 
     def _tile_idx(self, lo: int) -> Array:
         # Global ids; the last tile runs past n and is masked downstream.
         return jnp.arange(lo, lo + self.tile, dtype=jnp.int32)
 
-    def _step_impl(self, z_s: Array, z_t: Array, idx_s: Array, idx_t: Array,
-                   live: Array | None = None):
+    def _step_impl(self, z_s: tuple, z_t: tuple, idx_s: Array, idx_t: Array,
+                   live: Array):
         """max(D1[rows_s, cols_t], D1[rows_t, cols_s]ᵀ), masked."""
         b_st = self.engine._one_sided_rows_impl(idx_s, z_t)  # (tile, tile)
         b_ts = self.engine._one_sided_rows_impl(idx_t, z_s)  # (tile, tile)
         sym = jnp.maximum(b_st, b_ts.T)
         ri, ci = idx_s[:, None], idx_t[None, :]
         invalid = (ri == ci) | (ri >= self.n) | (ci >= self.n)
-        if live is not None:
-            lr = jnp.take(live, jnp.clip(idx_s, 0, self.n - 1))
-            lc = jnp.take(live, jnp.clip(idx_t, 0, self.n - 1))
-            invalid = invalid | (~lr[:, None]) | (~lc[None, :])
+        lr = jnp.take(live, jnp.clip(idx_s, 0, self.n - 1))
+        lc = jnp.take(live, jnp.clip(idx_t, 0, self.n - 1))
+        invalid = invalid | (~lr[:, None]) | (~lc[None, :])
         return jnp.where(invalid, _INF, sym)
 
     def _z_tile(self, t: int) -> Array:
@@ -137,7 +136,7 @@ def _fold_block(carry: topk_lib.TopK, block: Array, col_gids: Array,
 
 
 def corpus_self_topk(
-    engine: LCRWMDEngine, k: int, *, tile: int = 64
+    engine: SegmentedEngine, k: int, *, tile: int = 64
 ) -> topk_lib.TopK:
     """Per-document k nearest neighbours over the engine's own corpus.
 
@@ -149,7 +148,7 @@ def corpus_self_topk(
     Returns a TopK of (n, k): ascending distances, global doc ids.
     """
     n = engine.resident.n_docs
-    n_eff = getattr(engine, "n_live", n)  # tombstones can't be neighbors
+    n_eff = engine.n_live  # tombstones can't be neighbors
     if not 1 <= k <= n_eff - 1:
         raise ValueError(f"need 1 <= k <= n_live-1 = {n_eff - 1}, got {k}")
     sched = SelfPairScheduler(engine, tile=max(tile, k))
@@ -183,7 +182,7 @@ class CorpusTopKResult(NamedTuple):
 
 
 def corpus_vs_corpus_topk(
-    engine: LCRWMDEngine,
+    engine: SegmentedEngine,
     corpus: DocSet,
     k: int,
     *,
@@ -223,7 +222,7 @@ def corpus_vs_corpus_topk(
 
 
 def corpus_self_topk_distributed(
-    engine: LCRWMDEngine,
+    engine: SegmentedEngine,
     mesh,
     k: int,
     *,

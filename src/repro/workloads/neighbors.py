@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.lc_rwmd import LCRWMDEngine
+from repro.core.lc_rwmd import SegmentedEngine
 from repro.workloads.corpus_distance import SelfPairScheduler, corpus_self_topk
 
 #: Numeric noise floor of the symmetric LC-RWMD score for EXACT copies.
@@ -99,7 +99,7 @@ def _block_survivors(block: jax.Array, threshold: jax.Array, cap: int):
 
 
 def near_duplicate_graph(
-    engine: LCRWMDEngine, threshold: float, *, tile: int = 64,
+    engine: SegmentedEngine, threshold: float, *, tile: int = 64,
     block_edge_cap: int | None = None,
 ) -> NeighborGraph:
     """All doc pairs with symmetric LC-RWMD ≤ ``threshold``, as CSR.
@@ -147,7 +147,7 @@ def near_duplicate_graph(
 
 
 def knn_graph(
-    engine: LCRWMDEngine, k: int, *, tile: int = 64, mutual: bool = False
+    engine: SegmentedEngine, k: int, *, tile: int = 64, mutual: bool = False
 ) -> NeighborGraph:
     """k-nearest-neighbor graph from the tiled top-k pass, symmetrized.
 
@@ -201,14 +201,14 @@ def ingest_dedup_mask(
     Pick ``threshold`` above the numeric noise floor: EXACT copies score
     ~1e-3 (not 0) because phase-1 distances come from the matmul-form
     ``||a||² + ||b||² − 2ab`` whose cancellation error survives the sqrt
-    (see the streaming-topk note in tests/test_streaming_topk.py);
+    (:func:`repro.core.distances.sq_dists`);
     thresholds below :data:`DUPLICATE_SCORE_FLOOR` (1e-2) would silently
     admit exact copies, so they are clamped up to it with a warning.
     """
     threshold = _floor_threshold(threshold, "ingest_dedup_mask")
     b = docs.n_docs
     keep = np.ones(b, dtype=bool)
-    if getattr(engine, "n_live", engine.resident.n_docs if engine else 0):
+    if engine.n_live:
         d = np.asarray(engine.symmetric(docs))        # (n, B); dead rows +inf
         keep &= d.min(axis=0) > threshold
     if intra_batch and b > 1:

@@ -36,7 +36,7 @@ def test_github_anchor_slugging():
 
 def test_quickstart_extraction():
     code = check_docs.extract_quickstart()
-    assert "LCRWMDEngine" in code
+    assert "SegmentedEngine" in code
     assert "rerank_topk" in code
     compile(code, "<readme-quickstart>", "exec")  # must at least parse
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.lc_rwmd import (
-    LCRWMDEngine,
+    SegmentedEngine,
     lc_rwmd_one_sided,
     lc_rwmd_streaming,
     lc_rwmd_symmetric,
@@ -82,7 +82,7 @@ def test_engine_one_sided_parity(small_corpus):
     ds = small_corpus.docs
     emb = jnp.asarray(small_corpus.emb)
     queries = ds[:6]
-    eng = LCRWMDEngine(ds, emb)
+    eng = SegmentedEngine(ds, emb)
     want = lc_rwmd_one_sided(ds, queries, emb)
     np.testing.assert_allclose(np.asarray(eng.one_sided(queries)),
                                np.asarray(want), rtol=1e-4, atol=1e-2)
@@ -92,7 +92,7 @@ def test_engine_symmetric_parity(small_corpus):
     ds = small_corpus.docs
     emb = jnp.asarray(small_corpus.emb)
     queries = ds[:6]
-    eng = LCRWMDEngine(ds, emb)
+    eng = SegmentedEngine(ds, emb)
     want = lc_rwmd_symmetric(ds, queries, emb)
     np.testing.assert_allclose(np.asarray(eng.symmetric(queries)),
                                np.asarray(want), rtol=1e-4, atol=1e-2)
@@ -103,13 +103,9 @@ def test_engine_symmetric_parity_chunked_and_kernel(small_corpus):
     emb = jnp.asarray(small_corpus.emb)
     queries = ds[3:8]
     want = lc_rwmd_symmetric(ds, queries, emb)
-    for eng in (
-        LCRWMDEngine(ds, emb, vocab_chunk=100),
-        LCRWMDEngine(ds, emb, use_kernel=True, interpret=True),
-        LCRWMDEngine(ds, emb, restrict=False),
-    ):
-        np.testing.assert_allclose(np.asarray(eng.symmetric(queries)),
-                                   np.asarray(want), rtol=1e-4, atol=1e-2)
+    eng = SegmentedEngine(ds, emb, vocab_chunk=100)
+    np.testing.assert_allclose(np.asarray(eng.symmetric(queries)),
+                               np.asarray(want), rtol=1e-4, atol=1e-2)
 
 
 def test_engine_handles_oov_query_words(small_corpus):
@@ -120,7 +116,7 @@ def test_engine_handles_oov_query_words(small_corpus):
     emb = jnp.asarray(small_corpus.emb)
     resident = ds_full[:20]   # restricted vocab = words of 20 docs only
     queries = ds_full[60:64]  # almost surely contains out-of-resident words
-    eng = LCRWMDEngine(resident, emb)
+    eng = SegmentedEngine(resident, emb)
     want = lc_rwmd_symmetric(resident, queries, emb)
     np.testing.assert_allclose(np.asarray(eng.symmetric(queries)),
                                np.asarray(want), rtol=1e-4, atol=1e-2)
@@ -130,7 +126,7 @@ def test_engine_topk_parity(small_corpus):
     ds = small_corpus.docs
     emb = jnp.asarray(small_corpus.emb)
     queries = ds[:5]
-    eng = LCRWMDEngine(ds, emb)
+    eng = SegmentedEngine(ds, emb)
     tk = eng.topk(queries, 7)
     want = topk_smallest_cols(lc_rwmd_symmetric(ds, queries, emb), 7)
     np.testing.assert_allclose(np.asarray(tk.dists), np.asarray(want.dists),
@@ -146,19 +142,19 @@ def test_pruned_wmd_topk_engine_parity(small_corpus):
                            sinkhorn_kw=sink)
     eng = pruned_wmd_topk(resident, queries, emb, k=4, refine_budget=8,
                           sinkhorn_kw=sink,
-                          engine=LCRWMDEngine(resident, emb))
+                          engine=SegmentedEngine(resident, emb))
     np.testing.assert_allclose(np.asarray(eng.topk.dists),
                                np.asarray(base.topk.dists),
                                rtol=1e-4, atol=1e-2)
 
 
 def test_engine_serve_step_parity(small_corpus):
-    """Engine-backed distributed serve == function serve on the host mesh.
+    """Engine-backed distributed serve == engine-less serve on the host mesh.
 
-    Both engine modes: streaming=False keeps the materialized (n_local, B)
-    block and its d_local diagnostics; the default streaming accumulator
-    returns the same top-k from (B, k)-sized per-shard partials (d_local
-    intentionally absent — the block never exists).
+    The engine-less step materializes each shard's (n_local, B) block and
+    returns it as d_local; the segmented step streams the same top-k from
+    (B, k)-sized per-shard partials (d_local absent — the block never
+    exists).
     """
     from repro.distributed.lcrwmd_dist import build_serve_step
     from repro.launch.mesh import make_host_mesh
@@ -167,20 +163,29 @@ def test_engine_serve_step_parity(small_corpus):
     emb = jnp.asarray(small_corpus.emb)
     queries = ds[:5]
     mesh = make_host_mesh(data=1, model=1)
-    eng = LCRWMDEngine(ds, emb)
+    eng = SegmentedEngine(ds, emb)
     base = build_serve_step(mesh, k=7, bf16_matmul=False)(ds, queries, emb)
-    mat = build_serve_step(mesh, k=7, bf16_matmul=False,
-                           engine=eng, streaming=False)(queries)
-    np.testing.assert_allclose(np.asarray(mat.topk.dists),
-                               np.asarray(base.topk.dists),
-                               rtol=1e-4, atol=1e-2)
-    np.testing.assert_allclose(np.asarray(mat.d_local),
-                               np.asarray(base.d_local), rtol=1e-4, atol=1e-2)
     stream = build_serve_step(mesh, k=7, bf16_matmul=False,
-                              engine=eng)(queries)  # streaming default
+                              engine=eng)(queries)
     assert stream.d_local is None
     np.testing.assert_array_equal(np.asarray(stream.topk.indices),
-                                  np.asarray(mat.topk.indices))
+                                  np.asarray(base.topk.indices))
     np.testing.assert_allclose(np.asarray(stream.topk.dists),
-                               np.asarray(mat.topk.dists),
-                               rtol=1e-5, atol=1e-5)
+                               np.asarray(base.topk.dists),
+                               rtol=1e-4, atol=1e-2)
+
+
+@pytest.mark.parametrize("engine", ["segment", "docset"])
+def test_serve_step_rejects_other_engines(small_corpus, engine):
+    """An engine-backed step takes a SegmentedEngine and nothing else."""
+    from repro.core.lc_rwmd import EngineSegment
+    from repro.distributed.lcrwmd_dist import build_serve_step
+    from repro.launch.mesh import make_host_mesh
+
+    ds = small_corpus.docs
+    emb = jnp.asarray(small_corpus.emb)
+    other = (EngineSegment(ds, emb, offset=0) if engine == "segment"
+             else ds)
+    with pytest.raises(TypeError, match="SegmentedEngine"):
+        build_serve_step(make_host_mesh(data=1, model=1), k=7,
+                         bf16_matmul=False, engine=other)

@@ -98,7 +98,7 @@ def test_exhaustive_routing_bit_parity_distributed_serve(engine, index,
 
     mesh = make_host_mesh()
     kw = dict(k=K, refine=True, bf16_matmul=False, rerank_wmd=True,
-              rerank_budget=2 * K, streaming=True)
+              rerank_budget=2 * K)
     r_flat = build_serve_step(mesh, engine=engine, **kw)(queries)
     r_routed = build_serve_step(mesh, engine=engine, index=index,
                                 **kw)(queries)
@@ -115,7 +115,7 @@ def test_partial_routing_high_self_recall(corpus, engine, queries):
     idx = ClusterIndex(engine, num_cells=N_CELLS, top_p=2,
                        probe_cap=N_CELLS, seed=0)
     mesh = make_host_mesh()
-    kw = dict(k=K, refine=False, bf16_matmul=False, streaming=True)
+    kw = dict(k=K, refine=False, bf16_matmul=False)
     flat = np.asarray(build_serve_step(mesh, engine=engine, **kw)
                       (queries).topk.indices)
     routed = np.asarray(build_serve_step(mesh, engine=engine, index=idx,

@@ -7,7 +7,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.core.lc_rwmd import LCRWMDEngine, SegmentedEngine
+from repro.core.lc_rwmd import SegmentedEngine
 from repro.data.docs import DocSet
 from repro.data.synth import CorpusSpec, make_corpus
 
@@ -73,20 +73,6 @@ def test_topk_bit_equals_monolithic_rebuild(corpus, grown, method):
                            getattr(mono, method)(queries, K))
 
 
-def test_topk_matches_legacy_engine(corpus, grown):
-    """The segmented fold agrees with the original monolithic LCRWMDEngine
-    (same candidates; distances to fp tolerance across the two codepaths)."""
-    seg, _ = grown
-    legacy = LCRWMDEngine(seg.resident, corpus.emb)
-    queries = _slice(corpus.docs, 40, 56)
-    tk_s = seg.topk(queries, K)
-    tk_l = legacy.symmetric_topk_streaming(queries, K)
-    np.testing.assert_array_equal(np.asarray(tk_s.indices),
-                                  np.asarray(tk_l.indices))
-    np.testing.assert_allclose(np.asarray(tk_s.dists),
-                               np.asarray(tk_l.dists), atol=1e-5)
-
-
 def test_serve_step_rerank_bit_parity(corpus, grown):
     """The distributed serve step (streaming + symmetric refine + WMD
     rerank) is bit-identical between the segmented engine and its
@@ -97,7 +83,7 @@ def test_serve_step_rerank_bit_parity(corpus, grown):
     seg, mono = grown
     mesh = make_host_mesh()
     kw = dict(k=K, refine=True, bf16_matmul=False, rerank_wmd=True,
-              rerank_budget=2 * K, streaming=True)
+              rerank_budget=2 * K)
     queries = _slice(corpus.docs, 0, 8)
     res_s = build_serve_step(mesh, engine=seg, **kw)(queries)
     res_m = build_serve_step(mesh, engine=mono, **kw)(queries)
@@ -148,7 +134,7 @@ def test_delete_excludes_distributed_serve_without_rebuild(corpus):
     docs = corpus.docs
     eng = SegmentedEngine(_slice(docs, 0, BASE_N), corpus.emb)
     serve = build_serve_step(make_host_mesh(), k=K, engine=eng, refine=True,
-                             bf16_matmul=False, streaming=True)
+                             bf16_matmul=False)
     target = 11
     queries = _slice(docs, target, target + 8)
     before = np.asarray(serve(queries).topk.indices)

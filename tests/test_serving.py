@@ -136,12 +136,12 @@ def test_rerank_topk_matches_bruteforce_wmd(corpus):
     import jax
     import jax.numpy as jnp
 
-    from repro.core.lc_rwmd import LCRWMDEngine
+    from repro.core.lc_rwmd import SegmentedEngine
     from repro.core.wmd import wmd_pair
 
     kw = dict(eps=0.05, eps_scaling=2, max_iters=100)
     ds, emb = corpus.docs, jnp.asarray(corpus.emb)
-    engine = LCRWMDEngine(ds, emb)
+    engine = SegmentedEngine(ds, emb)
     queries = ds[10:14]
     k, budget = 4, 12
     cand = engine.topk(queries, budget).indices  # (B, budget)

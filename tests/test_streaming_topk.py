@@ -12,7 +12,7 @@ import pytest
 
 from benchmarks.common import intermediate_shapes
 from repro.core import topk as topk_lib
-from repro.core.lc_rwmd import LCRWMDEngine, lc_rwmd_symmetric
+from repro.core.lc_rwmd import SegmentedEngine, _segment_dense, _segment_topk
 from repro.core.pipeline import AdaptiveRefineBudget, pruned_wmd_topk
 from repro.data.docs import DocSet
 
@@ -117,13 +117,13 @@ def test_ops_fused_topk_no_nB_intermediate(small_corpus):
 # ---------------------------------------------------------------------------
 # Engine entry points
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_engine_streaming_topk_parity(small_corpus, use_kernel):
+@pytest.mark.parametrize("row_block", [33, 128])
+def test_engine_streaming_topk_parity(small_corpus, row_block):
+    """Slabs of 33 rows (a ragged last slab) and one slab over all rows."""
     ds = small_corpus.docs
     emb = jnp.asarray(small_corpus.emb)
     q = ds[3:8]
-    eng = LCRWMDEngine(ds, emb, use_kernel=use_kernel,
-                       interpret=use_kernel, row_block=33)
+    eng = SegmentedEngine(ds, emb, row_block=row_block)
     want_sym = topk_lib.topk_smallest_cols(eng.symmetric(q), 7)
     got_sym = eng.symmetric_topk_streaming(q, 7)
     np.testing.assert_array_equal(np.asarray(got_sym.indices),
@@ -147,7 +147,7 @@ def test_engine_topk_routes_through_streaming(small_corpus):
     ds = small_corpus.docs
     emb = jnp.asarray(small_corpus.emb)
     q = ds[:4]
-    eng = LCRWMDEngine(ds, emb)
+    eng = SegmentedEngine(ds, emb)
     a = eng.topk(q, 6)
     b = eng.symmetric_topk_streaming(q, 6)
     np.testing.assert_array_equal(np.asarray(a.indices), np.asarray(b.indices))
@@ -159,16 +159,21 @@ def test_engine_streaming_no_nB_intermediate(small_corpus):
     emb = jnp.asarray(small_corpus.emb)
     q = ds[:5]
     n, b = ds.n_docs, 5
-    eng = LCRWMDEngine(ds, emb, row_block=32)
+    eng = SegmentedEngine(ds, emb, row_block=32)
     assert eng.emb_restricted.shape[0] != n  # unambiguous (n, B) probe
+    (seg,), (live,) = eng.segments, eng._seg_live_device()
+    kw = dict(bf16_matmul=False, vocab_chunk=None)
     shapes = intermediate_shapes(
-        lambda qi, qw: eng._topk_stream_impl(7, True, eng._gather_flat(qi),
-                                             qw),
+        lambda qi, qw: _segment_topk(
+            seg.tensors, eng._gather_queries_flat(qi), qw, live, k=7,
+            symmetric=True, row_block=32, **kw),
         q.ids, q.weights)
     assert (n, b) not in shapes, "engine streaming top-k materialized (n, B)"
     assert (b, n) not in shapes, "swapped direction materialized (B, n)"
     shapes_mat = intermediate_shapes(
-        lambda qi, qw: eng._symmetric_impl(eng._gather_flat(qi), qw),
+        lambda qi, qw: _segment_dense(
+            seg.tensors, eng._gather_queries_flat(qi), qw, live,
+            symmetric=True, **kw),
         q.ids, q.weights)
     assert (n, b) in shapes_mat, "positive control lost its (n, B)"
 
@@ -187,7 +192,7 @@ def test_pipeline_streaming_candidates_match_materialized(small_corpus):
                            sinkhorn_kw=sink)
     eng = pruned_wmd_topk(resident, queries, emb, k=4, refine_budget=8,
                           sinkhorn_kw=sink,
-                          engine=LCRWMDEngine(resident, emb))
+                          engine=SegmentedEngine(resident, emb))
     np.testing.assert_array_equal(np.asarray(eng.rwmd_topk.indices),
                                   np.asarray(base.rwmd_topk.indices))
     np.testing.assert_array_equal(np.asarray(eng.topk.indices),
@@ -203,10 +208,10 @@ def test_pipeline_streaming_candidates_match_materialized(small_corpus):
 # Distributed serve step
 # ---------------------------------------------------------------------------
 def test_distributed_streaming_structural_and_self_exclude(small_corpus):
-    """The streaming shard kernel holds no (n_shard, B) f32 before the
-    cross-shard collective (the materialized kernel is the positive
-    control), and in-accumulator self-exclusion matches the materialized
-    path's masking exactly."""
+    """The segmented shard kernel holds no (n_shard, B) f32 before the
+    cross-shard collective (the engine-less materialized step is the
+    positive control), and in-accumulator self-exclusion gives the top-k of
+    the one-sided distances with each query's own row at +inf."""
     from repro.distributed.lcrwmd_dist import build_serve_step
     from repro.launch.mesh import make_host_mesh
 
@@ -214,43 +219,45 @@ def test_distributed_streaming_structural_and_self_exclude(small_corpus):
     emb = jnp.asarray(small_corpus.emb)
     n, b = ds.n_docs, 8
     mesh = make_host_mesh(data=1, model=1)
-    eng = LCRWMDEngine(ds, emb, row_block=32)
+    eng = SegmentedEngine(ds, emb, row_block=32)
     assert eng.emb_restricted.shape[0] != n
     idx = jnp.arange(b, dtype=jnp.int32)
     tile = eng.resident_tile(idx)
-    t_q = eng.gather_queries(tile.ids)
-    q_valid = (tile.weights > 0).astype(jnp.float32)
 
-    def build(streaming, psum_batch=8):
+    def build(psum_batch=8):
         return build_serve_step(mesh, k=5, engine=eng, bf16_matmul=False,
-                                self_exclude=True, streaming=streaming,
-                                row_block=32, psum_batch=psum_batch)
+                                self_exclude=True, row_block=32,
+                                psum_batch=psum_batch)
 
-    mat = build(False)(tile, query_ids=idx)
-    stream = build(True)(tile, query_ids=idx)
+    stream = build()(tile, query_ids=idx)
+    d = np.asarray(eng.one_sided(tile)).copy()          # (n, B)
+    d[np.arange(b), np.arange(b)] = np.inf              # own rows out
+    want = topk_lib.topk_smallest_cols(jnp.asarray(d), 5)
     np.testing.assert_array_equal(np.asarray(stream.topk.indices),
-                                  np.asarray(mat.topk.indices))
+                                  np.asarray(want.indices))
     np.testing.assert_allclose(np.asarray(stream.topk.dists),
-                               np.asarray(mat.topk.dists),
+                               np.asarray(want.dists),
                                rtol=1e-5, atol=1e-5)
     for i in range(b):
         assert i not in np.asarray(stream.topk.indices)[i]
-    del t_q, q_valid  # serve gathers its own query tensors
 
     # Structural contract, traced through shard_map into the mesh kernel:
-    # the materialized kernel forms (n_shard, B); the streaming kernel's
+    # the engine-less step forms (n_shard, B); the segmented step's
     # biggest doc-axis slab is the (psum_batch·row_block, B) super-slab —
     # bounded by the knobs, independent of n_shard.  psum_batch=2 here so
     # the super-slab (64, B) stays strictly below this small shard (96).
+    materialized = build_serve_step(mesh, k=5, bf16_matmul=False)
     shapes_mat = intermediate_shapes(
-        lambda qi, qw, gid: build(False)(DocSet(qi, qw), query_ids=gid).topk,
-        tile.ids, tile.weights, idx)
+        lambda qi, qw: materialized(ds, DocSet(qi, qw), emb).topk,
+        tile.ids, tile.weights)
+    super_slab = build(psum_batch=2)
+    super_slab(tile, query_ids=idx)   # places the segment outside the trace
     shapes_stream = intermediate_shapes(
-        lambda qi, qw, gid: build(True, psum_batch=2)(
-            DocSet(qi, qw), query_ids=gid).topk,
+        lambda qi, qw, gid: super_slab(DocSet(qi, qw), query_ids=gid).topk,
         tile.ids, tile.weights, idx)
     assert (n, b) in shapes_mat, "positive control lost its (n_shard, B)"
-    n_pad = -(-n // 32) * 32  # streaming pads the doc axis to row_block
+    n_pad = -(-n // 64) * 64  # the step pads the doc axis to super-slabs
+    assert eng.emb_restricted.shape[0] not in (n, n_pad)
     assert (n, b) not in shapes_stream and (n_pad, b) not in shapes_stream, (
         f"streaming serve materialized an (n_shard, B) block: {shapes_stream}")
     assert (64, b) in shapes_stream, "super-slab positive control lost"
@@ -347,7 +354,7 @@ def test_near_duplicate_graph_overflow_fallback(small_corpus):
     generously-capped one (the in-device list is an optimization only)."""
     from repro.workloads import near_duplicate_graph
 
-    eng = LCRWMDEngine(small_corpus.docs, jnp.asarray(small_corpus.emb))
+    eng = SegmentedEngine(small_corpus.docs, jnp.asarray(small_corpus.emb))
     thr = 6.0  # loose (typical distances ~5-8): plenty of edges per block
     big = near_duplicate_graph(eng, thr, tile=32)
     tiny = near_duplicate_graph(eng, thr, tile=32, block_edge_cap=2)

@@ -88,7 +88,7 @@ def served_step(topo):
 
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                 (DATA_AXIS, MODEL_AXIS), axis_types=(AxisType.Auto,) * 2)
-    rb, g, _ = _slab_geometry(N_DOCS, 1, 128, 8, True)
+    rb, g, _ = _slab_geometry(N_DOCS, 1, 128, 8)
     step = _segmented_step(mesh, kc=16, rbs=(rb,), gs=(g,),
                            widths=(_width_classes(H),), self_exclude=False,
                            bf16_matmul=False, phase1_full_mesh=True)

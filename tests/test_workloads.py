@@ -5,7 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import LCRWMDEngine, rwmd_many_vs_many, rwmd_pair, topk_smallest
+from repro.core import (
+    SegmentedEngine,
+    rwmd_many_vs_many,
+    rwmd_pair,
+    topk_smallest,
+)
 from repro.data.docs import DocSet
 from repro.data.synth import CorpusSpec, make_bimodal_corpus
 from repro.workloads import (
@@ -27,7 +32,7 @@ from repro.workloads import (
 
 @pytest.fixture(scope="module")
 def engine(small_corpus):
-    return LCRWMDEngine(small_corpus.docs, jnp.asarray(small_corpus.emb))
+    return SegmentedEngine(small_corpus.docs, jnp.asarray(small_corpus.emb))
 
 
 def _brute_self_topk(corpus, emb, k):
@@ -97,7 +102,8 @@ def test_step_is_tile_bounded(engine):
     sched = SelfPairScheduler(engine, tile=tile)
     idx = jnp.arange(tile, dtype=jnp.int32)
     z = engine.phase1_resident(idx)
-    shapes = intermediate_shapes(sched._step_impl, z, z, idx, idx)
+    shapes = intermediate_shapes(sched._step_impl, z, z, idx, idx,
+                                 sched._live)
     assert (n, n) not in shapes
     assert (tile, tile) in shapes
     v_e = engine.emb_restricted.shape[0]
@@ -145,7 +151,7 @@ def bimodal():
 
 @pytest.fixture(scope="module")
 def bimodal_engine(bimodal):
-    return LCRWMDEngine(bimodal.docs, jnp.asarray(bimodal.emb))
+    return SegmentedEngine(bimodal.docs, jnp.asarray(bimodal.emb))
 
 
 def test_kcenters_spreads_over_classes(bimodal, bimodal_engine):
@@ -201,7 +207,7 @@ def dup_corpus(small_corpus):
 
 
 def test_near_duplicate_graph_finds_planted_dups(small_corpus, dup_corpus):
-    eng = LCRWMDEngine(dup_corpus, jnp.asarray(small_corpus.emb))
+    eng = SegmentedEngine(dup_corpus, jnp.asarray(small_corpus.emb))
     g = near_duplicate_graph(eng, 0.05, tile=40)
     groups = [sorted(gr.tolist()) for gr in duplicate_groups(g)]
     assert [5, 50, 77] in groups
@@ -216,7 +222,7 @@ def test_near_duplicate_graph_finds_planted_dups(small_corpus, dup_corpus):
 
 
 def test_near_duplicate_graph_no_self_loops(small_corpus, dup_corpus):
-    eng = LCRWMDEngine(dup_corpus, jnp.asarray(small_corpus.emb))
+    eng = SegmentedEngine(dup_corpus, jnp.asarray(small_corpus.emb))
     g = near_duplicate_graph(eng, 0.05, tile=64)
     for i in range(g.n_docs):
         assert i not in g.indices[g.indptr[i]:g.indptr[i + 1]]
@@ -242,7 +248,7 @@ def test_near_duplicate_threshold_floor_warns_and_clamps(small_corpus,
     warning — and the planted exact copies are still caught."""
     from repro.workloads import DUPLICATE_SCORE_FLOOR
 
-    eng = LCRWMDEngine(dup_corpus, jnp.asarray(small_corpus.emb))
+    eng = SegmentedEngine(dup_corpus, jnp.asarray(small_corpus.emb))
     with pytest.warns(UserWarning, match="noise floor"):
         g = near_duplicate_graph(eng, DUPLICATE_SCORE_FLOOR / 100, tile=40)
     groups = [sorted(gr.tolist()) for gr in duplicate_groups(g)]
