@@ -58,7 +58,8 @@ class Observability:
         self.tracer = Tracer(enabled=tracing_enabled)
         self.events = EventLog(maxlen=event_capacity)
         self._stage_hists: dict = {}
-        self._local = threading.local()   # the batch this thread serves
+        # Per thread: the batch it serves, its spans' last end times.
+        self._local = threading.local()
 
     # -- serve spans -------------------------------------------------------
     def stage_histogram(self, stage: str):
@@ -87,7 +88,18 @@ class Observability:
                 batch, trace = ctx
         hist = (self.stage_histogram(stage)
                 if observe and self.metrics.enabled else None)
-        return Span(stage, batch, hist, trace)
+        return Span(stage, batch, hist, trace, self._ends())
+
+    def _ends(self) -> dict:
+        ends = getattr(self._local, "ends", None)
+        if ends is None:
+            ends = self._local.ends = {}
+        return ends
+
+    def span_end(self, stage: str) -> float | None:
+        """``time.perf_counter()`` at which this thread's latest
+        ``serve.<stage>`` span closed; None if it never opened one."""
+        return self._ends().get(stage)
 
     def observe_stage(self, stage: str, seconds: float) -> None:
         if self.metrics.enabled:

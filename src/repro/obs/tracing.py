@@ -61,6 +61,7 @@ _BATCH_STAGES = frozenset(
 #: worker does other work while the device runs).
 SERVE_SPANS: dict[str, str | None] = {
     "wait": None,                  # for fill, max_wait or a ready batch
+    "hold": None,                  # a partial batch held for the device
     "prep": None,                  # vectorize or staging-ring collect
     "pad": "batch_formation",      # fixed-shape padding
     "refresh": "dispatch",         # resident re-placement; 0 in steady state
@@ -97,20 +98,24 @@ class Span:
 
     On the profiler's clock it is ``serve.<stage>`` (``batch=`` as its
     argument); on exit its length is ``seconds``, observed in ``hist``
-    and widening the stage's timeline stage on ``trace``, where given.
+    and widening the stage's timeline stage on ``trace``, where given;
+    its end time is stored under ``stage`` in ``ends``, where given.
     With no profiler session active the annotation costs about 1 µs.
     """
 
-    __slots__ = ("stage", "_ann", "_hist", "_trace", "t0", "seconds")
+    __slots__ = ("stage", "_ann", "_hist", "_trace", "_ends", "t0",
+                 "seconds")
 
     def __init__(self, stage: str, batch: int | None = None, hist=None,
-                 trace: "BatchTrace | None" = None):
+                 trace: "BatchTrace | None" = None,
+                 ends: dict | None = None):
         ann = _trace_annotation()
         self.stage = stage
         self._ann = (ann("serve." + stage) if batch is None
                      else ann("serve." + stage, batch=batch))
         self._hist = hist
         self._trace = trace
+        self._ends = ends
         self.seconds = 0.0
 
     def __enter__(self) -> "Span":
@@ -122,6 +127,8 @@ class Span:
         t1 = time.perf_counter()
         self._ann.__exit__(*exc)
         self.seconds = t1 - self.t0
+        if self._ends is not None:
+            self._ends[self.stage] = t1
         if self._hist is not None:
             self._hist.observe(self.seconds)
         if self._trace is not None:

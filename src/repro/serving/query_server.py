@@ -278,6 +278,7 @@ class _InFlight(NamedTuple):
     tier: int = 0        # degradation tier the batch was served at
     t0: float = 0.0      # dispatch wall-clock (latency EWMA)
     t_launched: float = 0.0  # when the launch returned (device_compute opens)
+    t_step: float = 0.0  # when the step launch returned (_DeviceClock)
     state: Any = None    # CorpusState the batch was served against
     traces: tuple = ()   # per-query QueryTraces (aligned with qs; may be empty)
     btrace: Any = None   # shared BatchTrace (None when tracing is off)
@@ -288,6 +289,57 @@ class _Staged(NamedTuple):
     pool; its vectorized histogram arrives via staging-ring ``ticket``."""
 
     ticket: int
+
+
+class _DeviceClock:
+    """When the device will be done with the batches in flight, predicted
+    from times the serving core observes (no setting of its own).
+
+    * period — device seconds a batch takes: its observed ready time less
+      its device start, the later of the previous batch's ready time and
+      its own step launch's return (for batches queued back to back, the
+      interval between their ready times);
+    * lead — host seconds from a batch leaving the queue to its step
+      launch returning (prep, pad, query gather, step launch).
+
+    Each is read from the last ``WINDOW`` batches on the side that ends a
+    hold early: the lower quartile of the periods, the upper quartile of
+    the leads.  A batch formed early waits on the device; one formed late
+    leaves it idle.  A lone outlier (a compile inside a launch, a host
+    pause) moves neither reading.
+    """
+
+    WINDOW = 8
+    MIN_SAMPLES = 4
+    GUARD_S = 0.001  # readback and wake-up jitter
+
+    def __init__(self):
+        self._periods: deque = deque(maxlen=self.WINDOW)
+        self._leads: deque = deque(maxlen=self.WINDOW)
+        self.t_free: float | None = None  # last collected batch's ready time
+
+    def launched(self, t_dequeue: float, t_step: float) -> None:
+        self._leads.append(t_step - t_dequeue)
+
+    def ready(self, t_step: float, t_ready: float) -> None:
+        start = t_step if self.t_free is None else max(self.t_free, t_step)
+        self._periods.append(t_ready - start)
+        self.t_free = t_ready
+
+    def hold_until(self, steps: Sequence[float]) -> float | None:
+        """The time at which to form the next batch behind the batches in
+        flight (their step launches' return times ``steps``, oldest first):
+        their predicted end less one lead.  None before enough batches."""
+        if (not steps or self.t_free is None
+                or len(self._periods) < self.MIN_SAMPLES
+                or len(self._leads) < self.MIN_SAMPLES):
+            return None
+        period = sorted(self._periods)[len(self._periods) // 4]
+        lead = sorted(self._leads)[-1 - len(self._leads) // 4]
+        end = self.t_free
+        for t in steps:
+            end = max(end, t) + period
+        return end - lead - self.GUARD_S
 
 
 def _check_query(ids, weights) -> None:
@@ -441,6 +493,8 @@ class _ServeCore:
                 fail_streak_down=cfg.fail_streak_down, obs=self.obs)
             self.stats["tier_transitions"] = self.controller.transitions
         self._seq = 0
+        # Observed device and host times, for the async worker's hold.
+        self.clock = _DeviceClock()
         # Diagnostic hook: set to a list to record ("dispatch"|"collect", seq)
         # events — the overlap tests assert dispatch(i+1) precedes collect(i).
         self.trace: list[tuple[str, int]] | None = None
@@ -645,6 +699,10 @@ class _ServeCore:
         # device_compute opens when the async-dispatched launches return
         # and closes at collect's block_until_ready readback.
         t_launched = time.perf_counter()
+        t_step = self.obs.span_end("step_launch")
+        if t_step is None or t_step < t0:  # a serve without a step span
+            t_step = t_launched
+        self.clock.launched(t_dequeue, t_step)
         with self._stats_lock:
             self.stats["queries"] += len(qs)
             self.stats["batches"] += 1
@@ -663,7 +721,7 @@ class _ServeCore:
                 self._m_queue_wait.observe(t_dequeue - t_admit)
         return _InFlight(result=res, n_real=len(qs), seq=seq,
                          qs=tuple(qs), tier=tier, t0=t0,
-                         t_launched=t_launched, state=state,
+                         t_launched=t_launched, t_step=t_step, state=state,
                          traces=tuple(traces), btrace=bt)
 
     def collect(self, inflight: _InFlight) -> list:
@@ -693,6 +751,7 @@ class _ServeCore:
         with self.obs.span("collect", batch=inflight.seq):
             tk_i = np.asarray(res.topk.indices)  # blocks on the device result
             tk_d = np.asarray(res.topk.dists)
+            self.clock.ready(inflight.t_step, time.perf_counter())
             for counters, sums in (
                     (self._m_rerank, getattr(res, "rerank_work", None)),
                     (self._m_phase1, getattr(res, "phase1_cols", None))):
@@ -1094,6 +1153,16 @@ class AsyncQueryServer:
     for.  Futures resolve strictly in submission order (FIFO batching, FIFO
     collection).
 
+    Late formation: while a batch is in flight, a partial batch is HELD
+    (span ``serve.hold``, counter ``serving_batches_held_total``) until
+    the batch in flight is predicted to end less one host lead
+    (:class:`_DeviceClock`, from observed ready and launch times), not
+    formed as soon as it is ``max_wait_s`` old: the device is busy until
+    then anyway, and queries arriving meanwhile join the batch instead of
+    waiting a whole device period for the next one.  A full queue, a
+    deadline rush, a flush or a close dispatch at once; with nothing in
+    flight ``max_wait_s`` rules as before.
+
     Backpressure: at most ``cfg.queue_capacity`` (default ``4·max_batch``)
     queries may be pending; ``submit`` blocks the producer until the worker
     drains below capacity (bounded memory under overload).  A deadline
@@ -1163,6 +1232,9 @@ class AsyncQueryServer:
         # they left) `_inflight`, so the supervisor must fail them from
         # here — otherwise they would hang forever.
         self._crash_victims: list[ServeFuture] = []
+        self._m_held = self._core.obs.metrics.counter(
+            "serving_batches_held_total",
+            "batches formed after a hold for the batch in flight")
         self._worker = threading.Thread(
             target=self._supervised_run, name="lcrwmd-serve-pipeline",
             daemon=True)
@@ -1453,22 +1525,37 @@ class AsyncQueryServer:
         self._not_full.notify_all()
         return expired
 
-    def _next_batch(self, have_inflight: bool, inflight_ready=None):
-        """Returns ``(items, expired)``.
+    def _next_batch(self, have_inflight: bool, inflight_ready=None,
+                    hold_until: float | None = None, holding: bool = False):
+        """Returns ``(items, expired, hold)``.
 
         ``items`` is up to max_batch queued entries to dispatch, or None
         when the caller should instead fail ``expired`` (deadline sweep),
         collect (work in flight whose device result is ready, or nothing
         pending), or exit (closed).
+
+        Hold: while a batch is in flight and ``hold_until`` (its predicted
+        end less one host lead, :class:`_DeviceClock`) lies ahead, a
+        partial batch is not formed at staleness (arrivals keep joining it,
+        and it still starts on the device when it would have), and an
+        empty queue is no reason to block on the batch in flight (the
+        worker waits for arrivals instead).  A full queue, a deadline
+        rush, a flush or a close still dispatch at once, and a ready batch
+        in flight is still collected at once.  ``hold`` is True when a
+        hold of queued queries begins: the caller calls again with
+        ``holding=True`` inside its own span.
         """
         cfg = self._core.cfg
         with self._lock:
             while True:
                 expired = self._sweep_expired_locked()
                 if expired:
-                    return None, expired
+                    return None, expired, False
+                now = time.perf_counter()
+                hold = (have_inflight and hold_until is not None
+                        and now < hold_until)
+                timeout = 0.1
                 if self._queue:
-                    now = time.perf_counter()
                     mono = time.monotonic()
                     stale = (self._batch_t0 is not None
                              and now - self._batch_t0 >= cfg.max_wait_s)
@@ -1476,10 +1563,12 @@ class AsyncQueryServer:
                            if d is not None]
                     # Rush: dispatch the partial batch early when the
                     # earliest deadline is one serve-latency away.
-                    rush = bool(dls) and (
-                        min(dls) - mono <= self._rush_margin())
-                    if (len(self._queue) >= cfg.max_batch or stale or rush
-                            or self._flush_requested or self._closed):
+                    rush_in = (min(dls) - mono - self._rush_margin()
+                               if dls else None)
+                    rush = rush_in is not None and rush_in <= 0
+                    if (len(self._queue) >= cfg.max_batch or rush
+                            or self._flush_requested or self._closed
+                            or (stale and not hold)):
                         # A batch never mixes corpora: take the longest
                         # same-corpus prefix (FIFO order preserved).
                         take = min(len(self._queue), cfg.max_batch)
@@ -1495,30 +1584,33 @@ class AsyncQueryServer:
                             self._batch_t0 = None
                             self._flush_requested = False
                         self._not_full.notify_all()
-                        return items, []
-                    # Partial batch: wait for fill, staleness, a flush, or
-                    # the next deadline event — but never sit on a COMPLETED
-                    # in-flight batch: if the oldest dispatched batch's
-                    # device result is ready, hand control back so its
-                    # futures resolve now instead of after up to max_wait_s.
-                    timeout = max(0.0, self._batch_t0 + cfg.max_wait_s - now)
-                    if dls:
-                        timeout = min(timeout, max(
-                            0.0, min(dls) - mono - self._rush_margin()))
-                    if inflight_ready is not None and have_inflight:
-                        self._work.wait(min(timeout, 0.005))
-                        if inflight_ready():
-                            return None, []
-                    else:
-                        self._work.wait(timeout)
-                    continue
-                # Empty queue: a pending flush request has nothing left to
-                # flush — clear it so it cannot leak onto the NEXT submitted
-                # query (which must get normal max_batch/max_wait batching).
-                self._flush_requested = False
-                if have_inflight or self._closed:
-                    return None, []
-                self._work.wait(0.1)
+                        return items, [], False
+                    # Partial batch: wait for fill, staleness (or the hold's
+                    # end), a flush, or the next deadline event.
+                    timeout = (hold_until - now if hold else
+                               max(0.0, self._batch_t0 + cfg.max_wait_s - now))
+                    if rush_in is not None:
+                        timeout = min(timeout, rush_in)
+                else:
+                    # Empty queue: a pending flush request has nothing left
+                    # to flush — clear it so it cannot leak onto the NEXT
+                    # submitted query (which must get normal batching).
+                    self._flush_requested = False
+                    if self._closed or (have_inflight and not hold):
+                        return None, [], False
+                    if hold:
+                        timeout = hold_until - now
+                if hold and self._queue and not holding:
+                    return None, [], True
+                # Never sit on a COMPLETED in-flight batch: if the oldest
+                # dispatched batch's device result is ready, hand control
+                # back so its futures resolve now.
+                if inflight_ready is not None and have_inflight:
+                    self._work.wait(min(timeout, 0.005))
+                    if inflight_ready():
+                        return None, [], False
+                else:
+                    self._work.wait(timeout)
 
     def _resolve(self, futures: Sequence[ServeFuture],
                  answers: Sequence) -> None:
@@ -1643,20 +1735,33 @@ class AsyncQueryServer:
 
     def _run(self) -> None:
         core = self._core
-        waited = 0.0    # worker wait since the last batch left the queue
+        # Worker wait and hold since the last batch left the queue.
+        waited, held = 0.0, None
         while True:
+            hold_until = core.clock.hold_until(
+                [h.t_step for h, _f, _d in self._inflight])
             with core.obs.span("wait", batch=core.next_seq,
                                observe=False) as wait:
-                batch, expired = self._next_batch(
+                batch, expired, hold = self._next_batch(
                     have_inflight=bool(self._inflight),
-                    inflight_ready=self._oldest_ready)
+                    inflight_ready=self._oldest_ready, hold_until=hold_until)
             waited += wait.seconds
+            if hold:
+                with core.obs.span("hold", batch=core.next_seq,
+                                   observe=False) as sp:
+                    batch, expired, _ = self._next_batch(
+                        have_inflight=True, inflight_ready=self._oldest_ready,
+                        hold_until=hold_until, holding=True)
+                held = (held or 0.0) + sp.seconds
             if expired:
                 self._expire(expired)
                 continue
             if batch is not None:
                 core.obs.observe_stage("wait", waited)
-                waited = 0.0
+                if held is not None:
+                    core.obs.observe_stage("hold", held)
+                    self._m_held.inc()
+                waited, held = 0.0, None
                 # The batch leaves the queue HERE: queue_wait ends and
                 # host prep (batch_formation) starts now, not after
                 # _prep_entries — otherwise vectorize time (the very cost

@@ -19,9 +19,10 @@ from repro.obs import SERVE_SPANS, Observability
 from repro.serving import AsyncQueryServer, QueryServer, ServerConfig
 
 #: The spans a batch meets on the default path (refine on, no rerank, no
-#: index): every leaf span but those of the stages it does not run.
+#: index, drained: no hold): every leaf span but those of the stages it
+#: does not run.  ``tests/test_batch_hold.py`` covers ``serve.hold``.
 PATH_SPANS = tuple(s for s in SERVE_SPANS if s not in ("rerank_launch",
-                                                       "route"))
+                                                       "route", "hold"))
 N_QUERIES, MAX_BATCH = 24, 8
 
 
