@@ -54,7 +54,7 @@ def _block_engine(eng) -> None:
     """Block until every segment's device tensors are materialized."""
     for seg in eng.segments:
         jax.block_until_ready(seg.tensors.emb_r)
-        jax.block_until_ready(seg.tensors.t_r)
+        jax.block_until_ready(seg.tensors.cent)
 
 
 def _slice_docs(docs, lo: int, hi: int):

@@ -26,6 +26,9 @@ def lcrwmd() -> ArchSpec:
     smoke = LCRWMDConfig(name="lcrwmd-smoke", emb_dim=32, bf16_matmul=False)
     shapes = {
         # Paper Fig. 12: one query batch against the 1M-doc resident Set 1.
+        # h 128 is the benchmark's h_max for Set 1 (bench/configs/set1_knn):
+        # 16 width classes of 8 slots; 2.4% of Poisson(107.5) lengths
+        # exceed it.  data.synth.table_iv_spec("set1") clips at 160.
         "serve_set1_1m": ShapeCell(
             "serve_set1_1m", "lcrwmd_serve",
             dict(n_resident=1_048_576, h_resident=128, n_query=256,

@@ -40,18 +40,37 @@ class SegmentTensors(NamedTuple):
     module-level jitted segment kernels take it as a *traced* argument — so
     every segment with the same shapes shares ONE compiled trace (appending a
     delta segment of a previously seen shape never re-traces anything).
+
+    Nothing here is (n_rows·h1, m): a path that needs the resident words'
+    embeddings gathers ``emb_r[r_ids]`` for the rows it is working on, and
+    the tier-2 WCD centroids are kept per row instead.
     """
 
     emb_r: Array     # (v_e, m) restricted embedding rows (phase-1 input)
     r_ids: Array     # (n_rows, h1) restricted int32 word ids (ELL)
     r_w: Array       # (n_rows, h1) f32 weights (0 at padding rows/slots)
-    t_r: Array       # (n_rows*h1, m) pre-gathered FULL-table word embeddings
     valid_r: Array   # (n_rows*h1,) bool slot validity
+    cent: Array      # (n_rows, m) f32 WCD centroid Σ_h w·E[word] per row
 
     @property
     def nbytes(self) -> int:
         """Device bytes held by this segment's resident tensors."""
         return int(sum(x.size * x.dtype.itemsize for x in self))
+
+
+def _word_rows(emb_r: Array, r_ids: Array) -> Array:
+    """``emb_r[r_ids]``, (*r_ids.shape, m): the resident words' embeddings,
+    at every valid slot the same row as the full table's.  A segment whose
+    documents hold no word has an empty ``emb_r``; its rows are zeros."""
+    if emb_r.shape[0] == 0:
+        return jnp.zeros((*r_ids.shape, emb_r.shape[1]), emb_r.dtype)
+    return emb_r[r_ids]
+
+
+def _distinct_nbytes(arrays) -> int:
+    """Bytes of ``arrays``, each distinct array object counted once."""
+    seen = {id(x): x for x in arrays}
+    return int(sum(x.size * x.dtype.itemsize for x in seen.values()))
 
 
 def _pad_rows(x: Array, n_pad: int) -> Array:
@@ -264,7 +283,6 @@ def _topk_stream_from_z(
 
     b, h2 = q_w.shape
     n, h1 = seg.r_ids.shape
-    m = seg.t_r.shape[-1]
     kk = min(k, n)
     if not symmetric:
         # The one-sided fold IS the shared phase-2 streaming reduction.
@@ -280,18 +298,20 @@ def _topk_stream_from_z(
     n_pad = nb * r
     ids_b = _pad_rows(seg.r_ids, n_pad)
     w_b = _pad_rows(seg.r_w, n_pad)
-    t_r_b = _pad_rows(seg.t_r.reshape(n, h1, m), n_pad)
     v_r_b = _pad_rows(seg.valid_r.reshape(n, h1), n_pad)
     live_b = (None if row_valid is None
               else _pad_rows(row_valid, n_pad).reshape(nb, r))
     xs = [ids_b.reshape(nb, r, h1), w_b.reshape(nb, r, h1),
           jnp.arange(nb, dtype=jnp.int32) * r,
-          t_r_b.reshape(nb, r * h1, m), v_r_b.reshape(nb, r * h1), live_b]
+          v_r_b.reshape(nb, r * h1), live_b]
     stk = StreamingTopK(kk)
 
     def body(carry, xs):
-        ids_blk, w_blk, lo, tr_blk, vr_blk, live_blk = xs
+        ids_blk, w_blk, lo, vr_blk, live_blk = xs
         d1 = phase2_spmm(DocSet(ids=ids_blk, weights=w_blk), z1)
+        # The block's resident word embeddings: at every valid slot the
+        # same row of the full table; padding slots are masked below.
+        tr_blk = _word_rows(seg.emb_r, ids_blk.reshape(-1))  # (R·h1, m)
         sq = sq_dists(t_q, tr_blk, bf16_matmul=bf16_matmul)
         sq = jnp.where(vr_blk[None, :], sq, _INF)
         z2 = safe_sqrt(jnp.min(sq.reshape(b * h2, r, h1), axis=2))
@@ -355,7 +375,8 @@ def _segment_dense(
     )
     d = phase2_spmm(DocSet(ids=seg.r_ids, weights=seg.r_w), z1)
     if symmetric:
-        sq = sq_dists(t_q, seg.t_r, bf16_matmul=bf16_matmul)
+        t_r = _word_rows(seg.emb_r, seg.r_ids.reshape(-1))  # this call only
+        sq = sq_dists(t_q, t_r, bf16_matmul=bf16_matmul)
         sq = jnp.where(seg.valid_r[None, :], sq, _INF)
         z2 = safe_sqrt(jnp.min(sq.reshape(b * h2, n, h1), axis=2))
         d2 = jnp.einsum("bh,bhn->bn", q_w, z2.reshape(b, h2, n))
@@ -406,17 +427,37 @@ def _segmented_rerank(
         return topk_lib.topk_from_candidates(vals, cand_idx, k), work
 
 
+#: Rows per chunk of the centroid pass: at h1 = 128 and m = 300 a chunk's
+#: gathered embeddings are ≈ 0.6 GB.
+_CENT_ROWS = 4096
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _centroids(emb: Array, ids: Array, w: Array, *, chunk: int) -> Array:
+    """WCD centroids (n_rows, m): Σ_h w · emb[ids], ``chunk`` rows at a
+    time, so that the (n_rows·h1, m) gather never exists."""
+    n, h1 = ids.shape
+    nc = -(-n // chunk)
+    ids = _pad_rows(ids, nc * chunk).reshape(nc, chunk, h1)
+    w = _pad_rows(w, nc * chunk).reshape(nc, chunk, h1)
+    with jax.named_scope("centroids"):
+        c = jax.lax.map(lambda a: jnp.einsum(
+            "nh,nhm->nm", a[1], _word_rows(emb, a[0])), (ids, w))
+    return c.reshape(nc * chunk, -1)[:n]
+
+
 class EngineSegment:
     """One immutable unit of a :class:`SegmentedEngine`.
 
     Owns a contiguous global doc-id range ``[offset, offset + n_real)`` and
     its precomputed state: the per-segment ``v_e`` vocab restriction (queries
     still gather from the FULL table, so out-of-resident-vocab query words
-    stay exact), the remapped ELL resident matrix, and the pre-gathered
-    full-table resident word embeddings.  Rows
-    may be padded to ``n_pad`` (zero-weight, non-live) and the restricted
-    vocab to a ``vocab_pad`` multiple so repeated delta shapes hit the same
-    jit trace.
+    stay exact), the remapped ELL resident matrix, and each row's WCD
+    centroid (built in :data:`_CENT_ROWS`-row chunks).  Per document it
+    holds O(h1 + m) device bytes, never an (h1, m) block of embeddings.
+    Rows may be padded to ``n_pad`` (zero-weight, non-live) and the
+    restricted vocab to a ``vocab_pad`` multiple so repeated delta shapes
+    hit the same jit trace.
     """
 
     def __init__(
@@ -447,8 +488,11 @@ class EngineSegment:
             emb_r=emb_r,
             r_ids=sub.ids,
             r_w=sub.weights,
-            t_r=emb_full[docs.ids.reshape(-1)],
             valid_r=(docs.weights > 0).reshape(-1),
+            # From the full table: the same rows as emb_r[r_ids], and one
+            # compiled pass for every segment of a shape, whatever its v_e.
+            cent=_centroids(emb_full, docs.ids, docs.weights,
+                            chunk=min(_CENT_ROWS, max(1, docs.n_docs))),
         )
 
     @property
@@ -458,8 +502,10 @@ class EngineSegment:
 
     @property
     def nbytes(self) -> int:
-        """Device bytes held by this segment (the eviction accounting unit)."""
-        return self.tensors.nbytes
+        """Device bytes held by this segment (the eviction accounting
+        unit): its tensors and its histograms, each array counted once."""
+        return _distinct_nbytes(
+            (*self.tensors, self.docs.ids, self.docs.weights))
 
 
 class SegmentedEngine:
@@ -646,10 +692,15 @@ class SegmentedEngine:
         use :meth:`live_mask` to filter) so global ids stay stable.
         """
         if self._resident_cache is None or self._resident_version != self.version:
-            ids = np.concatenate(
-                [np.asarray(s.docs.ids)[:s.n_real] for s in self.segments])
-            w = np.concatenate(
-                [np.asarray(s.docs.weights)[:s.n_real] for s in self.segments])
+            segs = self.segments
+            if len(segs) == 1 and segs[0].n_rows == segs[0].n_real:
+                # One unpadded segment: its own device histograms, no copy.
+                ids, w = segs[0].docs.ids, segs[0].docs.weights
+            else:
+                ids = np.concatenate(
+                    [np.asarray(s.docs.ids)[:s.n_real] for s in segs])
+                w = np.concatenate(
+                    [np.asarray(s.docs.weights)[:s.n_real] for s in segs])
             self._resident_cache = DocSet(ids=jnp.asarray(ids),
                                           weights=jnp.asarray(w))
             self._resident_version = self.version

@@ -148,6 +148,12 @@ def table_iv_spec(which: str, scale: float = 1.0) -> CorpusSpec:
 
     Set 1: n=1M, mean h=107.5, v_e=452,058.
     Set 2: n=2.8M, mean h=27.5, v_e=292,492.
+
+    ``h_max`` is not published.  Set 1's 160 here keeps nearly every
+    Poisson(107.5) length whole; the benchmark's ``set1_knn`` and
+    :mod:`repro.configs.lcrwmd`'s ``serve_set1_1m`` use 128, a whole number
+    of the serve step's 8-slot width classes and 128-lane tiles: 2.4% of
+    the drawn lengths exceed it, and 2.9% of the documents fill it.
     """
     if which == "set1":
         return CorpusSpec(

@@ -700,14 +700,17 @@ def _build_segmented_serve_step(
     ingest/delete/compact — no rebuild, and no re-trace unless the segment
     shape signature is new.  A segment's rows are placed length-ordered
     within each data shard (:func:`_length_order`) once; a delete only
-    re-permutes its live mask.  Tier-2 centroids are refreshed on the same
-    version check with tombstoned rows pushed to an unreachable distance.
+    re-permutes its live mask.  Tier 2 reads the segments' WCD centroids
+    and masks tombstoned rows by the engine's live mask.
 
     Each batch the step serves adds the ELL slots phase 2 gathers and the
     slots among them that hold a word to the registry counters
     ``serving_phase2_gathered_slots_total`` and
     ``serving_phase2_filled_slots_total``, and returns phase 1's column
-    counts in ``ServeResult.phase1_cols``.
+    counts in ``ServeResult.phase1_cols``.  Each placed corpus version sets
+    the gauge ``serving_resident_bytes{part=docs|ell|cent|emb}``: the
+    device bytes of the histograms, of the ELL tensors (restricted,
+    placed, masks), of the centroids, and of the embedding tables.
     """
     from jax.sharding import NamedSharding
 
@@ -725,6 +728,12 @@ def _build_segmented_serve_step(
     filled_slots = obs.metrics.counter(
         "serving_phase2_filled_slots_total",
         "ELL slots gathered by phase 2 that hold a word")
+    resident_bytes = {
+        part: obs.metrics.gauge(
+            "serving_resident_bytes",
+            "Device bytes the placed corpus version holds, by part",
+            labels={"part": part})
+        for part in ("docs", "ell", "cent", "emb")}
 
     def _place(seg) -> dict:
         rb_s, g_s, row_mult = _slab_geometry(
@@ -774,19 +783,23 @@ def _build_segmented_serve_step(
                                     dtype=jnp.int32)
         state["slots"] = (sum(p["gathered"] for p in segs),
                           sum(p["filled"] for p in segs))
-        # Tier-2 WCD shortlist: per-segment centroids from the pre-gathered
-        # resident embeddings; tombstoned rows sit at distance ~1e18 so the
-        # shortlist can never surface them.
-        cents = []
-        for seg in engine.segments:
-            n_rows, h1 = seg.docs.ids.shape
-            c = jnp.einsum("nh,nhm->nm", seg.docs.weights,
-                           seg.tensors.t_r.reshape(n_rows, h1, -1))
-            cents.append(c[:seg.n_real])
-        cent = jnp.concatenate(cents, axis=0)
-        state["cent"] = jnp.where(
-            engine.live_mask_device()[:, None], cent, 1e18)
+        state["cent"] = _resident_centroids(engine)
         state["version"] = engine.version
+        if obs.metrics.enabled:
+            for part, n in _device_bytes({
+                "docs": [a for seg in engine.segments
+                         for a in (seg.docs.ids, seg.docs.weights)],
+                "ell": [a for seg in engine.segments
+                        for a in (seg.tensors.r_ids, seg.tensors.r_w,
+                                  seg.tensors.valid_r)]
+                       + [a for f in ("rids", "rw", "map", "cls", "live")
+                          for a in state[f]],
+                "cent": [seg.tensors.cent for seg in engine.segments]
+                        + [state["cent"]],
+                "emb": [engine.emb_full, *state["emb"]]
+                       + [seg.tensors.emb_r for seg in engine.segments],
+            }).items():
+                resident_bytes[part].set(n)
 
     def serve(queries: DocSet, query_ids=None, *, tier: int = 0) -> ServeResult:
         """Tiered segmented serve (same ladder as the monolithic step)."""
@@ -801,7 +814,8 @@ def _build_segmented_serve_step(
             q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
                      else jnp.full((queries.n_docs,), -1, jnp.int32))
         if tier >= 2:  # QualityTier.WCD
-            tk = _wcd_topk_step(k, self_exclude, state["cent"], t_q,
+            tk = _wcd_topk_step(k, self_exclude, state["cent"],
+                                engine.live_mask_device(), t_q,
                                 queries.weights, q_gid)
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
@@ -1025,15 +1039,7 @@ def _build_routed_serve_step(
         state["step"] = step
         # Tier-2 WCD shortlist over the ENGINE's flat resident order (the
         # degradation ladder bypasses routing entirely).
-        cents = []
-        for seg in engine.segments:
-            n_rows, h1 = seg.docs.ids.shape
-            c = jnp.einsum("nh,nhm->nm", seg.docs.weights,
-                           seg.tensors.t_r.reshape(n_rows, h1, -1))
-            cents.append(c[:seg.n_real])
-        cent = jnp.concatenate(cents, axis=0)
-        state["cent"] = jnp.where(
-            engine.live_mask_device()[:, None], cent, 1e18)
+        state["cent"] = _resident_centroids(engine)
         state["key"] = key
 
     def _pack_slots(route, b):
@@ -1074,7 +1080,8 @@ def _build_routed_serve_step(
             q_gid = (jnp.asarray(query_ids, jnp.int32) if self_exclude
                      else jnp.full((queries.n_docs,), -1, jnp.int32))
         if tier >= 2:  # QualityTier.WCD — no routing on the last rung
-            tk = _wcd_topk_step(k, self_exclude, state["cent"], t_q,
+            tk = _wcd_topk_step(k, self_exclude, state["cent"],
+                                engine.live_mask_device(), t_q,
                                 queries.weights, q_gid)
             return ServeResult(topk=tk, d_local=None, pruned_exact=None,
                                tier=tier)
@@ -1118,6 +1125,33 @@ def _build_routed_serve_step(
                            rerank_work=work)
 
     return serve
+
+
+def _resident_centroids(engine) -> Array:
+    """Every resident row's WCD centroid (n_docs, m), global id order: the
+    segments' own arrays, joined only where there are several."""
+    cents = [seg.tensors.cent[:seg.n_real] for seg in engine.segments]
+    return cents[0] if len(cents) == 1 else jnp.concatenate(cents, axis=0)
+
+
+def _device_bytes(parts: dict) -> dict:
+    """Device bytes of each named list of arrays; a buffer that several
+    arrays share (a view, a placement that moved nothing) counts once, in
+    the first part that names it.  Host arrays count nothing."""
+    seen: set = set()
+    out = {}
+    for part, arrays in parts.items():
+        n = 0
+        for x in arrays:
+            if not isinstance(x, jax.Array):
+                continue
+            for sh in x.addressable_shards:
+                key = (sh.device.id, sh.data.unsafe_buffer_pointer())
+                if key not in seen:
+                    seen.add(key)
+                    n += sh.data.nbytes
+        out[part] = n
+    return out
 
 
 @jax.jit
@@ -1171,20 +1205,21 @@ def _wmd_rerank(
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _wcd_topk_step(
-    k: int, self_exclude: bool, cent_r: Array, t_q: Array, q_w: Array,
-    q_gid: Array,
+    k: int, self_exclude: bool, cent_r: Array, live: Array, t_q: Array,
+    q_w: Array, q_gid: Array,
 ) -> TopK:
     """Tier-2 degraded serve: top-k by Word Centroid Distance only.
 
     The cheapest rung of the cascade ladder (paper Sec. III): one (B, m)
     einsum + one (n, B) centroid-distance matrix — no phase 1/2, no mesh
     collectives (``cent_r`` is replicated; at n where WCD is the fallback
-    the matrix is trivially small next to the shed stages).  Module-level
-    jit keyed on ``(k, self_exclude)`` so every serve-step build — and every
-    adaptive-budget rebuild — shares one trace.
+    the matrix is trivially small next to the shed stages).  Tombstoned
+    rows (``live`` False) are +inf, so the shortlist never surfaces them.
+    Module-level jit keyed on ``(k, self_exclude)`` so every serve-step
+    build — and every adaptive-budget rebuild — shares one trace.
     """
     c_q = jnp.einsum("bh,bhm->bm", q_w, t_q)
-    d = dists(cent_r, c_q)  # (n, B)
+    d = jnp.where(live[:, None], dists(cent_r, c_q), _INF)  # (n, B)
     if self_exclude:
         row = jnp.arange(cent_r.shape[0], dtype=jnp.int32)
         d = jnp.where(row[:, None] == q_gid[None, :], _INF, d)

@@ -9,9 +9,10 @@ PER-CORPUS on purpose: one tenant's pruning failures must never inflate —
 or, via the decay floor, permanently pin — another tenant's rerank budget.
 
 :class:`CorpusManager` keys the states by ``corpus_id`` in an LRU order
-and accounts device residency in BYTES (``engine.nbytes`` — the resident
-ELL matrices, restricted embeddings, and pre-gathered target tensors are
-the dominant per-corpus device cost).  When ``cache_bytes`` is exceeded,
+and accounts device residency in BYTES (``engine.nbytes`` — the restricted
+embeddings, the resident histograms and ELL matrices, and one WCD centroid
+per document: O(v_e·m + n·(h + m)) per corpus, with no per-word-slot
+embedding copy).  When ``cache_bytes`` is exceeded,
 least-recently-served corpora are EVICTED: their resident tensors and
 compiled serve step are dropped and a host-side snapshot (ids, weights,
 live mask, budget) is kept.  ``checkout`` of an evicted corpus READMITS

@@ -145,6 +145,30 @@ def test_delete_excludes_distributed_serve_without_rebuild(corpus):
     assert before.shape == after.shape
 
 
+def test_delete_excludes_wcd_tier(corpus):
+    """Tier 2 answers from the segments' WCD centroids: a resident query
+    finds itself at distance 0, and once tombstoned it is never named —
+    the other answers keep their order."""
+    from repro.distributed.lcrwmd_dist import build_serve_step
+    from repro.launch.mesh import make_host_mesh
+
+    docs = corpus.docs
+    eng = SegmentedEngine(_slice(docs, 0, BASE_N), corpus.emb)
+    eng.append(_slice(docs, BASE_N, BASE_N + 32))
+    serve = build_serve_step(make_host_mesh(), k=K, engine=eng, refine=True,
+                             bf16_matmul=False)
+    target = BASE_N + 3                          # in the delta segment
+    queries = _slice(docs, target, target + 8)
+    before = serve(queries, tier=2).topk
+    assert int(before.indices[0, 0]) == target
+    assert float(before.dists[0, 0]) == pytest.approx(0.0, abs=1e-3)
+    eng.delete([target])
+    after = np.asarray(serve(queries, tier=2).topk.indices)
+    assert target not in after
+    kept = [i for i in np.asarray(before.indices[0]) if i != target]
+    np.testing.assert_array_equal(after[0, :K - 1], kept)
+
+
 def test_compact_preserves_answers(corpus):
     docs = corpus.docs
     eng = SegmentedEngine(_slice(docs, 0, BASE_N), corpus.emb)

@@ -3,8 +3,9 @@
 The chip's compiler refuses what interpret mode lets through: block shapes
 off the (8, 128) tiling, layouts Mosaic cannot cast, programs that do not
 fit the device's memory.  These compiles catch that here, at the widths of
-``chip_smoke.py`` (m = 300, h = 48, B = 64), at no chip time.  Nothing runs,
-so they say nothing about results or times.
+``chip_smoke.py`` (m = 300, h = 48, B = 64) and, for the served step, of
+the ``set1_knn`` cell (h = 128), at no chip time.  Nothing runs, so they say
+nothing about results or times.
 """
 
 import os
@@ -22,6 +23,9 @@ M, H, B = 300, 48, 64
 #: The smoke corpus's resident rows and restricted vocabulary (Set-2 widths,
 #: n cut to 65,536; v_e is what its seeded generator yields).
 N_DOCS, V_E = 65_536, 283_696
+#: The served step's shapes (n_docs, v_e, h): the smoke corpus, and the
+#: ``set1_knn`` cell (Set-1 widths: v_e 452,058 padded to 458,752, h 128).
+SERVED = {"set2": (N_DOCS, V_E, H), "set1": (131_072, 458_752, 128)}
 HBM_BYTES = 16 * 2**30
 
 
@@ -76,42 +80,43 @@ def test_sinkhorn_kernel_compiles(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.fixture(scope="module")
-def served_step(topo):
-    """The segmented serve step at the smoke corpus's sizes, compiled for
-    one described v5e chip."""
+@pytest.fixture(scope="module", params=sorted(SERVED))
+def served_step(topo, request):
+    """The segmented serve step at one of :data:`SERVED`'s sizes, compiled
+    for one described v5e chip; returns the compiled step, v_e and h."""
     from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     from repro.distributed.lcrwmd_dist import (
         _segmented_step, _slab_geometry, _width_classes)
     from repro.launch.mesh import DATA_AXIS, MODEL_AXIS
 
+    n_docs, v_e, h = SERVED[request.param]
     mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                 (DATA_AXIS, MODEL_AXIS), axis_types=(AxisType.Auto,) * 2)
-    rb, g, _ = _slab_geometry(N_DOCS, 1, 128, 8)
+    rb, g, _ = _slab_geometry(n_docs, 1, 128, 8)
     step = _segmented_step(mesh, kc=16, rbs=(rb,), gs=(g,),
-                           widths=(_width_classes(H),), self_exclude=False,
+                           widths=(_width_classes(h),), self_exclude=False,
                            bf16_matmul=False, phase1_full_mesh=True)
 
     def s(shape, dtype, *spec):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, P(*spec)))
 
-    args = ((s((N_DOCS, H), jnp.int32, DATA_AXIS, None),),
-            (s((N_DOCS, H), jnp.float32, DATA_AXIS, None),),
-            (s((N_DOCS,), jnp.bool_, DATA_AXIS),),
-            (s((N_DOCS,), jnp.int32, DATA_AXIS),),
-            (s((N_DOCS // (rb * g),), jnp.int32, DATA_AXIS),),
+    args = ((s((n_docs, h), jnp.int32, DATA_AXIS, None),),
+            (s((n_docs, h), jnp.float32, DATA_AXIS, None),),
+            (s((n_docs,), jnp.bool_, DATA_AXIS),),
+            (s((n_docs,), jnp.int32, DATA_AXIS),),
+            (s((n_docs // (rb * g),), jnp.int32, DATA_AXIS),),
             s((1,), jnp.int32, None),
-            s((B, H, M), jnp.float32, None, None, None),
-            s((B, H), jnp.float32, None, None),
+            s((B, h, M), jnp.float32, None, None, None),
+            s((B, h), jnp.float32, None, None),
             s((B,), jnp.int32, None),
-            (s((V_E, M), jnp.float32, (MODEL_AXIS, DATA_AXIS), None),))
-    return step.lower(*args).compile()
+            (s((v_e, M), jnp.float32, (MODEL_AXIS, DATA_AXIS), None),))
+    return step.lower(*args).compile(), v_e, h
 
 
 def test_served_segmented_step_fits_one_chip(served_step):
-    mem = served_step.memory_analysis()
+    mem = served_step[0].memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB of 16 GiB"
 
@@ -119,23 +124,25 @@ def test_served_segmented_step_fits_one_chip(served_step):
 def test_width_class_branches_read_z_in_place(served_step):
     """Z enters each slab's width-class branch by reference: its one copy
     is the layout change after phase 1, none per slab."""
-    text = served_step.as_text()
+    compiled, v_e, _h = served_step
+    text = compiled.as_text()
     assert "conditional(" in text
-    assert len(re.findall(rf"= f32\[{V_E},{B}\]\S* copy\(", text)) <= 1
+    assert len(re.findall(rf"= f32\[{v_e},{B}\]\S* copy\(", text)) <= 1
 
 
 def test_phase1_runs_per_query_group(served_step):
-    """Phase 1 makes no (V_E, B·H) block: each group of 16 queries has a
-    GEMM per width class, whose N = 16·w fills whole 128-lane tiles."""
+    """Phase 1 makes no (V_E, B·H) block: each group of 16 queries has
+    one GEMM per width class, whose N = 16·w fills whole 128-lane tiles."""
     from repro.distributed.lcrwmd_dist import _width_classes
 
-    text = served_step.as_text()
-    assert f"f32[{V_E},{B * H}]" not in text
+    compiled, v_e, h = served_step
+    text = compiled.as_text()
+    assert f"f32[{v_e},{B * h}]" not in text
     dots = re.findall(r"= f32\[(\d+),(\d+)\]\S* (?:convolution|dot)\("
                       r"[^\n]*op_name=\"([^\"]+)\"", text)
     n = sorted(int(c) for v, c, name in dots
-               if "/phase1/" in name and int(v) == V_E)
-    assert n == [16 * w for w in _width_classes(H)], n
+               if "/phase1/" in name and int(v) == v_e)
+    assert n == [16 * w for w in _width_classes(h)], n
     assert all(c % 128 == 0 for c in n)
 
 
